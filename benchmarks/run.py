@@ -56,6 +56,9 @@ def main() -> None:
                     help="directory for the BENCH_*.json artifacts")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         ablation,
         accuracy,

@@ -502,10 +502,9 @@ def _presel_query_dense(
     """Pooled MAC on pre-selected per-row slices: exactly one forward
     ``rfftn`` over the stacked clip batch, one channel-contracted MAC,
     one ``irfftn`` (the XLA reference for the grouped Pallas kernel)."""
-    xhat = jnp.fft.rfftn(x, s=fft_shape, axes=(-3, -2, -1))
+    xhat = spectral_conv.rfft3(x, fft_shape)
     yhat = jnp.einsum("bcxyz,bocxyz->boxyz", xhat, sel, precision="highest")
-    y = jnp.fft.irfftn(yhat, s=fft_shape, axes=(-3, -2, -1))
-    return y[..., : out_shape[0], : out_shape[1], : out_shape[2]]
+    return spectral_conv.irfft3(yhat, fft_shape, out_shape)
 
 
 def _pooled_query_dense(
@@ -1803,11 +1802,10 @@ class QueryEngine:
         encode, one ``rfftn`` per stream row, the batched-sel MAC (or
         grouped Pallas launch), ``irfftn``, stitch or fused top-K — the
         unsharded driver runs; sharding only partitions the loop, it
-        reorders no reduction.  ``check_rep=False`` because
-        ``pallas_call`` has no shard_map replication rule; the bodies
+        reorders no reduction.  ``check_vma=False`` because
+        ``pallas_call`` has no varying-manual-axes rule; the bodies
         are collective-free (each tenant's O-slice lives on exactly one
         model shard, so no psum is ever needed)."""
-        from jax.experimental.shard_map import shard_map  # lazy
         from jax.sharding import PartitionSpec as P  # lazy
         from repro.distributed import sharding as shardlib  # lazy
 
@@ -1851,20 +1849,20 @@ class QueryEngine:
         def run(body, x, pool_re, pool_im, x_scale, out_specs):
             xspec, gspec = specs_for(x, pool_re)
             if x_scale is None:
-                f = shard_map(
+                f = jax.shard_map(
                     lambda xl, prl, pil: body(xl, prl, pil, None),
                     mesh=mesh,
                     in_specs=(xspec, gspec, gspec),
                     out_specs=out_specs,
-                    check_rep=False,
+                    check_vma=False,
                 )
                 return f(x, pool_re, pool_im)
-            f = shard_map(
+            f = jax.shard_map(
                 body,
                 mesh=mesh,
                 in_specs=(xspec, gspec, gspec, xspec),
                 out_specs=out_specs,
-                check_rep=False,
+                check_vma=False,
             )
             return f(x, pool_re, pool_im, x_scale)
 

@@ -30,6 +30,7 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 Array = jax.Array
@@ -72,6 +73,131 @@ def valid_shape(sig_shape: Sequence[int], ker_shape: Sequence[int]) -> tuple[int
 
 
 # ---------------------------------------------------------------------------
+# 3-D real transforms
+# ---------------------------------------------------------------------------
+# Every correlation path transforms through these.  Off-TPU they are
+# jnp.fft's rfftn / irfftn.  On TPU they run as DFT matmuls on split
+# real / imaginary float32 planes: with libtpu 0.0.34 (jax 0.9.0) XLA's
+# inverse 3-D FFT returns wrong values on a v5e chip — ``irfftn`` was off
+# by about 40 % of the signal's peak over the served (…, 90, 120, 37)
+# grids, on some shapes only in some runs — and so did an inverse DFT
+# written as complex64 einsums.  Real float32 matmuls at HIGHEST
+# precision are exact there.  The planes form also lets the Pallas MAC,
+# which takes split planes, skip the complex dtype altogether.
+
+Planes = tuple[Array, Array]
+
+
+def _use_dft() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _crop(y: Array, out: Sequence[int] | None) -> Array:
+    if out is None:
+        return y
+    return y[..., : out[0], : out[1], : out[2]]
+
+
+def rfft3_planes(x: Array, s: Sequence[int]) -> Planes:
+    """``rfftn(x, s=s)`` over the trailing (H, W, T) axes as (real,
+    imaginary) float32 planes of shape (..., FH, FW, FT//2+1)."""
+    if _use_dft():
+        return rfft3_dft(x, s)
+    spec = jnp.fft.rfftn(x, s=s, axes=_FFT_AXES)
+    return jnp.real(spec), jnp.imag(spec)
+
+
+def irfft3_planes(
+    re: Array, im: Array, s: Sequence[int], out: Sequence[int] | None = None
+) -> Array:
+    """``irfftn(re + i·im, s=s)`` over the trailing axes, cropped to the
+    leading ``out`` samples of each axis (the whole grid when None)."""
+    if _use_dft():
+        return irfft3_dft(re, im, s, out)
+    return _crop(jnp.fft.irfftn(lax.complex(re, im), s=s, axes=_FFT_AXES), out)
+
+
+def rfft3(x: Array, s: Sequence[int]) -> Array:
+    """Complex ``rfftn(x, s=s)`` over the trailing (H, W, T) axes."""
+    if _use_dft():
+        return lax.complex(*rfft3_dft(x, s))
+    return jnp.fft.rfftn(x, s=s, axes=_FFT_AXES)
+
+
+def irfft3(y: Array, s: Sequence[int], out: Sequence[int] | None = None) -> Array:
+    """``irfftn(y, s=s)`` over the trailing axes, cropped to ``out``."""
+    if _use_dft():
+        return irfft3_dft(jnp.real(y), jnp.imag(y), s, out)
+    return _crop(jnp.fft.irfftn(y, s=s, axes=_FFT_AXES), out)
+
+
+def _dft_cos_sin(n_in: int, n_out: int, n: int) -> tuple[np.ndarray, np.ndarray]:
+    """cos / sin of θ[a, k] = 2π·a·k / n for a < n_in, k < n_out."""
+    theta = 2 * np.pi * np.outer(np.arange(n_in), np.arange(n_out)) / n
+    return np.cos(theta), np.sin(theta)
+
+
+def _cmatmul(re: Array, im: Array, spec: str, c: np.ndarray, s: np.ndarray) -> Planes:
+    """(re + i·im) contracted by ``spec`` with the matrix c + i·s."""
+    hi = lax.Precision.HIGHEST
+    c = c.astype(np.float32)
+    s = s.astype(np.float32)
+
+    def mm(a, m):
+        return jnp.einsum(spec, a, m, precision=hi)
+
+    return mm(re, c) - mm(im, s), mm(re, s) + mm(im, c)
+
+
+_H_SPEC = "...hwk,ha->...awk"
+_W_SPEC = "...wk,wa->...ak"
+
+
+def rfft3_dft(x: Array, s: Sequence[int]) -> Planes:
+    """Forward ``rfftn`` as float32 matmuls against DFT matrices: the
+    real transform along T, then complex transforms along W and H.  Zero
+    padding to ``s`` is folded into the matrices (only the signal's own
+    samples are multiplied); longer inputs are cropped, as ``rfftn``
+    crops them."""
+    h, w, n = (int(d) for d in s)
+    x = x[..., :h, :w, :n].astype(jnp.float32)
+    hin, win, tin = x.shape[-3:]
+    cos, sin = _dft_cos_sin(tin, n // 2 + 1, n)
+    hi = lax.Precision.HIGHEST
+    re = jnp.einsum("...t,tk->...k", x, cos.astype(np.float32), precision=hi)
+    im = -jnp.einsum("...t,tk->...k", x, sin.astype(np.float32), precision=hi)
+    c, s_ = _dft_cos_sin(win, w, w)
+    re, im = _cmatmul(re, im, _W_SPEC, c, -s_)
+    c, s_ = _dft_cos_sin(hin, h, h)
+    return _cmatmul(re, im, _H_SPEC, c, -s_)
+
+
+def irfft3_dft(
+    re: Array, im: Array, s: Sequence[int], out: Sequence[int] | None = None
+) -> Array:
+    """Inverse ``rfftn`` as float32 matmuls against DFT matrices, only
+    for the ``out`` samples kept: complex inverse transforms along H and
+    W, then the real inverse along T, whose bins other than DC and
+    Nyquist stand for their conjugate twins too (weight 2) and give only
+    their real part Re(Z·e^{iθ}) = Re Z·cos θ − Im Z·sin θ."""
+    h, w, n = (int(d) for d in s)
+    oh, ow, on = (h, w, n) if out is None else (int(d) for d in out)
+    c, s_ = _dft_cos_sin(h, oh, h)
+    re, im = _cmatmul(re, im, _H_SPEC, c / h, s_ / h)
+    c, s_ = _dft_cos_sin(w, ow, w)
+    re, im = _cmatmul(re, im, _W_SPEC, c / w, s_ / w)
+    k = np.arange(n // 2 + 1)
+    weight = np.where((k == 0) | (2 * k == n), 1.0, 2.0)[:, None] / n
+    cos, sin = _dft_cos_sin(n // 2 + 1, on, n)
+    hi = lax.Precision.HIGHEST
+    return jnp.einsum(
+        "...k,kt->...t", re, (weight * cos).astype(np.float32), precision=hi
+    ) - jnp.einsum(
+        "...k,kt->...t", im, (weight * sin).astype(np.float32), precision=hi
+    )
+
+
+# ---------------------------------------------------------------------------
 # Grating (record) and query (diffraction + echo readout)
 # ---------------------------------------------------------------------------
 
@@ -100,8 +226,7 @@ def make_grating(
       This is the tensor held stationary in HBM (the analogue of the
       stored atomic coherence).
     """
-    spec = jnp.fft.rfftn(kernels, s=fft_shape, axes=_FFT_AXES)
-    grating = jnp.conj(spec)
+    grating = jnp.conj(rfft3(kernels, fft_shape))
     if spatial_transfer is not None:
         grating = grating * spatial_transfer[..., :, :, None]
     return grating
@@ -126,11 +251,10 @@ def query_grating(
     Returns:
       (B, O, *out_shape) real correlation feature maps.
     """
-    xhat = jnp.fft.rfftn(x, s=fft_shape, axes=_FFT_AXES)  # (B,C,FH,FW,FTr)
+    xhat = rfft3(x, fft_shape)  # (B,C,FH,FW,FTr)
     # Channel-contracted spectral product — the 'diffraction' step.
     yhat = jnp.einsum("bcxyz,ocxyz->boxyz", xhat, grating, precision=precision)
-    y = jnp.fft.irfftn(yhat, s=fft_shape, axes=_FFT_AXES)
-    return y[..., : out_shape[0], : out_shape[1], : out_shape[2]]
+    return irfft3(yhat, fft_shape, out_shape)
 
 
 # ---------------------------------------------------------------------------
@@ -161,9 +285,9 @@ def correlate3d_fft(
         out = valid_shape(sig, ker)
         return query_grating(x, grating, fft_shape, out)
     # full / same need the negative lags, which wrap circularly: roll by K-1.
-    xhat = jnp.fft.rfftn(x, s=fft_shape, axes=_FFT_AXES)
+    xhat = rfft3(x, fft_shape)
     yhat = jnp.einsum("bcxyz,ocxyz->boxyz", xhat, grating, precision="highest")
-    y = jnp.fft.irfftn(yhat, s=fft_shape, axes=_FFT_AXES)
+    y = irfft3(yhat, fft_shape)
     shifts = tuple(k - 1 for k in ker)
     y = jnp.roll(y, shifts, axis=_FFT_AXES)
     y = y[..., : full[0], : full[1], : full[2]]

@@ -12,6 +12,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core import spectral_conv
 from repro.kernels.stmul import kernel as _kernel
 
 Array = jax.Array
@@ -114,17 +115,36 @@ def spectral_mac_grouped(
 
     Returns (B, n_out, *F) complex64.
     """
+    yr, yi = _mac_grouped_planes(
+        jnp.real(xhat), jnp.imag(xhat), pool_re, pool_im, o_start, n_out,
+        min_mxu_c=min_mxu_c, block_o=block_o, block_f=block_f,
+    )
+    return yr + 1j * yi
+
+
+def _mac_grouped_planes(
+    xr: Array,
+    xi: Array,
+    pool_re: Array,
+    pool_im: Array,
+    o_start: Array,
+    n_out: int,
+    *,
+    min_mxu_c: int | None,
+    block_o: int | None,
+    block_f: int | None,
+) -> tuple[Array, Array]:
+    """:func:`spectral_mac_grouped` on split (real, imaginary) planes."""
     tiles = _tile_kwargs(None, block_o, block_f)
-    fshape = xhat.shape[2:]
-    B, C = xhat.shape[:2]
+    fshape = xr.shape[2:]
+    B, C = xr.shape[:2]
     f = 1
     for n in fshape:
         f *= n
-    xf = xhat.reshape(B, C, f)
     so = pool_re.shape[0]
     yr, yi = _kernel.spectral_mac_grouped_pallas(
-        jnp.real(xf).astype(jnp.float32),
-        jnp.imag(xf).astype(jnp.float32),
+        xr.reshape(B, C, f).astype(jnp.float32),
+        xi.reshape(B, C, f).astype(jnp.float32),
         pool_re.reshape(so, C, f),
         pool_im.reshape(so, C, f),
         jnp.asarray(o_start, jnp.int32),
@@ -133,7 +153,8 @@ def spectral_mac_grouped(
         interpret=_use_interpret(),
         **tiles,
     )
-    return (yr + 1j * yi).reshape(B, int(n_out), *fshape)
+    out = (B, int(n_out), *fshape)
+    return yr.reshape(out), yi.reshape(out)
 
 
 def query_grating_pooled(
@@ -151,10 +172,12 @@ def query_grating_pooled(
 ) -> Array:
     """Pooled counterpart of :func:`query_grating_pallas`: one forward
     FFT over the stacked mixed-tenant batch, one grouped-kernel launch
-    against the pooled arena, one inverse FFT."""
-    xhat = jnp.fft.rfftn(x, s=fft_shape, axes=(-3, -2, -1))
-    yhat = spectral_mac_grouped(
-        xhat,
+    against the pooled arena, one inverse FFT — all on split real /
+    imaginary planes."""
+    xr, xi = spectral_conv.rfft3_planes(x, fft_shape)
+    yr, yi = _mac_grouped_planes(
+        xr,
+        xi,
         pool_re,
         pool_im,
         o_start,
@@ -163,8 +186,7 @@ def query_grating_pooled(
         block_o=block_o,
         block_f=block_f,
     )
-    y = jnp.fft.irfftn(yhat, s=fft_shape, axes=(-3, -2, -1))
-    return y[..., : out_shape[0], : out_shape[1], : out_shape[2]]
+    return spectral_conv.irfft3_planes(yr, yi, fft_shape, out_shape)
 
 
 def pooled_query_shard(
@@ -187,8 +209,8 @@ def pooled_query_shard(
     of the pooled arena and contracts it against its data-shard's clip
     rows — no offsets cross a shard, no psum follows (each tenant's
     O-slice lives on exactly one tile by packing).  Callers must pass
-    ``check_rep=False`` to ``shard_map``: ``pallas_call`` has no
-    replication rule, and this body is collective-free anyway.  Bitwise
+    ``check_vma=False`` to ``jax.shard_map``: ``pallas_call`` has no
+    varying-manual-axes rule, and this body is collective-free anyway.  Bitwise
     equal to the offset-gather dispatch at the corresponding rows — the
     per-(row, kernel, frequency) C-contraction is the same op sequence
     regardless of the tile's row offset.
@@ -282,7 +304,7 @@ def query_grating_pallas(
     block_f: int | None = None,
 ) -> Array:
     """Drop-in replacement for spectral_conv.query_grating using the kernel."""
-    xhat = jnp.fft.rfftn(x, s=fft_shape, axes=(-3, -2, -1))
+    xhat = spectral_conv.rfft3(x, fft_shape)
     yhat = spectral_mac(
         xhat,
         grating,
@@ -292,5 +314,4 @@ def query_grating_pallas(
         block_o=block_o,
         block_f=block_f,
     )
-    y = jnp.fft.irfftn(yhat, s=fft_shape, axes=(-3, -2, -1))
-    return y[..., : out_shape[0], : out_shape[1], : out_shape[2]]
+    return spectral_conv.irfft3(yhat, fft_shape, out_shape)

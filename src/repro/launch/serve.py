@@ -183,6 +183,7 @@ from repro.core.engine import (
 )
 from repro.core.fidelity import FidelityPipeline
 from repro.core.sthc import STHC, STHCConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.launch.resilience import (
     BatchExecutionError,
@@ -1677,10 +1678,13 @@ class HybridClassifierServer:
         y = jax.nn.relu(y @ p["fc1_w"] + p["fc1_b"][None, :])
         return y @ p["fc2_w"] + p["fc2_b"][None, :]
 
-    def classify(self, clips: jax.Array) -> np.ndarray:
+    def logits(self, clips: jax.Array) -> jax.Array:
+        """(B, num_classes) logits of ``(B, C, H, W, T)`` clips."""
         conv = self.sthc.correlate(self.grating, clips)  # optical layer
-        logits = self._head(conv)  # digital layers
-        return np.asarray(jnp.argmax(logits, axis=-1))
+        return self._head(conv)  # digital layers
+
+    def classify(self, clips: jax.Array) -> np.ndarray:
+        return np.asarray(jnp.argmax(self.logits(clips), axis=-1))
 
     def classify_stream(
         self, clips: jax.Array, block_t: int | None = None
@@ -1757,6 +1761,7 @@ def main() -> None:
     ap.add_argument("--mode", choices=["video", "lm"], default="video")
     ap.add_argument("--frames", type=int, default=256)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.mode == "video":
         rng = np.random.RandomState(0)
         server = VideoSearchServer(frame_hw=(24, 32))

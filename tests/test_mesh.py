@@ -233,6 +233,18 @@ def test_make_local_mesh_raises_on_short_device_count():
         make_local_mesh(8, 8)
 
 
+def test_short_device_error_names_accelerator_platform():
+    """On an accelerator host the fix is a larger slice, not XLA_FLAGS."""
+    from types import SimpleNamespace
+
+    from repro.launch import mesh as mesh_lib
+
+    chips = [SimpleNamespace(platform="tpu")] * 4
+    msg = str(mesh_lib._short_devices("mesh (1, 8)", 8, chips))
+    assert "found 4 tpu device(s)" in msg
+    assert "XLA_FLAGS" not in msg
+
+
 def test_make_local_mesh_rejects_bad_axes():
     with pytest.raises(ValueError, match="mesh axes"):
         make_local_mesh(0, 2)

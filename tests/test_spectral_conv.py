@@ -27,6 +27,52 @@ def test_fft_matches_direct(mode, rng):
     np.testing.assert_allclose(a, b, atol=TOL * float(jnp.max(jnp.abs(b))) + 1e-5)
 
 
+# (input shape, FFT grid, kept outputs): zero padding on every axis, a
+# crop, an even and an odd T grid (with and without a Nyquist bin)
+DFT_CASES = [
+    ((2, 3, 6, 8, 5), (9, 12, 8), (4, 5, 3)),
+    ((1, 2, 7, 5, 9), (10, 9, 9), None),
+    ((3, 4, 4, 4), (4, 4, 4), (2, 3, 4)),
+    ((2, 1, 12, 10, 7), (12, 10, 7), (3, 3, 3)),
+]
+
+
+@pytest.mark.parametrize("shape,s,out", DFT_CASES)
+def test_dft_transforms_match_numpy(shape, s, out, rng):
+    """The TPU transforms (DFT matmuls on split planes) against numpy's
+    float64 rfftn / irfftn."""
+    x = rng.randn(*shape)
+    spec = np.fft.rfftn(x, s, axes=(-3, -2, -1))
+    re, im = sc.rfft3_dft(jnp.asarray(x, jnp.float32), s)
+    scale = np.abs(spec).max()
+    np.testing.assert_allclose(re, spec.real, atol=1e-6 * scale)
+    np.testing.assert_allclose(im, spec.imag, atol=1e-6 * scale)
+    y = np.fft.irfftn(spec, s, axes=(-3, -2, -1))
+    if out is not None:
+        y = y[..., : out[0], : out[1], : out[2]]
+    got = sc.irfft3_dft(
+        jnp.asarray(spec.real, jnp.float32), jnp.asarray(spec.imag, jnp.float32),
+        s, out,
+    )
+    assert got.shape == y.shape
+    np.testing.assert_allclose(got, y, atol=1e-6 * np.abs(y).max())
+
+
+@pytest.mark.parametrize("shape,s,out", DFT_CASES)
+def test_transform_wrappers_are_jnp_fft_off_tpu(shape, s, out, rng):
+    x = jnp.asarray(rng.randn(*shape), jnp.float32)
+    spec = jnp.fft.rfftn(x, s=s, axes=(-3, -2, -1))
+    re, im = sc.rfft3_planes(x, s)
+    assert np.array_equal(sc.rfft3(x, s), spec)
+    assert np.array_equal(re, jnp.real(spec))
+    assert np.array_equal(im, jnp.imag(spec))
+    y = jnp.fft.irfftn(spec, s=s, axes=(-3, -2, -1))
+    if out is not None:
+        y = y[..., : out[0], : out[1], : out[2]]
+    assert np.array_equal(sc.irfft3(spec, s, out), y)
+    assert np.array_equal(sc.irfft3_planes(re, im, s, out), y)
+
+
 @settings(max_examples=12, deadline=None)
 @given(
     h=st.integers(6, 16),
