@@ -154,7 +154,7 @@ def main() -> None:
         f"{sm['latency_p99_ms']:.0f} ms, {sm['rejected']} shed"
     )
 
-    # serving metrics: cache behavior + measured vs projected rates
+    # serving metrics: cache behavior across fidelities
     m = server.metrics()
     c = m["cache"]
     print(f"cache: {c['hits']} hits / {c['misses']} misses / "
@@ -162,9 +162,6 @@ def main() -> None:
           f"({c['bytes']/1e6:.2f} MB resident) — "
           f"{len(set(t['fidelity'] for t in m['tenants'].values()))} "
           f"fidelities on one server")
-    print(f"throughput: {m['frames_per_s']:.0f} frames/s measured on this "
-          f"host vs {m['projected_slm_fps']:.0f} fps (SLM) / "
-          f"{m['projected_hmd_fps']:.0f} fps (HMD) projected loaders")
 
 
 if __name__ == "__main__":

@@ -98,6 +98,7 @@ from jax import lax
 
 from repro.core import fidelity as fidelity_mod
 from repro.core import optics, pseudo_negative, spectral_conv
+from repro.core.spans import span
 
 if TYPE_CHECKING:  # avoid a circular import; sthc imports this module
     from repro.core.sthc import STHCConfig
@@ -503,7 +504,10 @@ def _presel_query_dense(
     ``rfftn`` over the stacked clip batch, one channel-contracted MAC,
     one ``irfftn`` (the XLA reference for the grouped Pallas kernel)."""
     xhat = spectral_conv.rfft3(x, fft_shape)
-    yhat = jnp.einsum("bcxyz,bocxyz->boxyz", xhat, sel, precision="highest")
+    with span("sthc.mac"):
+        yhat = jnp.einsum(
+            "bcxyz,bocxyz->boxyz", xhat, sel, precision="highest"
+        )
     return spectral_conv.irfft3(yhat, fft_shape, out_shape)
 
 
@@ -1137,6 +1141,7 @@ class QueryEngine:
 
         return readout
 
+    @span("sthc.readout")
     def _chunk_topk(self, win, starts, plan, win_out, x_scale, readout, k):
         """Collapse one window chunk's correlation outputs to the
         (B, O, k) running state.
@@ -1489,163 +1494,172 @@ class QueryEngine:
         top-K states, chunked-cursor and bf16 storage included — are
         bitwise-equal to the single-device path.
         """
-        groups = self._group_requests(requests, stream=True)
-        keys = self._clip_ids(requests, clip_keys, dedup)
+        with span("sthc.engine.layout"):
+            groups = self._group_requests(requests, stream=True)
+            keys = self._clip_ids(requests, clip_keys, dedup)
         results: list[Array | None] = [None] * len(requests)
         shards = int(mesh.shape["model"]) if mesh is not None else 1
         for idxs in groups.values():
-            gratings = [requests[i][0] for i in idxs]
-            g0 = gratings[0]
-            if g0.ker_shape is None:
-                raise ValueError(
-                    "grating lacks ker_shape (recorded by an older engine); "
-                    "re-record before streaming queries"
-                )
-            members, slot_of = _dedup_members(gratings)
-            pool = self._pool_for(members, shards)
-            xs = [requests[i][1] for i in idxs]
-            kh, kw, kt = g0.ker_shape
-            oh, ow, _ = g0.out_shape
-            frame_hw = (oh + kh - 1, ow + kw - 1)
-            if tuple(xs[0].shape[-3:-1]) != frame_hw:
-                raise ValueError(
-                    f"clip spatial dims {tuple(xs[0].shape[-3:-1])} do not "
-                    f"match the recorded frame size {frame_hw}"
-                )
-            if mesh is not None:
-                lay = self._mesh_layout(
-                    pool, gratings, slot_of, [keys[i] for i in idxs]
-                )
-            else:
-                lay = self._dedup_layout(
-                    pool, gratings, slot_of, [keys[i] for i in idxs]
-                )
-            ux = [xs[j] for j in lay.uniq]
-            nbs = [int(xj.shape[0]) for xj in ux]
-            ub0 = [0]
-            for nb in nbs:
-                ub0.append(ub0[-1] + nb)
-            rows = tuple(
-                r for u, nb in enumerate(nbs) for r in [lay.row_of[u]] * nb
-            )
-            # per-REQUEST output splits: several requests may read
-            # different O-windows of one shared physical row
-            splits = tuple(
-                (
-                    ub0[lay.uniq_of[j]],
-                    int(xs[j].shape[0]),
-                    lay.o_off[j],
-                    gratings[j].n_out,
-                )
-                for j in range(len(idxs))
-            )
-            self._count_pooled(sum(int(xj.shape[0]) for xj in xs), sum(nbs))
-            if mesh is not None:
-                # GSPMD mis-lowers a concatenate traced inside jit when
-                # its result feeds a shard_map input on a 2-axis mesh —
-                # each model shard receives the model-axis SUM of its
-                # rows — so the physical batch is packed eagerly here
-                # and the sharded drivers take exactly one array
-                if len(ux) > 1:
-                    ux = [jnp.concatenate(ux, axis=0)]
-                # full-arena fan-out: the shard-tiled arena is read
-                # whole (lay.n_out == its row count), so no padded view
-                # is needed; planes live on the mesh, rows on 'model'
-                pool_re, pool_im = self._mesh_arena(pool, mesh)
-            else:
-                # union spans can read past the arena tail: fetch the
-                # (memoized) padded view so the jitted body never
-                # gathers out of bounds
-                max_row = max(lay.row_of) if lay.row_of else 0
-                pool_re, pool_im = self._padded_arena(pool, max_row, lay.n_out)
-            plan = self.stream_plan_for(g0, xs[0].shape[-1], chunk_windows)
-            mbw = self._max_buffer_windows(max_buffer_windows)
-            static = dict(
-                rows=rows,
-                splits=splits,
-                ker_shape=g0.ker_shape,
-                fft_shape=g0.fft_shape,
-                encode=g0.encode,
-                slm_bits=g0.slm_bits,
-                n_out=lay.n_out,
-            )
-            fused = readout_k is not None
-            if mesh is not None:
-                fns = self._mesh_fns(mesh)
-                many_fn = fns["stream_topk"] if fused else fns["stream"]
-            else:
-                many_fn = (
-                    self._stream_many_topk_fn
-                    if fused
-                    else self._stream_many_fn
-                )
-            if fused:
-                static["k"] = int(readout_k)
-            oh, ow, _ = g0.out_shape
-            stream_out = (oh, ow, plan.n_valid)
-            if mbw is None or plan.n_blocks <= mbw:
-                outs = many_fn(
-                    tuple(ux), pool_re, pool_im, plan=plan, **static
-                )
-                if fused:
-                    outs = tuple(
-                        TopKDetections(s, ix, stream_out) for s, ix in outs
+            with span("sthc.engine.layout"):
+                gratings = [requests[i][0] for i in idxs]
+                g0 = gratings[0]
+                if g0.ker_shape is None:
+                    raise ValueError(
+                        "grating lacks ker_shape (recorded by an older "
+                        "engine); re-record before streaming queries"
                     )
-            else:
-                # bounded-memory chunked pass: stream-global SLM scales
-                # measured once, then every fixed-size segment rides the
-                # same jitted pooled driver
-                cursor = spectral_conv.StreamCursor(plan, mbw)
-                x_scale = None
-                if g0.encode:
-                    scales = [_stream_scale(xj) for xj in ux]
-                    x_scale = (
-                        scales[0]
-                        if len(scales) == 1
-                        else jnp.concatenate(scales, axis=0)
+                members, slot_of = _dedup_members(gratings)
+                pool = self._pool_for(members, shards)
+                xs = [requests[i][1] for i in idxs]
+                kh, kw, kt = g0.ker_shape
+                oh, ow, _ = g0.out_shape
+                frame_hw = (oh + kh - 1, ow + kw - 1)
+                if tuple(xs[0].shape[-3:-1]) != frame_hw:
+                    raise ValueError(
+                        f"clip spatial dims {tuple(xs[0].shape[-3:-1])} do "
+                        f"not match the recorded frame size {frame_hw}"
                     )
-                seg_outs, nv_locals, t0s = [], [], []
-                for seg in cursor:
-                    seg_plan = spectral_conv.stream_plan(
-                        seg.frames, kt, plan.block_t, plan.chunk
-                    )
-                    so = many_fn(
-                        tuple(xj[..., seg.t0 : seg.t1] for xj in ux),
-                        pool_re,
-                        pool_im,
-                        x_scale,
-                        plan=seg_plan,
-                        **static,
-                    )
-                    nv_locals.append(seg_plan.n_valid)
-                    t0s.append(seg.out_t0)
-                    seg_outs.append(so)
-                if fused:
-                    # one jitted rebase+merge tail per request: local
-                    # positions land in the stream-global volume and the
-                    # (rows, K) states fold, without per-segment eager
-                    # dispatch overhead
-                    outs = tuple(
-                        TopKDetections(
-                            *self._seg_merge_fn(
-                                tuple(so[r][0] for so in seg_outs),
-                                tuple(so[r][1] for so in seg_outs),
-                                k=int(readout_k),
-                                nv_locals=tuple(nv_locals),
-                                t0s=tuple(t0s),
-                                nv_total=plan.n_valid,
-                            ),
-                            stream_out,
-                        )
-                        for r in range(len(splits))
+                if mesh is not None:
+                    lay = self._mesh_layout(
+                        pool, gratings, slot_of, [keys[i] for i in idxs]
                     )
                 else:
-                    outs = tuple(
-                        jnp.concatenate([so[r] for so in seg_outs], axis=-1)
-                        if len(seg_outs) > 1
-                        else seg_outs[0][r]
-                        for r in range(len(splits))
+                    lay = self._dedup_layout(
+                        pool, gratings, slot_of, [keys[i] for i in idxs]
                     )
+                ux = [xs[j] for j in lay.uniq]
+                nbs = [int(xj.shape[0]) for xj in ux]
+                ub0 = [0]
+                for nb in nbs:
+                    ub0.append(ub0[-1] + nb)
+                rows = tuple(
+                    r for u, nb in enumerate(nbs) for r in [lay.row_of[u]] * nb
+                )
+                # per-REQUEST output splits: several requests may read
+                # different O-windows of one shared physical row
+                splits = tuple(
+                    (
+                        ub0[lay.uniq_of[j]],
+                        int(xs[j].shape[0]),
+                        lay.o_off[j],
+                        gratings[j].n_out,
+                    )
+                    for j in range(len(idxs))
+                )
+                self._count_pooled(
+                    sum(int(xj.shape[0]) for xj in xs), sum(nbs)
+                )
+                if mesh is not None:
+                    # GSPMD mis-lowers a concatenate traced inside jit when
+                    # its result feeds a shard_map input on a 2-axis mesh —
+                    # each model shard receives the model-axis SUM of its
+                    # rows — so the physical batch is packed eagerly here
+                    # and the sharded drivers take exactly one array
+                    if len(ux) > 1:
+                        ux = [jnp.concatenate(ux, axis=0)]
+                    # full-arena fan-out: the shard-tiled arena is read
+                    # whole (lay.n_out == its row count), so no padded view
+                    # is needed; planes live on the mesh, rows on 'model'
+                    pool_re, pool_im = self._mesh_arena(pool, mesh)
+                else:
+                    # union spans can read past the arena tail: fetch the
+                    # (memoized) padded view so the jitted body never
+                    # gathers out of bounds
+                    max_row = max(lay.row_of) if lay.row_of else 0
+                    pool_re, pool_im = self._padded_arena(
+                        pool, max_row, lay.n_out
+                    )
+                plan = self.stream_plan_for(g0, xs[0].shape[-1], chunk_windows)
+                mbw = self._max_buffer_windows(max_buffer_windows)
+                static = dict(
+                    rows=rows,
+                    splits=splits,
+                    ker_shape=g0.ker_shape,
+                    fft_shape=g0.fft_shape,
+                    encode=g0.encode,
+                    slm_bits=g0.slm_bits,
+                    n_out=lay.n_out,
+                )
+                fused = readout_k is not None
+                if mesh is not None:
+                    fns = self._mesh_fns(mesh)
+                    many_fn = fns["stream_topk"] if fused else fns["stream"]
+                else:
+                    many_fn = (
+                        self._stream_many_topk_fn
+                        if fused
+                        else self._stream_many_fn
+                    )
+                if fused:
+                    static["k"] = int(readout_k)
+                oh, ow, _ = g0.out_shape
+                stream_out = (oh, ow, plan.n_valid)
+            with span("sthc.engine.dispatch"):
+                if mbw is None or plan.n_blocks <= mbw:
+                    outs = many_fn(
+                        tuple(ux), pool_re, pool_im, plan=plan, **static
+                    )
+                    if fused:
+                        outs = tuple(
+                            TopKDetections(s, ix, stream_out) for s, ix in outs
+                        )
+                else:
+                    # bounded-memory chunked pass: stream-global SLM scales
+                    # measured once, then every fixed-size segment rides the
+                    # same jitted pooled driver
+                    cursor = spectral_conv.StreamCursor(plan, mbw)
+                    x_scale = None
+                    if g0.encode:
+                        scales = [_stream_scale(xj) for xj in ux]
+                        x_scale = (
+                            scales[0]
+                            if len(scales) == 1
+                            else jnp.concatenate(scales, axis=0)
+                        )
+                    seg_outs, nv_locals, t0s = [], [], []
+                    for seg in cursor:
+                        seg_plan = spectral_conv.stream_plan(
+                            seg.frames, kt, plan.block_t, plan.chunk
+                        )
+                        so = many_fn(
+                            tuple(xj[..., seg.t0 : seg.t1] for xj in ux),
+                            pool_re,
+                            pool_im,
+                            x_scale,
+                            plan=seg_plan,
+                            **static,
+                        )
+                        nv_locals.append(seg_plan.n_valid)
+                        t0s.append(seg.out_t0)
+                        seg_outs.append(so)
+                    if fused:
+                        # one jitted rebase+merge tail per request: local
+                        # positions land in the stream-global volume and the
+                        # (rows, K) states fold, without per-segment eager
+                        # dispatch overhead
+                        outs = tuple(
+                            TopKDetections(
+                                *self._seg_merge_fn(
+                                    tuple(so[r][0] for so in seg_outs),
+                                    tuple(so[r][1] for so in seg_outs),
+                                    k=int(readout_k),
+                                    nv_locals=tuple(nv_locals),
+                                    t0s=tuple(t0s),
+                                    nv_total=plan.n_valid,
+                                ),
+                                stream_out,
+                            )
+                            for r in range(len(splits))
+                        )
+                    else:
+                        outs = tuple(
+                            jnp.concatenate(
+                                [so[r] for so in seg_outs], axis=-1
+                            )
+                            if len(seg_outs) > 1
+                            else seg_outs[0][r]
+                            for r in range(len(splits))
+                        )
             for j, i in enumerate(idxs):
                 results[i] = outs[j]
         return results  # type: ignore[return-value]
@@ -2180,6 +2194,7 @@ class QueryEngine:
 
     # -- internals ---------------------------------------------------------
 
+    @span("sthc.encode")
     def _encode(
         self, x: Array, bits: int, x_scale: Array | None = None
     ) -> tuple[Array, Array]:

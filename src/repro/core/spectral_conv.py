@@ -33,6 +33,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.core.spans import span
+
 Array = jax.Array
 
 _FFT_AXES = (-3, -2, -1)
@@ -98,6 +100,7 @@ def _crop(y: Array, out: Sequence[int] | None) -> Array:
     return y[..., : out[0], : out[1], : out[2]]
 
 
+@span("sthc.rfft")
 def rfft3_planes(x: Array, s: Sequence[int]) -> Planes:
     """``rfftn(x, s=s)`` over the trailing (H, W, T) axes as (real,
     imaginary) float32 planes of shape (..., FH, FW, FT//2+1)."""
@@ -107,6 +110,7 @@ def rfft3_planes(x: Array, s: Sequence[int]) -> Planes:
     return jnp.real(spec), jnp.imag(spec)
 
 
+@span("sthc.irfft")
 def irfft3_planes(
     re: Array, im: Array, s: Sequence[int], out: Sequence[int] | None = None
 ) -> Array:
@@ -117,6 +121,7 @@ def irfft3_planes(
     return _crop(jnp.fft.irfftn(lax.complex(re, im), s=s, axes=_FFT_AXES), out)
 
 
+@span("sthc.rfft")
 def rfft3(x: Array, s: Sequence[int]) -> Array:
     """Complex ``rfftn(x, s=s)`` over the trailing (H, W, T) axes."""
     if _use_dft():
@@ -124,6 +129,7 @@ def rfft3(x: Array, s: Sequence[int]) -> Array:
     return jnp.fft.rfftn(x, s=s, axes=_FFT_AXES)
 
 
+@span("sthc.irfft")
 def irfft3(y: Array, s: Sequence[int], out: Sequence[int] | None = None) -> Array:
     """``irfftn(y, s=s)`` over the trailing axes, cropped to ``out``."""
     if _use_dft():
@@ -253,7 +259,10 @@ def query_grating(
     """
     xhat = rfft3(x, fft_shape)  # (B,C,FH,FW,FTr)
     # Channel-contracted spectral product — the 'diffraction' step.
-    yhat = jnp.einsum("bcxyz,ocxyz->boxyz", xhat, grating, precision=precision)
+    with span("sthc.mac"):
+        yhat = jnp.einsum(
+            "bcxyz,ocxyz->boxyz", xhat, grating, precision=precision
+        )
     return irfft3(yhat, fft_shape, out_shape)
 
 

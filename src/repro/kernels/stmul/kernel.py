@@ -62,6 +62,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.spans import span
+
 Array = jax.Array
 
 # Default tile sizes (see VMEM budget above).
@@ -348,6 +350,7 @@ def spectral_mac_grouped_pallas(
 # re-chunked and one-shot streams produce bit-identical detections.
 
 
+@span("sthc.readout")
 def topk_select(vals: Array, gidx: Array, k: int) -> tuple[Array, Array]:
     """Top-k along the last axis with a *total* order: score descending,
     then global index ascending (ties go to the smallest index — exactly

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import spectral_conv
+from repro.core.spans import span
 from repro.kernels.stmul import kernel as _kernel
 
 Array = jax.Array
@@ -36,6 +37,7 @@ def _tile_kwargs(
     return tiles
 
 
+@span("sthc.mac")
 def spectral_mac(
     xhat: Array,
     grating: Array,
@@ -122,6 +124,7 @@ def spectral_mac_grouped(
     return yr + 1j * yi
 
 
+@span("sthc.mac")
 def _mac_grouped_planes(
     xr: Array,
     xi: Array,
@@ -230,6 +233,7 @@ def pooled_query_shard(
     )
 
 
+@span("sthc.readout")
 def topk_readout(
     vals: Array,
     gidx: Array,

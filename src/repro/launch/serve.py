@@ -94,9 +94,10 @@ Two serving modes, matching the paper's system and the LM zoo:
    kernels serves at O(chunk) memory end to end.
 
    `metrics()` reports cache hits/misses/evictions/bytes, per-tenant
-   fidelity + device labels, pooled/sequential dispatch counters,
-   clip-dedup row savings, and measured windows/s + frames/s against
-   the paper's projected loader rates (`core.throughput`).
+   fidelity + device labels and traffic counts, pooled/sequential
+   dispatch counters and clip-dedup row savings.  Each stage of the
+   served path is a named ``sthc.*`` span and device scope
+   (`core.spans`; see ``docs/serving.md``, *Observability*).
 
    **Failure semantics** (PR 6, the serving-resilience layer — see
    :mod:`repro.launch.resilience` for the primitives, and
@@ -174,7 +175,7 @@ import numpy as np
 
 from repro import configs
 from repro.core import atomic, fidelity as fidelity_mod, optics
-from repro.core import hybrid, throughput
+from repro.core import hybrid
 from repro.core.engine import (
     TOPK_EMPTY_IDX,
     GratingCache,
@@ -182,6 +183,7 @@ from repro.core.engine import (
     clip_keys_for,
 )
 from repro.core.fidelity import FidelityPipeline
+from repro.core.spans import span
 from repro.core.sthc import STHC, STHCConfig
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
@@ -372,7 +374,6 @@ class _Tenant:
     queries: int = 0
     windows: int = 0
     frames: int = 0
-    seconds: float = 0.0
 
 
 class VideoSearchServer:
@@ -425,8 +426,8 @@ class VideoSearchServer:
         # for introspection and the LM/video demo drivers
         self.sthc = self._sthc_for(self._default_fidelity)
         self._tenants: dict[str, _Tenant] = {}  # guarded-by: _lock
-        # traffic from removed/replaced tenants — server-wide totals and
-        # the measured-vs-projected rates must survive tenant churn
+        # traffic from removed/replaced tenants — server-wide totals
+        # must survive tenant churn
         self._retired = _Tenant(kernels=None, kt=0)
         # guards _tenants membership and the per-tenant counters; the
         # correlation itself runs outside (the cache has its own lock)
@@ -644,11 +645,10 @@ class VideoSearchServer:
 
     def _retire(self, ten: _Tenant) -> None:  # holds-lock: _lock
         # fold a departing tenant's traffic into the server-wide totals
-        # so metrics() rates don't rewind
+        # so metrics() counts don't rewind
         self._retired.queries += ten.queries
         self._retired.windows += ten.windows
         self._retired.frames += ten.frames
-        self._retired.seconds += ten.seconds
 
     def _discard_if_unreferenced(self, key: tuple | None) -> None:  # holds-lock: _lock
         if key is not None and all(
@@ -767,6 +767,14 @@ class VideoSearchServer:
         set on the future; direct callers must check
         ``isinstance(out, ServingError)``.
         """
+        with span("sthc.search.batch", requests=len(requests)):
+            return self._search_batch(
+                requests, pooled, clip_keys, dedup, return_volume
+            )
+
+    def _search_batch(
+        self, requests, pooled, clip_keys, dedup, return_volume
+    ) -> list[dict]:
         if pooled is None:
             pooled = getattr(self.cfg, "pooled_queries", True)
         if dedup is None:
@@ -775,6 +783,215 @@ class VideoSearchServer:
             getattr(self.cfg, "fused_readout", True) and not return_volume
         )
         topk = max(1, int(getattr(self.cfg, "readout_topk", 1)))
+        with span("sthc.search.group"):
+            order, tens, stacks = self._group(requests)
+        if self.chaos is not None:  # chaos seam: batch encode/stacking
+            self.chaos.on("encode", mode="pooled" if pooled else "sequential")
+
+        if pooled:
+            # pooled cross-tenant dispatch: fetch all gratings, then one
+            # engine call answers every same-geometry group together.
+            # The pooled executor is fidelity-agnostic (record-time
+            # physics is baked into each grating), so the server's
+            # default engine serves all tenants' gratings.
+            t0 = time.time()
+            with span("sthc.search.gratings"):
+                gratings = [
+                    self._fetch_grating(key[0], ten)
+                    for (key, _), ten in zip(order, tens)
+                ]
+            # per-group clip identities for the shared-stream dedup: a
+            # stacked group's identity is the tuple of its members'
+            # content hashes (hashed once per distinct array object —
+            # or upstream at scheduler submit time, via ``clip_keys``)
+            group_keys = None
+            if dedup:
+                if clip_keys is None:
+                    clip_keys = clip_keys_for([clip for _, clip in requests])
+                group_keys = []
+                for _, idxs in order:
+                    ks = [clip_keys[i] for i in idxs]
+                    if any(k is None for k in ks):
+                        group_keys.append(None)
+                    elif len(ks) == 1:
+                        group_keys.append(ks[0])
+                    else:
+                        group_keys.append(("stack",) + tuple(ks))
+            if self.chaos is not None:  # chaos seam: pooled dispatch
+                self.chaos.on("dispatch", mode="pooled")
+            if fused:
+                # fused readout: the pooled dispatch itself returns the
+                # per-request top-K states — no volume, no separate
+                # readout launch
+                fmaps = None
+                dets = self.sthc.engine.query_stream_many(
+                    list(zip(gratings, stacks)),
+                    clip_keys=group_keys,
+                    dedup=dedup,
+                    readout_k=topk,
+                    mesh=self.mesh,
+                )
+                with span("sthc.search.wait"):
+                    jax.block_until_ready(
+                        tuple((d.scores, d.index) for d in dets)
+                    )
+            else:
+                dets = None
+                fmaps = self.sthc.engine.query_stream_many(
+                    list(zip(gratings, stacks)),
+                    clip_keys=group_keys,
+                    dedup=dedup,
+                    mesh=self.mesh,
+                )
+                # stitched detection readout rides the batch too: one
+                # jitted call for every group's peak + argmax instead of
+                # an eager op chain (with its host sync) per tenant
+                readouts = self._readout(tuple(fmaps))
+                with span("sthc.search.wait"):
+                    readouts = jax.block_until_ready(readouts)
+            dt = time.time() - t0
+            with self._lock:
+                self._pooled_dispatches += 1
+            lat = [dt] * len(order)  # every request rode the one dispatch
+            plans = [
+                ten.sthc.engine.stream_plan_for(g, clips.shape[-1])
+                for ten, g, clips in zip(tens, gratings, stacks)
+            ]
+        else:
+            gratings, plans, lat = [], [], []
+            fmaps = None if fused else []
+            dets = [] if fused else None
+            for (key, idxs), ten, clips in zip(order, tens, stacks):
+                t0 = time.time()
+                with span("sthc.search.gratings"):
+                    grating = self._fetch_grating(key[0], ten)
+                if self.chaos is not None:  # chaos seam: sequential path
+                    self.chaos.on("dispatch", mode="sequential")
+                if fused:
+                    det = ten.sthc.engine.query_stream(
+                        grating, clips, readout_k=topk
+                    )
+                    with span("sthc.search.wait"):
+                        jax.block_until_ready((det.scores, det.index))
+                    dets.append(det)
+                else:
+                    fmap = ten.sthc.engine.query_stream(grating, clips)
+                    # honest serving latency
+                    with span("sthc.search.wait"):
+                        fmap = jax.block_until_ready(fmap)
+                    fmaps.append(fmap)
+                dt = time.time() - t0
+                with self._lock:
+                    self._sequential_dispatches += 1
+                gratings.append(grating)
+                # the exact plan the correlation ran under (derived from
+                # the grating's recorded geometry, not the live cfg)
+                plans.append(
+                    ten.sthc.engine.stream_plan_for(grating, clips.shape[-1])
+                )
+                lat.append(dt)
+            if not fused:
+                # same shared readout helper as the pooled path (one
+                # jitted call; bitwise-identical scores across entry
+                # points), timed outside the per-group latency windows
+                with span("sthc.search.wait"):
+                    readouts = jax.block_until_ready(
+                        self._readout(tuple(fmaps))
+                    )
+
+        with span("sthc.search.results"):
+            results: list[dict | None] = [None] * len(requests)
+            with self._lock:
+                for g_i, ((key, idxs), ten, clips) in enumerate(
+                    zip(order, tens, stacks)
+                ):
+                    # the snapshot tenant may have been removed/retired
+                    # during the correlation — credit its traffic to the
+                    # server-wide totals so metrics() never undercounts
+                    tgt = (
+                        ten
+                        if self._tenants.get(key[0]) is ten
+                        else self._retired
+                    )
+                    n_streams = clips.shape[0]
+                    tgt.queries += len(idxs)
+                    tgt.windows += plans[g_i].n_blocks * n_streams
+                    tgt.frames += int(clips.shape[-1]) * n_streams
+            guard = getattr(self.cfg, "guard_scores", True)
+            for g_i, ((key, idxs), clips) in enumerate(zip(order, stacks)):
+                tenant = key[0]
+                plan = plans[g_i]
+                topk_s = topk_t = None
+                if fused:
+                    # fused readout: slot 0 of the (B, O, K) state IS
+                    # the stitched max/argmax (total selection order, k=1
+                    # == first-occurrence argmax); tmod comes off the
+                    # state's recorded valid-T extent — no volume anywhere
+                    det = dets[g_i]
+                    tmod = int(det.out_shape[-1])
+                    # transfer the tiny (B, O, K) state once and slice on
+                    # the host — a device-side [..., 0] would be one more
+                    # dispatch per request on the hot path
+                    state_s = np.asarray(det.scores)
+                    state_i = np.asarray(det.index)
+                    peak = state_s[..., 0]
+                    idx = state_i[..., 0]
+                    if topk > 1:
+                        topk_s = state_s
+                        ti = state_i
+                        # exhausted slots carry the empty sentinel:
+                        # report frame −1 rather than a garbage modulo
+                        topk_t = np.where(
+                            ti == TOPK_EMPTY_IDX, -1, ti % tmod
+                        )
+                else:
+                    tmod = int(fmaps[g_i].shape[-1])
+                    peak = np.asarray(readouts[g_i][0])
+                    idx = np.asarray(readouts[g_i][1])
+                if self.chaos is not None:  # chaos seam: detection readout
+                    peak = self.chaos.on(
+                        "readout",
+                        mode="pooled" if pooled else "sequential",
+                        payload=peak,
+                    )
+                t_idx = idx % tmod
+                b = 0
+                for i in idxs:
+                    nb = requests[i][1].shape[0]
+                    scores = peak[b : b + nb]
+                    # signal-integrity guard on the already-host-resident
+                    # peaks: one NaN/Inf row quarantines one request, the
+                    # rest of the pooled batch delivers untouched (a NaN
+                    # in a fused row propagates into its peak slot, so the
+                    # check is path-independent)
+                    if guard and not np.isfinite(scores).all():
+                        with self._lock:
+                            self._quarantined += 1
+                        results[i] = TenantQuarantined(  # type: ignore[call-overload]
+                            f"non-finite correlation scores for tenant "
+                            f"{tenant!r}; request quarantined",
+                            tenant=tenant,
+                        )
+                    else:
+                        res = {
+                            "tenant": tenant,
+                            "scores": scores,
+                            "peak_frame": t_idx[b : b + nb],
+                            "latency_s": lat[g_i],
+                            "windows": plan.n_blocks,
+                        }
+                        if topk_s is not None:
+                            res["topk_scores"] = topk_s[b : b + nb]
+                            res["topk_frames"] = topk_t[b : b + nb]
+                        if return_volume:
+                            res["volume"] = fmaps[g_i][b : b + nb]
+                        results[i] = res
+                    b += nb
+            return results  # type: ignore[return-value]
+
+    def _group(self, requests) -> tuple[list, list, list]:
+        """Validate a batch and group it by tenant and stream shape:
+        (sorted groups, their tenants, one stacked clip batch each)."""
         groups: dict[tuple, list[int]] = {}
         with self._lock:  # snapshot: a racing remove_tenant can't break
             tenants = dict(self._tenants)
@@ -821,222 +1038,16 @@ class VideoSearchServer:
             else jnp.concatenate([requests[i][1] for i in idxs], axis=0)
             for _, idxs in order
         ]
-        if self.chaos is not None:  # chaos seam: batch encode/stacking
-            self.chaos.on("encode", mode="pooled" if pooled else "sequential")
-
-        if pooled:
-            # pooled cross-tenant dispatch: fetch all gratings, then one
-            # engine call answers every same-geometry group together.
-            # The pooled executor is fidelity-agnostic (record-time
-            # physics is baked into each grating), so the server's
-            # default engine serves all tenants' gratings.
-            t0 = time.time()
-            gratings = [
-                self._fetch_grating(key[0], ten)
-                for (key, _), ten in zip(order, tens)
-            ]
-            # per-group clip identities for the shared-stream dedup: a
-            # stacked group's identity is the tuple of its members'
-            # content hashes (hashed once per distinct array object —
-            # or upstream at scheduler submit time, via ``clip_keys``)
-            group_keys = None
-            if dedup:
-                if clip_keys is None:
-                    clip_keys = clip_keys_for([clip for _, clip in requests])
-                group_keys = []
-                for _, idxs in order:
-                    ks = [clip_keys[i] for i in idxs]
-                    if any(k is None for k in ks):
-                        group_keys.append(None)
-                    elif len(ks) == 1:
-                        group_keys.append(ks[0])
-                    else:
-                        group_keys.append(("stack",) + tuple(ks))
-            if self.chaos is not None:  # chaos seam: pooled dispatch
-                self.chaos.on("dispatch", mode="pooled")
-            if fused:
-                # fused readout: the pooled dispatch itself returns the
-                # per-request top-K states — no volume, no separate
-                # readout launch
-                fmaps = None
-                dets = self.sthc.engine.query_stream_many(
-                    list(zip(gratings, stacks)),
-                    clip_keys=group_keys,
-                    dedup=dedup,
-                    readout_k=topk,
-                    mesh=self.mesh,
-                )
-                jax.block_until_ready(
-                    tuple((d.scores, d.index) for d in dets)
-                )
-            else:
-                dets = None
-                fmaps = self.sthc.engine.query_stream_many(
-                    list(zip(gratings, stacks)),
-                    clip_keys=group_keys,
-                    dedup=dedup,
-                    mesh=self.mesh,
-                )
-                # stitched detection readout rides the batch too: one
-                # jitted call for every group's peak + argmax instead of
-                # an eager op chain (with its host sync) per tenant
-                readouts = self._readout(tuple(fmaps))
-                readouts = jax.block_until_ready(readouts)
-            dt = time.time() - t0
-            with self._lock:
-                self._pooled_dispatches += 1
-            lat = [dt] * len(order)  # every request rode the one dispatch
-            # credit the tenant busy-seconds proportionally to each
-            # group's window share: the batch paid dt *once*, and the
-            # windows/s rate must not divide by dt × n_groups
-            plans = [
-                ten.sthc.engine.stream_plan_for(g, clips.shape[-1])
-                for ten, g, clips in zip(tens, gratings, stacks)
-            ]
-            weights = [
-                p.n_blocks * int(clips.shape[0])
-                for p, clips in zip(plans, stacks)
-            ]
-            total_w = sum(weights) or 1
-            busy = [dt * w / total_w for w in weights]
-        else:
-            gratings, plans, lat, busy = [], [], [], []
-            fmaps = None if fused else []
-            dets = [] if fused else None
-            for (key, idxs), ten, clips in zip(order, tens, stacks):
-                t0 = time.time()
-                grating = self._fetch_grating(key[0], ten)
-                if self.chaos is not None:  # chaos seam: sequential path
-                    self.chaos.on("dispatch", mode="sequential")
-                if fused:
-                    det = ten.sthc.engine.query_stream(
-                        grating, clips, readout_k=topk
-                    )
-                    jax.block_until_ready((det.scores, det.index))
-                    dets.append(det)
-                else:
-                    fmap = ten.sthc.engine.query_stream(grating, clips)
-                    # honest serving latency
-                    fmap = jax.block_until_ready(fmap)
-                    fmaps.append(fmap)
-                dt = time.time() - t0
-                with self._lock:
-                    self._sequential_dispatches += 1
-                gratings.append(grating)
-                # the exact plan the correlation ran under (derived from
-                # the grating's recorded geometry, not the live cfg)
-                plans.append(
-                    ten.sthc.engine.stream_plan_for(grating, clips.shape[-1])
-                )
-                lat.append(dt)
-                busy.append(dt)
-            if not fused:
-                # same shared readout helper as the pooled path (one
-                # jitted call; bitwise-identical scores across entry
-                # points), timed outside the per-group latency windows
-                readouts = jax.block_until_ready(
-                    self._readout(tuple(fmaps))
-                )
-
-        results: list[dict | None] = [None] * len(requests)
-        with self._lock:
-            for g_i, ((key, idxs), ten, clips) in enumerate(
-                zip(order, tens, stacks)
-            ):
-                # the snapshot tenant may have been removed/retired during
-                # the correlation — credit its traffic to the server-wide
-                # totals instead so metrics() never undercounts
-                tgt = (
-                    ten
-                    if self._tenants.get(key[0]) is ten
-                    else self._retired
-                )
-                n_streams = clips.shape[0]
-                tgt.queries += len(idxs)
-                tgt.windows += plans[g_i].n_blocks * n_streams
-                tgt.frames += int(clips.shape[-1]) * n_streams
-                tgt.seconds += busy[g_i]
-        guard = getattr(self.cfg, "guard_scores", True)
-        for g_i, ((key, idxs), clips) in enumerate(zip(order, stacks)):
-            tenant = key[0]
-            plan = plans[g_i]
-            topk_s = topk_t = None
-            if fused:
-                # fused readout: slot 0 of the (B, O, K) state IS the
-                # stitched max/argmax (total selection order, k=1 ==
-                # first-occurrence argmax); tmod comes off the state's
-                # recorded valid-T extent — no volume anywhere
-                det = dets[g_i]
-                tmod = int(det.out_shape[-1])
-                # transfer the tiny (B, O, K) state once and slice on
-                # the host — a device-side [..., 0] would be one more
-                # dispatch per request on the hot path
-                state_s = np.asarray(det.scores)
-                state_i = np.asarray(det.index)
-                peak = state_s[..., 0]
-                idx = state_i[..., 0]
-                if topk > 1:
-                    topk_s = state_s
-                    ti = state_i
-                    # exhausted slots carry the empty sentinel: report
-                    # frame −1 rather than a garbage modulo
-                    topk_t = np.where(
-                        ti == TOPK_EMPTY_IDX, -1, ti % tmod
-                    )
-            else:
-                tmod = int(fmaps[g_i].shape[-1])
-                peak = np.asarray(readouts[g_i][0])
-                idx = np.asarray(readouts[g_i][1])
-            if self.chaos is not None:  # chaos seam: detection readout
-                peak = self.chaos.on(
-                    "readout",
-                    mode="pooled" if pooled else "sequential",
-                    payload=peak,
-                )
-            t_idx = idx % tmod
-            b = 0
-            for i in idxs:
-                nb = requests[i][1].shape[0]
-                scores = peak[b : b + nb]
-                # signal-integrity guard on the already-host-resident
-                # peaks: one NaN/Inf row quarantines one request, the
-                # rest of the pooled batch delivers untouched (a NaN in
-                # a fused row propagates into its peak slot, so the
-                # check is path-independent)
-                if guard and not np.isfinite(scores).all():
-                    with self._lock:
-                        self._quarantined += 1
-                    results[i] = TenantQuarantined(  # type: ignore[call-overload]
-                        f"non-finite correlation scores for tenant "
-                        f"{tenant!r}; request quarantined",
-                        tenant=tenant,
-                    )
-                else:
-                    res = {
-                        "tenant": tenant,
-                        "scores": scores,
-                        "peak_frame": t_idx[b : b + nb],
-                        "latency_s": lat[g_i],
-                        "windows": plan.n_blocks,
-                    }
-                    if topk_s is not None:
-                        res["topk_scores"] = topk_s[b : b + nb]
-                        res["topk_frames"] = topk_t[b : b + nb]
-                    if return_volume:
-                        res["volume"] = fmaps[g_i][b : b + nb]
-                    results[i] = res
-                b += nb
-        return results  # type: ignore[return-value]
+        return order, tens, stacks
 
     # -- observability -----------------------------------------------------
 
     def metrics(self) -> dict:
-        """Serving metrics: cache counters + measured vs projected rates.
+        """Serving counters: cache, tenants, dispatches, dedup, guard.
 
-        Rates divide by summed per-group *busy* seconds, not elapsed
-        wall time — with searches running concurrently from several
-        threads the overlapping intervals double-count and the reported
-        frames/s / windows/s are a lower bound on the true rate.
+        Rates are not kept here: a rate needs a window, which the caller
+        owns (the benchmark divides the work answered in its window by
+        the window).
         """
         with self._lock:
             per_tenant = {
@@ -1046,7 +1057,6 @@ class VideoSearchServer:
                     "queries": t.queries,
                     "windows": t.windows,
                     "frames": t.frames,
-                    "seconds": t.seconds,
                 }
                 for name, t in self._tenants.items()
             }
@@ -1060,10 +1070,6 @@ class VideoSearchServer:
             frames = retired.frames + sum(
                 t["frames"] for t in per_tenant.values()
             )
-            seconds = retired.seconds + sum(
-                t["seconds"] for t in per_tenant.values()
-            )
-        fps = frames / seconds if seconds > 0 else 0.0
         with self._lock:
             pooled = self._pooled_dispatches
             sequential = self._sequential_dispatches
@@ -1092,13 +1098,6 @@ class VideoSearchServer:
             "queries": queries,
             "windows_total": windows,
             "frames_total": frames,
-            "windows_per_s": windows / seconds if seconds > 0 else 0.0,
-            "frames_per_s": fps,
-            # measured digital-twin rate vs the paper's projected loaders
-            "projected_slm_fps": throughput.SLM_FPS,
-            "projected_hmd_fps": throughput.HMD_FPS,
-            "frames_per_s_vs_slm": fps / throughput.SLM_FPS,
-            "frames_per_s_vs_hmd": fps / throughput.HMD_FPS,
         }
 
 
@@ -1259,7 +1258,10 @@ class MicrobatchScheduler:
         wants_dedup = getattr(cfg, "dedup_clips", True) and getattr(
             cfg, "pooled_queries", True
         )  # the sequential executor never reads clip keys either
-        cid = clip_key(clip) if wants_dedup else None
+        cid = None
+        if wants_dedup:
+            with span("sthc.sched.hash"):
+                cid = clip_key(clip)
         now = time.time()
         if deadline_s is None:
             deadline_s = self.default_deadline_s
@@ -1316,55 +1318,70 @@ class MicrobatchScheduler:
             item = self._take(timeout=0.05)
             if item is None:
                 continue
-            batch = [item]
-            shape = tuple(item.clip.shape)
-            deadline = item.t_submit + self.batch_wait_s
-            # coalesce with earlier same-shape stash leftovers first —
-            # requests deferred by a shape mismatch must still get the
-            # pooled dispatch they waited for
-            kept: collections.deque[_Pending] = collections.deque()
-            while self._stash and len(batch) < self.max_batch:
-                nxt = self._stash.popleft()
-                if tuple(nxt.clip.shape) == shape:
-                    batch.append(nxt)
-                else:
-                    kept.append(nxt)
-            kept.extend(self._stash)
-            self._stash = kept
-            # then the live queue: wait out the deadline for a fuller
-            # batch, and past it take only what is already here —
-            # bounded to max_batch pulls per cycle, so a sustained
-            # other-shape stream can neither livelock this batch nor
-            # grow the stash without bound (admission control stays
-            # with the queue)
-            skipped: list[_Pending] = []
-            while (
-                len(batch) < self.max_batch
-                and len(batch) + len(skipped) < 2 * self.max_batch
-            ):
-                rem = deadline - time.time()
+            # repro-lint LD202: _batch_seq is written by the batcher
+            # thread only today, but metrics()/debugging read it
+            # concurrently and nothing structural stops a second
+            # dispatcher — take the counter lock like every other counter
+            with self._lock:
+                self._batch_seq += 1
+                batch_id = self._batch_seq
+            with span("sthc.sched.cycle", batch_id=batch_id):
+                batch = self._form_batch(item)
                 try:
-                    if rem > 0:
-                        nxt = self._q.get(timeout=rem)
-                    else:
-                        nxt = self._q.get_nowait()
-                except queue_mod.Empty:
-                    break
-                # batches form across tenants but per clip shape: the
-                # pooled executor groups by geometry anyway, and keeping
-                # one shape per microbatch keeps its dispatch singular
-                if tuple(nxt.clip.shape) == shape:
-                    batch.append(nxt)
-                else:
-                    skipped.append(nxt)
-            self._stash.extend(skipped)  # next cycle, arrival order kept
+                    self._dispatch(self._form_dedup_groups(batch), batch_id)
+                except Exception:  # noqa: BLE001 — the batcher must survive
+                    # _dispatch fails futures itself; this is a belt for
+                    # future-state races etc. — a dead batcher thread
+                    # would hang every subsequent request
+                    pass
+
+    def _form_batch(self, item: _Pending) -> list[_Pending]:
+        """The microbatch that starts with ``item``: same-shape stash
+        leftovers first, then the live queue until ``max_batch`` or the
+        batch wait runs out."""
+        batch = [item]
+        shape = tuple(item.clip.shape)
+        deadline = item.t_submit + self.batch_wait_s
+        # coalesce with earlier same-shape stash leftovers first —
+        # requests deferred by a shape mismatch must still get the
+        # pooled dispatch they waited for
+        kept: collections.deque[_Pending] = collections.deque()
+        while self._stash and len(batch) < self.max_batch:
+            nxt = self._stash.popleft()
+            if tuple(nxt.clip.shape) == shape:
+                batch.append(nxt)
+            else:
+                kept.append(nxt)
+        kept.extend(self._stash)
+        self._stash = kept
+        # then the live queue: wait out the deadline for a fuller
+        # batch, and past it take only what is already here —
+        # bounded to max_batch pulls per cycle, so a sustained
+        # other-shape stream can neither livelock this batch nor
+        # grow the stash without bound (admission control stays
+        # with the queue)
+        skipped: list[_Pending] = []
+        while (
+            len(batch) < self.max_batch
+            and len(batch) + len(skipped) < 2 * self.max_batch
+        ):
+            rem = deadline - time.time()
             try:
-                self._dispatch(self._form_dedup_groups(batch))
-            except Exception:  # noqa: BLE001 — the batcher must survive
-                # _dispatch fails futures itself; this is a belt for
-                # future-state races etc. — a dead batcher thread would
-                # hang every subsequent request
-                pass
+                if rem > 0:
+                    nxt = self._q.get(timeout=rem)
+                else:
+                    nxt = self._q.get_nowait()
+            except queue_mod.Empty:
+                break
+            # batches form across tenants but per clip shape: the
+            # pooled executor groups by geometry anyway, and keeping
+            # one shape per microbatch keeps its dispatch singular
+            if tuple(nxt.clip.shape) == shape:
+                batch.append(nxt)
+            else:
+                skipped.append(nxt)
+        self._stash.extend(skipped)  # next cycle, arrival order kept
+        return batch
 
     def _form_dedup_groups(self, batch: list[_Pending]) -> list[_Pending]:
         """Arrange a formed microbatch into shared-stream dedup groups:
@@ -1411,20 +1428,13 @@ class MicrobatchScheduler:
                 self.deadline_missed += 1
                 self.failed += 1
 
-    def _dispatch(self, batch: list[_Pending]) -> None:
+    def _dispatch(self, batch: list[_Pending], batch_id: int) -> None:
         # claim each future before any work: a caller may have
         # cancel()led a pending one, and set_result on a cancelled
         # future raises (killing the batcher); claiming also locks out
         # late cancels during the server call.  _execute below assumes
         # every future it sees is already claimed (the singles retry
         # path must not re-claim).
-        # repro-lint LD202: _batch_seq is written by the batcher thread
-        # only today, but metrics()/debugging read it concurrently and
-        # nothing structural stops a second dispatcher — take the counter
-        # lock like every other counter rather than rely on the comment.
-        with self._lock:
-            self._batch_seq += 1
-            batch_id = self._batch_seq
         batch = [p for p in batch if self._claim(p.future)]
         if batch:
             self._execute(batch, batch_id)
@@ -1680,8 +1690,11 @@ class HybridClassifierServer:
 
     def logits(self, clips: jax.Array) -> jax.Array:
         """(B, num_classes) logits of ``(B, C, H, W, T)`` clips."""
-        conv = self.sthc.correlate(self.grating, clips)  # optical layer
-        return self._head(conv)  # digital layers
+        with span("sthc.classify", clips=int(clips.shape[0])):
+            with span("sthc.classify.conv"):  # optical layer
+                conv = self.sthc.correlate(self.grating, clips)
+            with span("sthc.classify.head"):  # digital layers
+                return self._head(conv)
 
     def classify(self, clips: jax.Array) -> np.ndarray:
         return np.asarray(jnp.argmax(self.logits(clips), axis=-1))
@@ -1787,9 +1800,7 @@ def main() -> None:
         print(
             f"cache: {m['cache']['hits']} hits / {m['cache']['misses']} misses"
             f" / {m['cache']['evictions']} evictions, "
-            f"{m['cache']['bytes']/1e6:.1f} MB resident; "
-            f"{m['frames_per_s']:.0f} frames/s measured "
-            f"(SLM projection {m['projected_slm_fps']:.0f} fps)"
+            f"{m['cache']['bytes']/1e6:.1f} MB resident"
         )
     else:
         cfg = configs.get_smoke_config("qwen2-1.5b")

@@ -198,10 +198,6 @@ def test_server_metrics_counters():
     assert m["queries"] == 1
     assert m["frames_total"] == 2 * 20  # both concurrent streams count
     assert m["windows_total"] >= 2
-    assert m["frames_per_s"] > 0 and m["windows_per_s"] > 0
-    assert m["frames_per_s_vs_slm"] == pytest.approx(
-        m["frames_per_s"] / m["projected_slm_fps"]
-    )
     cache = m["cache"]
     for key in ("hits", "misses", "evictions", "entries", "bytes"):
         assert key in cache
