@@ -641,6 +641,17 @@ class QueryEngine:
 
     def __init__(self, config: "STHCConfig"):
         self.config = config
+        # the one-shot query (encode, forward transform, MAC, cropped
+        # inverse transform, de-scaling) as one program per clip shape
+        # and geometry: one host dispatch per call, the DFT matrices
+        # compile-time constants, and no complex64 spectrum written out
+        # between the MAC and the transforms
+        self._query_one_fn = jax.jit(
+            self._query_impl,
+            static_argnames=("fft_shape", "out_shape", "encode", "slm_bits"),
+        )
+        self._trace_lock = threading.Lock()
+        self._query_traces = 0  # guarded-by: _trace_lock
         # jitted overlap-save driver; built eagerly (wrapper creation is
         # free, tracing happens on first call) so concurrent first
         # queries from server threads can't race a lazy init
@@ -862,15 +873,36 @@ class QueryEngine:
 
         Exactly one forward ``rfftn``, one channel-contracted MAC against
         the effective grating, one ``irfftn``.  Returns (B, O, *out_shape).
+
+        One jitted dispatch: the program is traced once per clip shape
+        and grating geometry (:attr:`query_traces` counts the traces).
         """
-        if not grating.encode:
-            return self._query_fn()(
-                x, grating.effective_c, grating.fft_shape, grating.out_shape
-            )
-        enc, x_scale = self._encode(x, grating.slm_bits)
-        y = self._query_fn()(
-            enc, grating.effective_c, grating.fft_shape, grating.out_shape
+        return self._query_one_fn(
+            x,
+            grating.effective_c,
+            fft_shape=grating.fft_shape,
+            out_shape=grating.out_shape,
+            encode=grating.encode,
+            slm_bits=grating.slm_bits,
         )
+
+    @property
+    def query_traces(self) -> int:
+        """How many times the one-shot query program has been traced —
+        flat in steady serving, one more for each new clip shape."""
+        with self._trace_lock:
+            return self._query_traces
+
+    def _query_impl(self, x, effective, *, fft_shape, out_shape, encode,
+                    slm_bits):
+        """One-shot query body (jitted; geometry and encode static, the
+        effective grating traced)."""
+        with self._trace_lock:
+            self._query_traces += 1
+        if not encode:
+            return self._query_fn()(x, effective, fft_shape, out_shape)
+        enc, x_scale = self._encode(x, slm_bits)
+        y = self._query_fn()(enc, effective, fft_shape, out_shape)
         # fused epilogue: only the per-example de-scaling remains — the ±
         # combine, kernel scale and echo gain were folded at record time.
         return y * x_scale

@@ -61,7 +61,10 @@ def quantize_unit(x: Array, bits: int) -> Array:
         return x
     levels = float(2**bits - 1)
     xc = jnp.clip(x, 0.0, 1.0)
-    q = jnp.round(xc * levels) / levels
+    # times the reciprocal, not divided by ``levels``: under jit XLA turns
+    # a division by a constant into this product, so writing it out keeps
+    # eager and jitted paths bit-identical
+    q = jnp.round(xc * levels) * (1.0 / levels)
     # straight-through: value of q, gradient of xc
     return xc + jax.lax.stop_gradient(q - xc)
 

@@ -399,6 +399,34 @@ def test_engine_record_query_jit_friendly(rng):
     )
 
 
+@pytest.mark.parametrize("store", ["float32", "bfloat16"])
+@pytest.mark.parametrize("pipe", ["ideal", "physical"])
+def test_query_is_one_program_equal_to_eager_composition(pipe, store, rng):
+    """The jitted one-shot query equals the eager composition it
+    replaced (encode, ``query_grating``, de-scale) to float32 rounding,
+    and is traced once per clip shape: two calls at one shape and one
+    at another add exactly two traces."""
+    x = _clips(rng)
+    engine = QueryEngine(
+        STHCConfig(fidelity=getattr(fid, pipe)(), grating_dtype=store)
+    )
+    g = engine.record(_kernels(rng), x.shape[-3:])
+    n0 = engine.query_traces
+    got = engine.query(g, x)
+    engine.query(g, x)
+    engine.query(g, _clips(rng, B=3))
+    assert engine.query_traces - n0 == 2
+    if g.encode:
+        enc, x_scale = engine._encode(x, g.slm_bits)
+    else:
+        enc, x_scale = x, 1.0
+    want = sc.query_grating(enc, g.effective_c, g.fft_shape, g.out_shape)
+    want = want * x_scale
+    np.testing.assert_allclose(
+        got, want, rtol=0, atol=2e-6 * float(jnp.max(jnp.abs(want)))
+    )
+
+
 # -- pooled cross-tenant executor ---------------------------------------------
 
 

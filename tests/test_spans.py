@@ -159,3 +159,33 @@ def test_pooled_driver_names_its_device_stages():
     for scope in DEVICE_SCOPES:
         assert re.search(rf"(^|[/(]){re.escape(scope)}($|[/)])", path,
                          re.M), scope
+
+
+def test_classifier_conv_program_names_its_device_stages():
+    """The classifier's optical layer is one jitted program per call, and
+    its lowered ops carry the one-shot query's device scopes."""
+    hcfg = hybrid.HybridConfig(
+        height=12, width=16, frames=6, num_kernels=2, k_h=4, k_w=6, k_t=3,
+        pool_window=(2, 2, 2), hidden=8,
+    )
+    clf = HybridClassifierServer(
+        hybrid.init_params(jax.random.PRNGKey(0), hcfg), hcfg
+    )
+    engine = clf.sthc.engine
+    calls = []
+    inner = engine._query_one_fn
+
+    def record(*args, **kw):
+        calls.append((args, kw))
+        return inner(*args, **kw)
+
+    engine._query_one_fn = record
+    clips = np.random.RandomState(2).rand(2, 1, 12, 16, 6).astype(np.float32)
+    clf.logits(clips).block_until_ready()
+    assert len(calls) == 1  # one dispatch for the whole conv
+    (args, kw), = calls
+    hlo = inner.lower(*args, **kw).as_text(dialect="hlo", debug_info=True)
+    path = "\n".join(sorted(set(re.findall(r'op_name="([^"]*)"', hlo))))
+    for scope in ("sthc.encode", "sthc.rfft", "sthc.mac", "sthc.irfft"):
+        assert re.search(rf"(^|[/(]){re.escape(scope)}($|[/)])", path,
+                         re.M), scope
