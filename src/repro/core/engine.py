@@ -39,8 +39,12 @@ grating), recomputing the identical ``rfftn(x)`` both times, and
 * **Pooled serving** — ``query_many`` / ``query_stream_many`` extend the
   weight-stationary dataflow *across tenants*: resident effective
   gratings that share FFT geometry and encode semantics are packed into
-  one ``(ΣO, C, FH, FW, FTr)`` arena (:class:`GratingPool`, memoized
-  while its members live) and a mixed-tenant clip batch is answered with
+  one ``(ΣO, C, FH, FW, FTr)`` arena (:class:`GratingPool`; on the
+  streaming path one resident arena per pool group, packed from the
+  gratings declared by :meth:`QueryEngine.set_resident`, with a batch's
+  composition — rows and their arena offsets — passed as runtime data,
+  so any mix of resident tenants reuses one compiled program per row
+  bucket) and a mixed-tenant clip batch is answered with
   exactly one forward FFT, one pooled channel-contracted MAC in which
   every clip row reads only its own tenant's O-offset slice, and one
   inverse FFT — N same-geometry tenants pay 1 device dispatch instead of
@@ -234,7 +238,10 @@ class GratingPool:
     Attributes:
       re / im: split real/imag planes of the arena, in the members'
         storage dtype (bf16 gratings stay bf16 in HBM; the MAC up-casts
-        tiles to f32 — f32 accumulation either way).
+        tiles to f32 — f32 accumulation either way): (ΣO_pad, C, FH,
+        FW, FTr), or (ΣO_pad, C, F_pad) with the bins flattened and
+        lane-padded where the grouped Pallas kernel serves the arena
+        (the resident arenas of the pooled stream path).
       o_start: per-member first-row offset.  Member slots are padded to
         ``align`` rows (the Pallas grouped kernel indexes the arena in
         O-tile units; the dense gather path uses align=1), and the arena
@@ -281,9 +288,9 @@ class _DedupLayout:
         (first requester of that content), in dispatch batch order.
       uniq_of: per group-local request — which physical copy serves it.
       row_of: per physical copy — its arena start row (the union span's
-        first row).
+        first row, or earlier where the span would overrun the arena).
       o_off: per group-local request — offset of its tenant's O-slice
-        inside its physical row's span.
+        inside its physical row's read.
       n_out: rows every physical row reads/writes (the widest span,
         aligned to the pool's O-tile grid).
     """
@@ -293,6 +300,95 @@ class _DedupLayout:
     row_of: list[int]
     o_off: list[int]
     n_out: int
+
+
+def _unique_rows(keys: list) -> tuple[list[int], list[int]]:
+    """(uniq, uniq_of) of a group: the first request of each distinct
+    clip, and for each request its physical copy.  A None key (no clip
+    identity) is never shared."""
+    uniq: list[int] = []
+    uniq_of: list[int] = []
+    by_key: dict[tuple, int] = {}
+    for j, k in enumerate(keys):
+        u = by_key.get(k) if k is not None else None
+        if u is None:
+            u = len(uniq)
+            uniq.append(j)
+            if k is not None:
+                by_key[k] = u
+        uniq_of.append(u)
+    return uniq, uniq_of
+
+
+def _span_layout(
+    starts: list[int],
+    widths: list[int],
+    keys: list,
+    align: int,
+    arena_rows: int,
+) -> _DedupLayout:
+    """Collapse group rows with content-equal clips onto shared
+    physical rows.
+
+    ``starts[j]`` is request j's first arena row, ``widths[j]`` its
+    tenant's O.  Each unique clip gets one physical row whose O-window
+    is the *union span* of every slice requested for that clip (member
+    slots pack contiguously, so the span is one aligned ``[lo, lo +
+    n_out)`` read; tenants between two requested slots are computed and
+    discarded — in the canonical all-tenants-one-stream batch the span
+    is exactly the arena).  ``n_out`` is the widest span, rounded up to
+    a bucket (:func:`_row_bucket`) of the arena's O-tiles (the grouped
+    Pallas kernel's grid), so the pooled stream programs see few
+    distinct widths.  A span that would read past the arena's last row
+    starts earlier instead and its requests' ``o_off`` grow by the
+    shift, so every read stays inside the arena (``n_out`` never
+    exceeds it).
+
+    One ``n_out`` for the whole dispatch is a deliberate trade-off: the
+    MAC needs a uniform per-row width, so in a *mixed* batch (one wide
+    shared-stream span next to narrow unique rows) the narrow rows
+    compute and discard up to the widest span; splitting them into
+    separate dispatches would cost an extra FFT dispatch per batch —
+    the thing pooling exists to avoid.
+    """
+    uniq, uniq_of = _unique_rows(keys)
+    span_lo: list = [None] * len(uniq)
+    span_hi = [0] * len(uniq)
+    for j, u in enumerate(uniq_of):
+        s = starts[j]
+        span_lo[u] = s if span_lo[u] is None else min(span_lo[u], s)
+        span_hi[u] = max(span_hi[u], s + widths[j])
+    tiles = -(-max(hi - lo for lo, hi in zip(span_lo, span_hi)) // align)
+    n_out = min(_row_bucket(tiles) * align, arena_rows)
+    row_of = [min(lo, arena_rows - n_out) for lo in span_lo]
+    o_off = [starts[j] - row_of[uniq_of[j]] for j in range(len(uniq_of))]
+    return _DedupLayout(
+        uniq=uniq, uniq_of=uniq_of, row_of=row_of, o_off=o_off, n_out=n_out
+    )
+
+
+def _fanout_layout(
+    starts: list[int], keys: list, arena_rows: int
+) -> _DedupLayout:
+    """Row layout of a mesh-sharded dispatch: full-arena fan-out.
+
+    With the arena's ΣO rows sharded over the model axis, the
+    offset-gather behind :func:`_span_layout`'s union spans would be a
+    cross-shard read; instead every physical clip row computes against
+    the *entire* (sharded) arena — each model-axis device contracts only
+    its own ``shard_rows`` tile, psum-free — and a request's answer is
+    the slice of the global output at its member slot's absolute start.
+    Clip-dedup degenerates to unique-clips-only (a shared physical row
+    already reads every tenant's slice).
+    """
+    uniq, uniq_of = _unique_rows(keys)
+    return _DedupLayout(
+        uniq=uniq,
+        uniq_of=uniq_of,
+        row_of=[0] * len(uniq),
+        o_off=list(starts),
+        n_out=int(arena_rows),
+    )
 
 
 def _dedup_members(
@@ -330,10 +426,30 @@ def _bin_members(slots: list[int], shards: int) -> tuple[list[int], int]:
     return bin_of, max(load) if load else 0
 
 
+def _lane_planes(
+    re: Array, im: Array, lanes: int
+) -> tuple[Array, Array]:
+    """A grating's (O, C, FH, FW, FTr) planes as (O, C, F_pad): bins
+    flattened and zero-padded to a multiple of ``lanes``, the layout the
+    grouped Pallas kernel reads."""
+    o, c = int(re.shape[0]), int(re.shape[1])
+    f = int(np.prod(re.shape[2:]))
+    widths = [(0, 0), (0, 0), (0, -f % lanes)]
+    return (jnp.pad(re.reshape(o, c, f), widths),
+            jnp.pad(im.reshape(o, c, f), widths))
+
+
 def _build_pool(
-    members: list[FusedGrating], align: int, shards: int = 1
+    members: list[FusedGrating],
+    align: int,
+    shards: int = 1,
+    lanes: int | None = None,
 ) -> GratingPool:
     """Pack member gratings' planes into one arena (see GratingPool).
+
+    ``lanes`` packs the planes flat and lane-padded (:func:`_lane_planes`)
+    so that the grouped kernel reads the arena as it is stored; without
+    it the arena keeps the gratings' 5-D bins.
 
     ``shards > 1`` makes the packing mesh-aware: members are binned
     into ``shards`` equal tiles of ``shard_rows`` rows (every tile
@@ -350,6 +466,8 @@ def _build_pool(
                 f"{[m.channels for m in members]}"
             )
     planes = [g.planes for g in members]
+    if lanes is not None:
+        planes = [_lane_planes(re, im, lanes) for re, im in planes]
     slots = [
         -(-int(re.shape[0]) // align) * align for re, _ in planes
     ]
@@ -366,12 +484,20 @@ def _build_pool(
     feat = planes[0][0].shape[1:]
     dtype = planes[0][0].dtype
     if shards <= 1:
+        # each slot as its planes plus a zero block, one concatenate for
+        # the whole arena: no padded copy of a member is ever made
         o_start = []
         row = 0
-        for i in range(len(members)):
-            re, im = padded(i)
+        zeros: dict[int, Array] = {}
+        for i, (re, im) in enumerate(planes):
             res.append(re)
             ims.append(im)
+            gap = slots[i] - int(re.shape[0])
+            if gap:
+                if gap not in zeros:
+                    zeros[gap] = jnp.zeros((gap,) + feat, dtype)
+                res.append(zeros[gap])
+                ims.append(zeros[gap])
             o_start.append(row)
             row += slots[i]
         tail = max(o + n_out for o in o_start) - row
@@ -464,19 +590,69 @@ def clip_keys_for(arrays) -> list:
     return keys
 
 
-def _pad_arena(
-    pool_re: Array, pool_im: Array, max_row: int, n_out: int
-) -> tuple[Array, Array]:
-    """Zero-pad arena rows so every ``[row, row + n_out)`` read stays in
-    bounds.  The pool's own tail covers per-member slot reads; union
-    spans (clip-dedup) can read wider than any single slot, and jnp
-    fancy-indexing would clamp out-of-bounds rows to the last member
-    silently."""
-    need = int(max_row) + int(n_out) - int(pool_re.shape[0])
-    if need <= 0:
-        return pool_re, pool_im
-    widths = [(0, need)] + [(0, 0)] * (pool_re.ndim - 1)
-    return jnp.pad(pool_re, widths), jnp.pad(pool_im, widths)
+def _row_bucket(n: int) -> int:
+    """Rows a pooled stream program is compiled for: ``n`` rounded up to
+    the next of 1, 2, 3, 4, 6, 8, 12, 16, … (powers of two and 1.5×
+    them), so a pool group's programs number about two per doubling of
+    the batch however its batches are composed, and padding stays
+    under a third of a dispatch's rows.  (Powers of two alone pad a
+    fifth of the rows of the Zipf-skewed cell, and make its work per
+    batch vary with the mix three times as much.)"""
+    n = max(int(n), 1)
+    p = 1 << (n - 1).bit_length()  # the power of two at or above n
+    return p * 3 // 4 if p >= 4 and n <= p * 3 // 4 else p
+
+
+def _arena_key(g: FusedGrating) -> tuple:
+    """The pool group a grating's resident arena belongs to: gratings
+    that share FFT geometry, encode semantics, storage and channels."""
+    return (
+        g.fft_shape,
+        g.out_shape,
+        g.ker_shape,
+        bool(g.encode),
+        int(g.slm_bits) if g.encode else -1,
+        g.storage_dtype,
+        g.channels,
+    )
+
+
+@dataclasses.dataclass(frozen=True)
+class _Arena:
+    """A pool group's resident arena and where each member sits in it.
+
+    Attributes:
+      pool: the packed arena; its members are the declared residents of
+        the group (:meth:`QueryEngine.set_resident`), in declaration
+        order, then any other gratings the last rebuild had to admit.
+      slot: ``id(grating) -> member index`` (the pool pins every member,
+        so ids stay unique while the arena lives).
+      declared: ids of the declared residents it was built from.
+    """
+
+    pool: GratingPool
+    slot: dict
+    declared: tuple
+
+
+@dataclasses.dataclass(frozen=True)
+class PooledTopK:
+    """One request's answer inside its pool group's whole top-K state.
+
+    The pooled stream programs return one ``(rows, n_out, K)`` state per
+    pool group; a request owns ``scores[rows, kernels]``.  Callers that
+    copy the state to the host (the video-search server) copy it once
+    per group and slice there; :meth:`take` slices on the device."""
+
+    scores: Array  # (rows, n_out, K): the whole group state
+    index: Array
+    out_shape: tuple[int, int, int]
+    rows: slice
+    kernels: slice
+
+    def take(self) -> "TopKDetections":
+        sl = (self.rows, self.kernels)
+        return TopKDetections(self.scores[sl], self.index[sl], self.out_shape)
 
 
 def _pool_select(
@@ -637,7 +813,8 @@ def _segments_rebase_merge(
 class QueryEngine:
     """Record-once / query-many executor for one :class:`STHCConfig`."""
 
-    _max_pools = 8  # LRU bound on memoized cross-tenant arenas
+    # LRU bound on query_many's and the mesh's arenas, and on zero rows
+    _max_pools = 8
 
     def __init__(self, config: "STHCConfig"):
         self.config = config
@@ -661,20 +838,20 @@ class QueryEngine:
                 "ker_shape", "fft_shape", "plan", "encode", "slm_bits",
             ),
         )
-        # pooled streaming driver + the cross-tenant arena cache.  The
-        # request composition (per-row offsets, per-request splits) is
-        # *static*: steady-state serving compositions repeat call after
-        # call, and baking them into the trace removes every eager
-        # per-request op (host→device offset transfers, result slicing)
-        # from the hot path — the pooled dispatch is exactly one jitted
-        # call.  The flip side is a retrace per *novel* composition, so
-        # callers should canonicalize request order (the server sorts
-        # its tenant groups) to keep the composition space small.
+        # pooled streaming driver.  A batch's composition (which tenants,
+        # how many rows, where each row reads in the arena) is runtime
+        # data: the rows arrive as a tuple of single-row clips padded to
+        # a row bucket (_row_bucket), the per-row arena offsets as an int32
+        # array, and the program returns the group's whole state, which
+        # callers split per request.  Only shapes and geometry are
+        # static, so one pool group compiles once per (bucket, n_out)
+        # and any mix of resident tenants reuses those programs
+        # (:attr:`stream_traces` counts their traces).
         self._stream_many_fn = jax.jit(
             self._stream_many_impl,
             static_argnames=(
-                "rows", "splits", "ker_shape", "fft_shape", "plan",
-                "encode", "slm_bits", "n_out",
+                "ker_shape", "fft_shape", "plan", "encode", "slm_bits",
+                "n_out",
             ),
         )
         # fused-readout overlap-save drivers: same window loop, but each
@@ -690,23 +867,31 @@ class QueryEngine:
         self._stream_many_topk_fn = jax.jit(
             self._stream_many_topk_impl,
             static_argnames=(
-                "rows", "splits", "ker_shape", "fft_shape", "plan",
-                "encode", "slm_bits", "n_out", "k",
+                "ker_shape", "fft_shape", "plan", "encode", "slm_bits",
+                "n_out", "k",
             ),
         )
+        self._stream_traces = 0  # guarded-by: _trace_lock
         # cross-segment state tail (rebase + merge) as one launch — the
         # cursor path's per-request epilogue
         self._seg_merge_fn = jax.jit(
             _segments_rebase_merge,
             static_argnames=("k", "nv_locals", "t0s", "nv_total"),
         )
+        # per-member-set arenas of the one-shot query_many and the mesh
+        # path (their compositions stay static)
         self._pools: OrderedDict[tuple, GratingPool] = OrderedDict()  # guarded-by: _pools_lock
-        # row-padded arena views for dedup union spans that overhang the
-        # pool tail: keyed (pool, rows needed) so steady-state mixed-span
-        # compositions reuse one padded device buffer instead of paying
-        # an O(arena) jnp.pad per dispatch.  Entries hold the pool
-        # (strong ref: id-keyed lookups stay sound) + the padded planes.
-        self._padded: OrderedDict[tuple, tuple] = OrderedDict()  # guarded-by: _pools_lock
+        # the pooled stream path's resident arenas, one per pool group
+        # (_arena_key), packed from the declared residents
+        # (set_resident) and rebuilt only when those change or a batch
+        # brings an undeclared grating
+        self._resident: dict[tuple, list[FusedGrating]] = {}  # guarded-by: _pools_lock
+        self._arenas: dict[tuple, _Arena] = {}  # guarded-by: _pools_lock
+        self._arena_builds = 0  # guarded-by: _pools_lock
+        # zero rows that pad a row batch up to its bucket, one per row
+        # shape, the last few shapes kept (device-resident: padding
+        # uploads nothing)
+        self._zero_rows: OrderedDict[tuple, Array] = OrderedDict()  # guarded-by: _pools_lock
         # mesh serving state: per-Mesh jitted sharded drivers and
         # per-(pool, mesh) arena placements (planes device_put once with
         # rows NamedSharding'd over the model axis, reused across
@@ -721,10 +906,17 @@ class QueryEngine:
         self._pooled_dispatches = 0  # guarded-by: _pools_lock
         self._pooled_rows_offered = 0  # guarded-by: _pools_lock
         self._pooled_rows_dispatched = 0  # guarded-by: _pools_lock
+        self._pooled_rows_padded = 0  # guarded-by: _pools_lock
 
     def pool_stats(self) -> dict:
         """Pooled-executor counters for serving metrics: how many clip
-        rows the dedup collapsed (``rows_saved``) out of those offered."""
+        rows the dedup collapsed (``rows_saved``) out of those offered;
+        rows that carried a request (``rows_dispatched``) and rows that
+        only padded a batch to its bucket (``rows_padded``); resident
+        arenas packed (``arena_builds``) and pooled stream programs
+        traced (``stream_traces``)."""
+        with self._trace_lock:
+            traces = self._stream_traces
         with self._pools_lock:
             offered = self._pooled_rows_offered
             dispatched = self._pooled_rows_dispatched
@@ -733,13 +925,19 @@ class QueryEngine:
                 "rows_offered": offered,
                 "rows_dispatched": dispatched,
                 "rows_saved": offered - dispatched,
+                "rows_padded": self._pooled_rows_padded,
+                "arena_builds": self._arena_builds,
+                "stream_traces": traces,
             }
 
-    def _count_pooled(self, offered: int, dispatched: int) -> None:
+    def _count_pooled(
+        self, offered: int, dispatched: int, padded: int = 0
+    ) -> None:
         with self._pools_lock:
             self._pooled_dispatches += 1
             self._pooled_rows_offered += int(offered)
             self._pooled_rows_dispatched += int(dispatched)
+            self._pooled_rows_padded += int(padded)
 
     # -- record -----------------------------------------------------------
 
@@ -892,6 +1090,18 @@ class QueryEngine:
         flat in steady serving, one more for each new clip shape."""
         with self._trace_lock:
             return self._query_traces
+
+    @property
+    def stream_traces(self) -> int:
+        """How many times the single-device pooled stream programs have
+        been traced — at most one per pool group, row bucket and
+        ``n_out``, whatever the batches' compositions."""
+        with self._trace_lock:
+            return self._stream_traces
+
+    def _count_stream_trace(self) -> None:
+        with self._trace_lock:
+            self._stream_traces += 1
 
     def _query_impl(self, x, effective, *, fft_shape, out_shape, encode,
                     slm_bits):
@@ -1326,10 +1536,14 @@ class QueryEngine:
             pool = self._pool_for(members, shards)
             xs = [requests[i][1] for i in idxs]
             gkeys = [keys[i] for i in idxs]
+            starts = [pool.o_start[s] for s in slot_of]
             if mesh is not None:
-                lay = self._mesh_layout(pool, gratings, slot_of, gkeys)
+                lay = _fanout_layout(starts, gkeys, int(pool.re.shape[0]))
             else:
-                lay = self._dedup_layout(pool, gratings, slot_of, gkeys)
+                lay = _span_layout(
+                    starts, [g.n_out for g in gratings], gkeys, pool.align,
+                    int(pool.re.shape[0]),
+                )
             ux = [xs[j] for j in lay.uniq]
             x = ux[0] if len(ux) == 1 else jnp.concatenate(ux, axis=0)
             nbs = [int(xj.shape[0]) for xj in ux]
@@ -1378,109 +1592,6 @@ class QueryEngine:
             return list(clip_keys)
         return clip_keys_for([x for _, x in requests])
 
-    def _dedup_layout(
-        self,
-        pool: GratingPool,
-        gratings: list[FusedGrating],
-        slot_of: list[int],
-        keys: list,
-    ) -> "_DedupLayout":
-        """Collapse group rows with content-equal clips onto shared
-        physical rows.
-
-        Each unique clip gets one physical row whose O-window is the
-        *union span* of every member slice requested for that clip
-        (member slots pack contiguously, so the span is one aligned
-        ``[lo, lo + n_out)`` read; tenants between two requested slots
-        are computed and discarded — wasted rows are bounded by the
-        arena, and in the canonical all-tenants-one-stream batch the
-        span is exactly the whole arena).  ``n_out`` is the widest span,
-        rounded to the pool's O-tile grid for the grouped Pallas kernel;
-        rows with narrower spans read tail rows the dispatch zero-pads
-        (:func:`_pad_arena`).
-
-        One static ``n_out`` for the whole dispatch is a deliberate
-        trade-off: the MAC/gather (dense or Pallas) needs a uniform
-        per-row width, so in a *mixed* batch (one wide shared-stream
-        span next to narrow unique rows) the narrow rows compute and
-        discard up to the widest span.  With no dedup, spans equal
-        member slots and this reduces exactly to the pre-dedup
-        ``pool.n_out`` behavior; ragged per-row widths or splitting
-        wide/narrow rows into separate dispatches would cost an extra
-        FFT dispatch per batch — the thing pooling exists to avoid.
-        """
-        uniq: list[int] = []
-        uniq_of: list[int] = []
-        by_key: dict[tuple, int] = {}
-        for j, k in enumerate(keys):
-            u = by_key.get(k) if k is not None else None
-            if u is None:
-                u = len(uniq)
-                uniq.append(j)
-                if k is not None:
-                    by_key[k] = u
-            uniq_of.append(u)
-        span_lo = [None] * len(uniq)
-        span_hi = [0] * len(uniq)
-        for j, u in enumerate(uniq_of):
-            s = pool.o_start[slot_of[j]]
-            e = s + gratings[j].n_out
-            span_lo[u] = s if span_lo[u] is None else min(span_lo[u], s)
-            span_hi[u] = max(span_hi[u], e)
-        n_out = max(hi - lo for lo, hi in zip(span_lo, span_hi))
-        n_out = -(-n_out // pool.align) * pool.align
-        o_off = [
-            pool.o_start[slot_of[j]] - span_lo[uniq_of[j]]
-            for j in range(len(uniq_of))
-        ]
-        return _DedupLayout(
-            uniq=uniq,
-            uniq_of=uniq_of,
-            row_of=span_lo,
-            o_off=o_off,
-            n_out=n_out,
-        )
-
-    def _mesh_layout(
-        self,
-        pool: GratingPool,
-        gratings: list[FusedGrating],
-        slot_of: list[int],
-        keys: list,
-    ) -> "_DedupLayout":
-        """Row layout of a mesh-sharded dispatch: full-arena fan-out.
-
-        With the arena's ΣO rows sharded over the model axis, the
-        offset-gather behind :meth:`_dedup_layout`'s union spans would
-        be a cross-shard read; instead every physical clip row computes
-        against the *entire* (sharded) arena — each model-axis device
-        contracts only its own ``shard_rows`` tile, psum-free — and a
-        request's answer is the slice of the global output at its
-        member slot's absolute ``o_start``.  Clip-dedup degenerates to
-        unique-clips-only (a shared physical row already reads every
-        tenant's slice), and the "wasted" inter-slot rows are exactly
-        the canonical all-tenants-one-stream batch
-        :meth:`_dedup_layout` documents, spread over M devices.
-        """
-        uniq: list[int] = []
-        uniq_of: list[int] = []
-        by_key: dict[tuple, int] = {}
-        for j, k in enumerate(keys):
-            u = by_key.get(k) if k is not None else None
-            if u is None:
-                u = len(uniq)
-                uniq.append(j)
-                if k is not None:
-                    by_key[k] = u
-            uniq_of.append(u)
-        return _DedupLayout(
-            uniq=uniq,
-            uniq_of=uniq_of,
-            row_of=[0] * len(uniq),
-            o_off=[pool.o_start[slot_of[j]] for j in range(len(uniq_of))],
-            n_out=int(pool.re.shape[0]),
-        )
-
     def query_stream_many(
         self,
         requests: "Sequence[tuple[FusedGrating, Array]]",
@@ -1491,7 +1602,8 @@ class QueryEngine:
         dedup: bool = True,
         readout_k: int | None = None,
         mesh=None,
-    ) -> "list[Array] | list[TopKDetections]":
+        whole_state: bool = False,
+    ) -> "list[Array] | list[TopKDetections] | list[PooledTopK]":
         """Pooled :meth:`query_stream`: one overlap-save pass per group.
 
         The streaming analogue of :meth:`query_many` — mixed-tenant long
@@ -1510,6 +1622,17 @@ class QueryEngine:
         stays per-example stream-global, so each request's output equals
         ``query_stream(grating_i, x_i)`` to float tolerance.
 
+        **Composition is runtime data.**  Each group reads the resident
+        arena of its pool group (:meth:`set_resident`; a grating not
+        declared there is admitted by rebuilding the arena once).  The
+        group's physical rows go to the program as single-row clips
+        padded with zero rows to a row bucket (:func:`_row_bucket`), and their arena
+        offsets as an int32 array; padding rows read row 0 and are
+        dropped.  The program returns the whole ``(bucket, n_out, …)``
+        output and each request's rows and kernels are sliced from it
+        outside the program, so any mix of resident tenants runs a
+        program compiled for its (bucket, ``n_out``).
+
         ``readout_k`` fuses the detection readout into the pooled
         epilogue (see :meth:`query_stream`): each request gets a
         :class:`TopKDetections` instead of a volume, and the pooled
@@ -1517,6 +1640,9 @@ class QueryEngine:
         large tenant pools — never materializes; only (rows, K) states
         cross window chunks and cursor segments.  Bitwise equal to
         reducing the stitched volumes, dedup union-slice rows included.
+        With ``whole_state`` each request gets a :class:`PooledTopK`
+        instead: the group's whole state and its own rows and kernels in
+        it, for a caller that copies each group's state to the host once.
 
         ``mesh`` switches every group dispatch to the sharded executor
         (see :meth:`query_many`): arena ΣO rows over the model axis,
@@ -1529,8 +1655,9 @@ class QueryEngine:
         with span("sthc.engine.layout"):
             groups = self._group_requests(requests, stream=True)
             keys = self._clip_ids(requests, clip_keys, dedup)
-        results: list[Array | None] = [None] * len(requests)
+        results: list = [None] * len(requests)
         shards = int(mesh.shape["model"]) if mesh is not None else 1
+        fused = readout_k is not None
         for idxs in groups.values():
             with span("sthc.engine.layout"):
                 gratings = [requests[i][0] for i in idxs]
@@ -1540,8 +1667,6 @@ class QueryEngine:
                         "grating lacks ker_shape (recorded by an older "
                         "engine); re-record before streaming queries"
                     )
-                members, slot_of = _dedup_members(gratings)
-                pool = self._pool_for(members, shards)
                 xs = [requests[i][1] for i in idxs]
                 kh, kw, kt = g0.ker_shape
                 oh, ow, _ = g0.out_shape
@@ -1551,90 +1676,80 @@ class QueryEngine:
                         f"clip spatial dims {tuple(xs[0].shape[-3:-1])} do "
                         f"not match the recorded frame size {frame_hw}"
                     )
+                gkeys = [keys[i] for i in idxs]
                 if mesh is not None:
-                    lay = self._mesh_layout(
-                        pool, gratings, slot_of, [keys[i] for i in idxs]
-                    )
+                    members, slot_of = _dedup_members(gratings)
+                    pool = self._pool_for(members, shards)
+                    starts = [pool.o_start[s] for s in slot_of]
+                    lay = _fanout_layout(starts, gkeys, int(pool.re.shape[0]))
+                    # full-arena fan-out: planes live on the mesh, rows on
+                    # 'model'
+                    pool_re, pool_im = self._mesh_arena(pool, mesh)
                 else:
-                    lay = self._dedup_layout(
-                        pool, gratings, slot_of, [keys[i] for i in idxs]
+                    arena = self._resident_arena(gratings)
+                    pool = arena.pool
+                    starts = [pool.o_start[arena.slot[id(g)]] for g in gratings]
+                    lay = _span_layout(
+                        starts, [g.n_out for g in gratings], gkeys,
+                        pool.align, int(pool.re.shape[0]),
                     )
+                    pool_re, pool_im = pool.re, pool.im
                 ux = [xs[j] for j in lay.uniq]
                 nbs = [int(xj.shape[0]) for xj in ux]
                 ub0 = [0]
                 for nb in nbs:
                     ub0.append(ub0[-1] + nb)
-                rows = tuple(
-                    r for u, nb in enumerate(nbs) for r in [lay.row_of[u]] * nb
-                )
-                # per-REQUEST output splits: several requests may read
-                # different O-windows of one shared physical row
-                splits = tuple(
-                    (
-                        ub0[lay.uniq_of[j]],
-                        int(xs[j].shape[0]),
-                        lay.o_off[j],
-                        gratings[j].n_out,
-                    )
-                    for j in range(len(idxs))
-                )
-                self._count_pooled(
-                    sum(int(xj.shape[0]) for xj in xs), sum(nbs)
-                )
-                if mesh is not None:
-                    # GSPMD mis-lowers a concatenate traced inside jit when
-                    # its result feeds a shard_map input on a 2-axis mesh —
-                    # each model shard receives the model-axis SUM of its
-                    # rows — so the physical batch is packed eagerly here
-                    # and the sharded drivers take exactly one array
-                    if len(ux) > 1:
-                        ux = [jnp.concatenate(ux, axis=0)]
-                    # full-arena fan-out: the shard-tiled arena is read
-                    # whole (lay.n_out == its row count), so no padded view
-                    # is needed; planes live on the mesh, rows on 'model'
-                    pool_re, pool_im = self._mesh_arena(pool, mesh)
-                else:
-                    # union spans can read past the arena tail: fetch the
-                    # (memoized) padded view so the jitted body never
-                    # gathers out of bounds
-                    max_row = max(lay.row_of) if lay.row_of else 0
-                    pool_re, pool_im = self._padded_arena(
-                        pool, max_row, lay.n_out
-                    )
                 plan = self.stream_plan_for(g0, xs[0].shape[-1], chunk_windows)
                 mbw = self._max_buffer_windows(max_buffer_windows)
                 static = dict(
-                    rows=rows,
-                    splits=splits,
                     ker_shape=g0.ker_shape,
                     fft_shape=g0.fft_shape,
                     encode=g0.encode,
                     slm_bits=g0.slm_bits,
                     n_out=lay.n_out,
                 )
-                fused = readout_k is not None
+                if fused:
+                    static["k"] = int(readout_k)
+                stream_out = (oh, ow, plan.n_valid)
+            with span("sthc.engine.compose"):
                 if mesh is not None:
                     fns = self._mesh_fns(mesh)
                     many_fn = fns["stream_topk"] if fused else fns["stream"]
+                    # GSPMD mis-lowers a concatenate traced inside jit when
+                    # its result feeds a shard_map input on a 2-axis mesh —
+                    # each model shard receives the model-axis SUM of its
+                    # rows — so the physical batch is packed eagerly here
+                    # and the sharded drivers take exactly one array
+                    batch = [ux[0] if len(ux) == 1 else jnp.concatenate(ux)]
+                    n_rows = ub0[-1]
+                    dsize = int(mesh.shape["data"])
+                    n_pad = -(-n_rows // dsize) * dsize - n_rows
+                    args = ()
                 else:
                     many_fn = (
                         self._stream_many_topk_fn
                         if fused
                         else self._stream_many_fn
                     )
-                if fused:
-                    static["k"] = int(readout_k)
-                oh, ow, _ = g0.out_shape
-                stream_out = (oh, ow, plan.n_valid)
+                    batch = [
+                        xj if xj.shape[0] == 1 else xj[r : r + 1]
+                        for xj in ux
+                        for r in range(int(xj.shape[0]))
+                    ]
+                    n_rows = len(batch)
+                    n_pad = _row_bucket(n_rows) - n_rows
+                    rows = np.zeros((n_rows + n_pad,), np.int32)
+                    rows[:n_rows] = np.repeat(lay.row_of, nbs)
+                    args = (rows,)
+                self._count_pooled(
+                    sum(int(xj.shape[0]) for xj in xs), n_rows, n_pad
+                )
             with span("sthc.engine.dispatch"):
                 if mbw is None or plan.n_blocks <= mbw:
-                    outs = many_fn(
-                        tuple(ux), pool_re, pool_im, plan=plan, **static
+                    out = many_fn(
+                        self._pad_rows(batch, n_pad, mesh),
+                        pool_re, pool_im, *args, plan=plan, **static,
                     )
-                    if fused:
-                        outs = tuple(
-                            TopKDetections(s, ix, stream_out) for s, ix in outs
-                        )
                 else:
                     # bounded-memory chunked pass: stream-global SLM scales
                     # measured once, then every fixed-size segment rides the
@@ -1643,6 +1758,8 @@ class QueryEngine:
                     x_scale = None
                     if g0.encode:
                         scales = [_stream_scale(xj) for xj in ux]
+                        if mesh is None and n_pad:
+                            scales.append(jnp.ones((n_pad, 1, 1, 1, 1)))
                         x_scale = (
                             scales[0]
                             if len(scales) == 1
@@ -1653,48 +1770,126 @@ class QueryEngine:
                         seg_plan = spectral_conv.stream_plan(
                             seg.frames, kt, plan.block_t, plan.chunk
                         )
-                        so = many_fn(
-                            tuple(xj[..., seg.t0 : seg.t1] for xj in ux),
-                            pool_re,
-                            pool_im,
-                            x_scale,
-                            plan=seg_plan,
-                            **static,
-                        )
+                        seg_batch = [x[..., seg.t0 : seg.t1] for x in batch]
+                        seg_outs.append(many_fn(
+                            self._pad_rows(seg_batch, n_pad, mesh),
+                            pool_re, pool_im, *args, x_scale,
+                            plan=seg_plan, **static,
+                        ))
                         nv_locals.append(seg_plan.n_valid)
                         t0s.append(seg.out_t0)
-                        seg_outs.append(so)
                     if fused:
-                        # one jitted rebase+merge tail per request: local
-                        # positions land in the stream-global volume and the
-                        # (rows, K) states fold, without per-segment eager
-                        # dispatch overhead
-                        outs = tuple(
-                            TopKDetections(
-                                *self._seg_merge_fn(
-                                    tuple(so[r][0] for so in seg_outs),
-                                    tuple(so[r][1] for so in seg_outs),
-                                    k=int(readout_k),
-                                    nv_locals=tuple(nv_locals),
-                                    t0s=tuple(t0s),
-                                    nv_total=plan.n_valid,
-                                ),
-                                stream_out,
-                            )
-                            for r in range(len(splits))
+                        # one jitted rebase+merge tail per group: local
+                        # positions land in the stream-global volume and
+                        # the (rows, K) states fold, without per-segment
+                        # eager dispatch overhead
+                        out = self._seg_merge_fn(
+                            tuple(so[0] for so in seg_outs),
+                            tuple(so[1] for so in seg_outs),
+                            k=int(readout_k),
+                            nv_locals=tuple(nv_locals),
+                            t0s=tuple(t0s),
+                            nv_total=plan.n_valid,
                         )
                     else:
-                        outs = tuple(
-                            jnp.concatenate(
-                                [so[r] for so in seg_outs], axis=-1
-                            )
+                        out = (
+                            jnp.concatenate(seg_outs, axis=-1)
                             if len(seg_outs) > 1
-                            else seg_outs[0][r]
-                            for r in range(len(splits))
+                            else seg_outs[0]
                         )
             for j, i in enumerate(idxs):
-                results[i] = outs[j]
-        return results  # type: ignore[return-value]
+                b0 = ub0[lay.uniq_of[j]]
+                rsl = slice(b0, b0 + int(xs[j].shape[0]))
+                ksl = slice(lay.o_off[j], lay.o_off[j] + gratings[j].n_out)
+                if not fused:
+                    results[i] = out[rsl, ksl]
+                    continue
+                part = PooledTopK(out[0], out[1], stream_out, rsl, ksl)
+                results[i] = part if whole_state else part.take()
+        return results
+
+    def _pad_rows(self, batch: list, n_pad: int, mesh) -> tuple:
+        """A group's row batch as the program takes it: on one device, a
+        tuple of single-row device arrays (host rows start their copies
+        here, in one call), padded with device-resident zero rows
+        (padding uploads nothing) — every leaf a device array, so a host
+        row and a padding row never make two signatures of one program;
+        on a mesh, the one packed array."""
+        if mesh is not None:
+            return tuple(batch)
+        rows = tuple(jax.device_put(batch))
+        if not n_pad:
+            return rows
+        x = batch[0]
+        key = (tuple(x.shape), jnp.dtype(x.dtype).name)
+        with self._pools_lock:
+            zero = self._zero_rows.get(key)
+            if zero is not None:
+                self._zero_rows.move_to_end(key)
+        if zero is None:
+            # from the host: a transfer, where jnp.zeros would compile
+            zero = jax.device_put(np.zeros(x.shape, jnp.dtype(x.dtype)))
+            with self._pools_lock:
+                zero = self._zero_rows.setdefault(key, zero)
+                while len(self._zero_rows) > self._max_pools:
+                    self._zero_rows.popitem(last=False)
+        return rows + (zero,) * n_pad
+
+    # -- resident arenas (the pooled stream path) ---------------------------
+
+    def set_resident(self, gratings: "Sequence[FusedGrating]") -> None:
+        """Declare the gratings the pooled stream path keeps resident.
+
+        Each pool group (gratings sharing geometry, encode semantics,
+        storage and channels) gets one arena packed from its declared
+        gratings, in declaration order, on the first dispatch that needs
+        it; a group whose declared gratings did not change keeps its
+        arena.  The video-search server declares its tenants' gratings
+        whenever a tenant is added or removed or a grating is recorded
+        again.  A batch that brings an undeclared grating still runs:
+        its group's arena is rebuilt with it (``arena_builds`` in
+        :meth:`pool_stats` counts every packing)."""
+        by_key: dict[tuple, list[FusedGrating]] = {}
+        seen: set[int] = set()
+        for g in gratings:
+            if id(g) not in seen:
+                seen.add(id(g))
+                by_key.setdefault(_arena_key(g), []).append(g)
+        with self._pools_lock:
+            self._resident = by_key
+            for key, arena in list(self._arenas.items()):
+                ids = tuple(id(g) for g in by_key.get(key, ()))
+                if arena.declared != ids:
+                    del self._arenas[key]
+
+    def _resident_arena(self, gratings: list[FusedGrating]) -> _Arena:
+        """The resident arena of the gratings' pool group, rebuilt when
+        one of them is not in it: declared residents first, then the
+        batch's undeclared gratings (so undeclared members never
+        accumulate)."""
+        key = _arena_key(gratings[0])
+        with self._pools_lock:
+            arena = self._arenas.get(key)
+            if arena is not None and all(id(g) in arena.slot for g in gratings):
+                return arena
+            declared = list(self._resident.get(key, ()))
+            # drop the old arena before packing the new one: two arenas
+            # of a large group need not be live at once
+            self._arenas.pop(key, None)
+        ids = {id(g) for g in declared}
+        extra, _ = _dedup_members([g for g in gratings if id(g) not in ids])
+        members = declared + extra
+        arena = _Arena(
+            pool=_build_pool(
+                members, self._pool_align(), lanes=self._arena_lanes(members[0])
+            ),
+            slot={id(g): i for i, g in enumerate(members)},
+            declared=tuple(id(g) for g in declared),
+        )
+        with self._pools_lock:
+            self._arenas[key] = arena
+            self._arena_builds += 1
+        return arena
 
     def _group_requests(self, requests, stream: bool = False) -> dict:
         """Pool-group the requests: same FFT geometry + encode semantics
@@ -1738,6 +1933,22 @@ class QueryEngine:
             getattr(cfg, "stmul_block_o", None) or stmul_kernel.BLOCK_O
         )
 
+    def _arena_lanes(self, g: FusedGrating) -> int | None:
+        """Lane block a resident arena's bins are padded to: the grouped
+        Pallas kernel's frequency tile (it pads the bins of whatever it
+        reads to that tile, so an arena stored so is read as it is,
+        with no copy per dispatch); None on the dense path, which
+        gathers 5-D slices."""
+        cfg = self.config
+        if not getattr(cfg, "use_pallas", False):
+            return None
+        from repro.kernels.stmul import kernel as stmul_kernel  # lazy
+
+        fh, fw, ft = g.fft_shape
+        bins = fh * fw * (ft // 2 + 1)
+        block = getattr(cfg, "stmul_block_f", None) or stmul_kernel.BLOCK_F
+        return min(int(block), bins)
+
     def _pool_for(
         self, members: list[FusedGrating], shards: int = 1
     ) -> "GratingPool":
@@ -1766,29 +1977,6 @@ class QueryEngine:
             while len(self._pools) > self._max_pools:
                 self._pools.popitem(last=False)
         return pool
-
-    def _padded_arena(
-        self, pool: "GratingPool", max_row: int, n_out: int
-    ) -> tuple[Array, Array]:
-        """The pool planes, row-padded for ``[row, row + n_out)`` reads —
-        memoized per (pool, rows needed) so recurring dedup compositions
-        reuse one padded device buffer (the un-padded common case returns
-        the pool's own planes untouched)."""
-        need = int(max_row) + int(n_out) - int(pool.re.shape[0])
-        if need <= 0:
-            return pool.re, pool.im
-        key = (id(pool), int(max_row) + int(n_out))
-        with self._pools_lock:
-            hit = self._padded.get(key)
-            if hit is not None:
-                self._padded.move_to_end(key)
-                return hit[1], hit[2]
-        re, im = _pad_arena(pool.re, pool.im, max_row, n_out)
-        with self._pools_lock:
-            self._padded[key] = (pool, re, im)
-            while len(self._padded) > self._max_pools:
-                self._padded.popitem(last=False)
-        return re, im
 
     # -- mesh-sharded execution (query_many/query_stream_many mesh=) -------
 
@@ -1842,8 +2030,8 @@ class QueryEngine:
 
         Bitwise equality with the single-device path holds by
         construction: the shard body reuses ``_pooled_osave_setup`` /
-        ``_chunk_topk`` / ``_fold_chunk_states`` verbatim with
-        ``rows=(0,)*B_local`` over its local arena tile, so every
+        ``_chunk_topk`` / ``_fold_chunk_states`` verbatim with all-zero
+        row offsets over its local arena tile, so every
         (clip row, kernel row) element runs the exact op sequence —
         encode, one ``rfftn`` per stream row, the batched-sel MAC (or
         grouped Pallas launch), ``irfftn``, stitch or fused top-K — the
@@ -1877,8 +2065,8 @@ class QueryEngine:
 
         def pad_b(x, x_scale):
             """Zero-pad stream rows up to the data-axis size: pad rows
-            cost compute on their shard and are sliced away by the
-            per-request splits (scale pads to 1 — encode of an all-zero
+            cost compute on their shard and are dropped with the rest of
+            the whole output (scale pads to 1 — encode of an all-zero
             row divides by the same 1.0 the derived scale would use)."""
             b = int(x.shape[0])
             b_pad = -(-b // dsize) * dsize
@@ -1913,13 +2101,11 @@ class QueryEngine:
             return f(x, pool_re, pool_im, x_scale)
 
         def stream_many(
-            xs, pool_re, pool_im, x_scale=None, *, rows, splits,
+            xs, pool_re, pool_im, x_scale=None, *,
             ker_shape, fft_shape, plan, encode, slm_bits, n_out,
         ):
-            # `rows` rides the signature for trace-cache parity with the
-            # single-device driver but is all-zero in mesh mode (full-
-            # arena fan-out); `n_out` is the whole arena's row count.
-            del rows
+            # full-arena fan-out: every row reads the whole local tile
+            # (zero offsets); `n_out` is the whole arena's row count
             if len(xs) != 1:
                 raise ValueError(
                     "sharded stream drivers take one pre-packed batch "
@@ -1933,8 +2119,8 @@ class QueryEngine:
 
             def body(xl, prl, pil, xsl):
                 one_window, _, xs_l = self._pooled_osave_setup(
-                    (xl,), prl, pil, xsl,
-                    rows=(0,) * b_local, ker_shape=ker_shape,
+                    (xl,), prl, pil, jnp.zeros((b_local,), jnp.int32), xsl,
+                    ker_shape=ker_shape,
                     fft_shape=fft_shape, plan=plan, encode=encode,
                     slm_bits=slm_bits, n_out=s_local,
                 )
@@ -1947,16 +2133,12 @@ class QueryEngine:
                     y = y * xs_l
                 return y
 
-            y = run(body, x, pool_re, pool_im, x_scale, P("data", "model"))
-            return tuple(
-                y[b0 : b0 + nb, oo : oo + o] for b0, nb, oo, o in splits
-            )
+            return run(body, x, pool_re, pool_im, x_scale, P("data", "model"))
 
         def stream_many_topk(
-            xs, pool_re, pool_im, x_scale=None, *, rows, splits,
+            xs, pool_re, pool_im, x_scale=None, *,
             ker_shape, fft_shape, plan, encode, slm_bits, n_out, k,
         ):
-            del rows
             if len(xs) != 1:
                 raise ValueError(
                     "sharded stream drivers take one pre-packed batch "
@@ -1971,8 +2153,8 @@ class QueryEngine:
 
             def body(xl, prl, pil, xsl):
                 one_window, win_out, xs_l = self._pooled_osave_setup(
-                    (xl,), prl, pil, xsl,
-                    rows=(0,) * b_local, ker_shape=ker_shape,
+                    (xl,), prl, pil, jnp.zeros((b_local,), jnp.int32), xsl,
+                    ker_shape=ker_shape,
                     fft_shape=fft_shape, plan=plan, encode=encode,
                     slm_bits=slm_bits, n_out=s_local,
                 )
@@ -1988,11 +2170,7 @@ class QueryEngine:
                 return self._fold_chunk_states(chunk_s, chunk_i, k)
 
             spec = P("data", "model")
-            s, i = run(body, x, pool_re, pool_im, x_scale, (spec, spec))
-            return tuple(
-                (s[b0 : b0 + nb, oo : oo + o], i[b0 : b0 + nb, oo : oo + o])
-                for b0, nb, oo, o in splits
-            )
+            return run(body, x, pool_re, pool_im, x_scale, (spec, spec))
 
         def oneshot(
             x, pool_re, pool_im, x_scale=None, *, fft_shape,
@@ -2018,15 +2196,15 @@ class QueryEngine:
             "stream": jax.jit(
                 stream_many,
                 static_argnames=(
-                    "rows", "splits", "ker_shape", "fft_shape", "plan",
-                    "encode", "slm_bits", "n_out",
+                    "ker_shape", "fft_shape", "plan", "encode", "slm_bits",
+                    "n_out",
                 ),
             ),
             "stream_topk": jax.jit(
                 stream_many_topk,
                 static_argnames=(
-                    "rows", "splits", "ker_shape", "fft_shape", "plan",
-                    "encode", "slm_bits", "n_out", "k",
+                    "ker_shape", "fft_shape", "plan", "encode", "slm_bits",
+                    "n_out", "k",
                 ),
             ),
             "oneshot": oneshot,
@@ -2048,8 +2226,7 @@ class QueryEngine:
         cover union spans (default: the pool's slot width)."""
         if n_out is None:
             n_out = pool.n_out
-        max_row = int(np.max(rows)) if len(rows) else 0
-        pool_re, pool_im = self._padded_arena(pool, max_row, n_out)
+        pool_re, pool_im = pool.re, pool.im
         rows = jnp.asarray(rows, jnp.int32)
         query = self._pooled_query_fn()
         if not proto.encode:
@@ -2065,42 +2242,42 @@ class QueryEngine:
         return y * x_scale
 
     def _stream_many_impl(
-        self, xs, pool_re, pool_im, x_scale=None,
-        *, rows, splits, ker_shape, fft_shape, plan, encode, slm_bits, n_out,
+        self, xs, pool_re, pool_im, rows, x_scale=None,
+        *, ker_shape, fft_shape, plan, encode, slm_bits, n_out,
     ):
         """Pooled overlap-save body (jitted; mirrors ``_stream_impl``).
 
-        ``xs`` is the tuple of per-physical-copy clip batches (stacked
-        in-trace so the eager path dispatches nothing; clip-dedup means
-        one entry may serve several requests); ``rows`` the static
-        per-row arena offsets, ``splits`` the static per-request
-        ``(b0, nb, o_off, O_i)`` output partition (``o_off`` slices the
-        request's O-window out of its shared row's union span).
+        ``xs`` is the tuple of single-row clips of the group's physical
+        rows, padded to the row bucket (stacked in-trace so the eager
+        path dispatches nothing; clip-dedup means one row may serve
+        several requests); ``rows`` the int32 per-row arena offsets.
         ``x_scale`` carries precomputed stream-global SLM scales when
-        the clips are cursor segments of longer streams."""
+        the clips are cursor segments of longer streams.  Returns the
+        whole ``(rows, n_out, H', W', T')`` volume; callers slice each
+        request's rows and O-window from it."""
+        self._count_stream_trace()
         one_window, win_out, x_scale = self._pooled_osave_setup(
-            xs, pool_re, pool_im, x_scale,
-            rows=rows, ker_shape=ker_shape, fft_shape=fft_shape,
-            plan=plan, encode=encode, slm_bits=slm_bits, n_out=n_out,
+            xs, pool_re, pool_im, rows, x_scale,
+            ker_shape=ker_shape, fft_shape=fft_shape, plan=plan,
+            encode=encode, slm_bits=slm_bits, n_out=n_out,
         )
         starts = spectral_conv.window_starts(plan)
         blocks = lax.map(lambda cs: jax.vmap(one_window)(cs), starts)
         y = spectral_conv.stitch_windows(blocks, plan)
         if x_scale is not None:
             y = y * x_scale
-        return tuple(
-            y[b0 : b0 + nb, oo : oo + o] for b0, nb, oo, o in splits
-        )
+        return y
 
     def _pooled_osave_setup(
-        self, xs, pool_re, pool_im, x_scale,
-        *, rows, ker_shape, fft_shape, plan, encode, slm_bits, n_out,
+        self, xs, pool_re, pool_im, rows, x_scale,
+        *, ker_shape, fft_shape, plan, encode, slm_bits, n_out,
     ):
         """Shared front half of the pooled overlap-save bodies: stack
-        the per-copy clips, encode (stream-global scale), pad the time
+        the per-row clips, encode (stream-global scale), pad the time
         axis and build the per-window pooled query closure (grouped
         Pallas launch under ``use_pallas``, hoisted-gather einsum
-        otherwise).  Returns (one_window, win_out, x_scale)."""
+        otherwise) reading arena rows ``[rows[b], rows[b] + n_out)``.
+        Returns (one_window, win_out, x_scale)."""
         x = xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=0)
         rows = jnp.asarray(rows, jnp.int32)
         kh, kw, kt = ker_shape
@@ -2137,9 +2314,8 @@ class QueryEngine:
         return one_window, win_out, x_scale
 
     def _stream_many_topk_impl(
-        self, xs, pool_re, pool_im, x_scale=None,
-        *, rows, splits, ker_shape, fft_shape, plan, encode, slm_bits,
-        n_out, k,
+        self, xs, pool_re, pool_im, rows, x_scale=None,
+        *, ker_shape, fft_shape, plan, encode, slm_bits, n_out, k,
     ):
         """Fused-readout pooled overlap-save body (jitted): the window
         loop of ``_stream_many_impl`` with the stitch replaced by the
@@ -2147,12 +2323,14 @@ class QueryEngine:
         volume (the serving memory ceiling at large tenant pools) never
         materializes.  Per-request slicing commutes with the per-(row,
         kernel) reduction, so dedup union-span states split exactly like
-        volumes.  Returns a tuple of (scores, index) per request,
-        positions local to this call's valid range."""
+        volumes.  Returns the group's whole (scores, index) state, each
+        ``(rows, n_out, k)``, positions local to this call's valid
+        range."""
+        self._count_stream_trace()
         one_window, win_out, x_scale = self._pooled_osave_setup(
-            xs, pool_re, pool_im, x_scale,
-            rows=rows, ker_shape=ker_shape, fft_shape=fft_shape,
-            plan=plan, encode=encode, slm_bits=slm_bits, n_out=n_out,
+            xs, pool_re, pool_im, rows, x_scale,
+            ker_shape=ker_shape, fft_shape=fft_shape, plan=plan,
+            encode=encode, slm_bits=slm_bits, n_out=n_out,
         )
         readout = self._readout_fn()
 
@@ -2164,11 +2342,7 @@ class QueryEngine:
 
         starts = spectral_conv.window_starts(plan)
         chunk_s, chunk_i = lax.map(one_chunk, starts)
-        s, i = self._fold_chunk_states(chunk_s, chunk_i, k)
-        return tuple(
-            (s[b0 : b0 + nb, oo : oo + o], i[b0 : b0 + nb, oo : oo + o])
-            for b0, nb, oo, o in splits
-        )
+        return self._fold_chunk_states(chunk_s, chunk_i, k)
 
     def _pooled_query_fn(self):
         """The per-group pooled FFT+MAC+IFFT: dense offset-gather einsum
@@ -2511,6 +2685,14 @@ class GratingCache:
             return True
         fresh = _grating_checksum(grating)
         return abs(fresh - expect) <= 1e-3 * max(abs(expect), 1.0)
+
+    def peek(self, key: tuple | None) -> FusedGrating | None:
+        """The resident grating under ``key``, or None; records nothing
+        and moves no counter or LRU position."""
+        if key is None:
+            return None
+        with self._lock:
+            return self._entries.get(key)
 
     def discard(self, key: tuple | None) -> bool:
         """Explicitly invalidate one entry (tenant removal) — frees its

@@ -137,7 +137,9 @@ def _mac_grouped_planes(
     block_o: int | None,
     block_f: int | None,
 ) -> tuple[Array, Array]:
-    """:func:`spectral_mac_grouped` on split (real, imaginary) planes."""
+    """:func:`spectral_mac_grouped` on split (real, imaginary) planes.
+    The arena planes are (ΣO, C, *bins) or, stored flat and padded to
+    the kernel's lane block, (ΣO, C, F_pad)."""
     tiles = _tile_kwargs(None, block_o, block_f)
     fshape = xr.shape[2:]
     B, C = xr.shape[:2]
@@ -145,11 +147,17 @@ def _mac_grouped_planes(
     for n in fshape:
         f *= n
     so = pool_re.shape[0]
+    if pool_re.ndim != 3:
+        # a 5-D arena: flatten its bins here (and the kernel pads them to
+        # its lane block at every call); a resident arena comes stored
+        # flat and lane-padded, and is read as it is
+        pool_re = pool_re.reshape(so, C, f)
+        pool_im = pool_im.reshape(so, C, f)
     yr, yi = _kernel.spectral_mac_grouped_pallas(
         xr.reshape(B, C, f).astype(jnp.float32),
         xi.reshape(B, C, f).astype(jnp.float32),
-        pool_re.reshape(so, C, f),
-        pool_im.reshape(so, C, f),
+        pool_re,
+        pool_im,
         jnp.asarray(o_start, jnp.int32),
         n_out=int(n_out),
         min_mxu_c=min_mxu_c,

@@ -179,6 +179,7 @@ from repro.core import hybrid
 from repro.core.engine import (
     TOPK_EMPTY_IDX,
     GratingCache,
+    PooledTopK,
     clip_key,
     clip_keys_for,
 )
@@ -371,6 +372,9 @@ class _Tenant:
     fidelity_label: str = ""
     # display label of the tenant's device model (SLM / atoms overrides)
     device_label: str = "default"
+    # the grating last declared resident to the pooled engine (None: not
+    # in the cache when the server last declared its residents)
+    resident: Any = None
     queries: int = 0
     windows: int = 0
     frames: int = 0
@@ -625,6 +629,7 @@ class VideoSearchServer:
         # local tenant object so a racing remove_tenant(name) can't
         # invalidate the lookup mid-warm
         self._fetch_grating(name, ten)
+        self._declare_resident()
         return self
 
     # The serving-API name for tenant registration: a tenant *is* a named
@@ -642,6 +647,24 @@ class VideoSearchServer:
             ten = self._tenants.pop(name)
             self._discard_if_unreferenced(ten.key)
             self._retire(ten)
+        self._declare_resident()
+
+    def _declare_resident(self) -> None:
+        """Hand the pooled engine the gratings of every tenant the cache
+        holds: each pool group's resident arena is packed from them, so
+        it changes only when a tenant is added or removed or a grating
+        is recorded again (after an eviction or an integrity failure).
+        Tenants the cache does not hold are left out, so the arenas stay
+        within the cache's budget."""
+        if self.mesh is not None:  # the mesh path packs its own arenas
+            return
+        gratings = []
+        with self._lock:
+            for ten in self._tenants.values():
+                ten.resident = self.cache.peek(ten.key)
+                if ten.resident is not None:
+                    gratings.append(ten.resident)
+        self.sthc.engine.set_resident(gratings)
 
     def _retire(self, ten: _Tenant) -> None:  # holds-lock: _lock
         # fold a departing tenant's traffic into the server-wide totals
@@ -800,6 +823,11 @@ class VideoSearchServer:
                     self._fetch_grating(key[0], ten)
                     for (key, _), ten in zip(order, tens)
                 ]
+                if self.mesh is None and any(
+                    g is not ten.resident for g, ten in zip(gratings, tens)
+                ):
+                    # a grating recorded again since the last declaration
+                    self._declare_resident()
             # per-group clip identities for the shared-stream dedup: a
             # stacked group's identity is the tuple of its members'
             # content hashes (hashed once per distinct array object —
@@ -820,9 +848,9 @@ class VideoSearchServer:
             if self.chaos is not None:  # chaos seam: pooled dispatch
                 self.chaos.on("dispatch", mode="pooled")
             if fused:
-                # fused readout: the pooled dispatch itself returns the
-                # per-request top-K states — no volume, no separate
-                # readout launch
+                # fused readout: the pooled dispatch itself returns each
+                # pool group's whole top-K state, and where each request's
+                # rows lie in it — no volume, no separate readout launch
                 fmaps = None
                 dets = self.sthc.engine.query_stream_many(
                     list(zip(gratings, stacks)),
@@ -830,6 +858,7 @@ class VideoSearchServer:
                     dedup=dedup,
                     readout_k=topk,
                     mesh=self.mesh,
+                    whole_state=True,
                 )
                 with span("sthc.search.wait"):
                     jax.block_until_ready(
@@ -873,7 +902,10 @@ class VideoSearchServer:
                     )
                     with span("sthc.search.wait"):
                         jax.block_until_ready((det.scores, det.index))
-                    dets.append(det)
+                    whole = slice(None)
+                    dets.append(PooledTopK(
+                        det.scores, det.index, det.out_shape, whole, whole
+                    ))
                 else:
                     fmap = ten.sthc.engine.query_stream(grating, clips)
                     # honest serving latency
@@ -918,6 +950,12 @@ class VideoSearchServer:
                     tgt.windows += plans[g_i].n_blocks * n_streams
                     tgt.frames += int(clips.shape[-1]) * n_streams
             guard = getattr(self.cfg, "guard_scores", True)
+            if fused:
+                # copy each pool group's (rows, n_out, K) state to the
+                # host once, in one transfer, and slice every request's
+                # rows and kernels there
+                states = {id(d.scores): (d.scores, d.index) for d in dets}
+                host = dict(zip(states, jax.device_get(list(states.values()))))
             for g_i, ((key, idxs), clips) in enumerate(zip(order, stacks)):
                 tenant = key[0]
                 plan = plans[g_i]
@@ -929,11 +967,9 @@ class VideoSearchServer:
                     # state's recorded valid-T extent — no volume anywhere
                     det = dets[g_i]
                     tmod = int(det.out_shape[-1])
-                    # transfer the tiny (B, O, K) state once and slice on
-                    # the host — a device-side [..., 0] would be one more
-                    # dispatch per request on the hot path
-                    state_s = np.asarray(det.scores)
-                    state_i = np.asarray(det.index)
+                    whole_s, whole_i = host[id(det.scores)]
+                    state_s = whole_s[det.rows, det.kernels]
+                    state_i = whole_i[det.rows, det.kernels]
                     peak = state_s[..., 0]
                     idx = state_i[..., 0]
                     if topk > 1:
@@ -1024,21 +1060,29 @@ class VideoSearchServer:
             key = (tenant, clip.shape[1:], jnp.dtype(clip.dtype))
             groups.setdefault(key, []).append(i)
 
-        # one stacked clip batch per tenant-group, in *canonical* group
-        # order: the pooled executor bakes the batch composition into
-        # its jitted trace, so permutations of the same tenant mix must
-        # map to one composition, not one retrace each
+        # one stacked clip batch per tenant-group, groups sorted by
+        # tenant: the order fixes only which row of a pool group's batch
+        # each request takes — the pooled executor takes the
+        # composition as runtime data, so no order retraces anything
         order = sorted(
             groups.items(), key=lambda kv: (kv[0][0], str(kv[0][1:]))
         )
         tens = [tenants[key[0]] for key, _ in order]
-        stacks = [
-            requests[idxs[0]][1]  # single request: no device copy
-            if len(idxs) == 1
-            else jnp.concatenate([requests[i][1] for i in idxs], axis=0)
-            for _, idxs in order
-        ]
+        stacks = [self._stack([requests[i][1] for i in idxs])
+                  for _, idxs in order]
         return order, tens, stacks
+
+    @staticmethod
+    def _stack(clips: list):
+        """One tenant-group's clips on the batch axis: a single request
+        as it came (no copy), host clips stacked on the host (they go to
+        the device row by row with the pooled dispatch), device clips on
+        the device."""
+        if len(clips) == 1:
+            return clips[0]
+        if all(isinstance(c, np.ndarray) for c in clips):
+            return np.concatenate(clips, axis=0)
+        return jnp.concatenate(clips, axis=0)
 
     # -- observability -----------------------------------------------------
 

@@ -1,0 +1,270 @@
+"""A batch's composition is runtime data on the pooled stream path: any
+mix of resident tenants is answered right by programs compiled once per
+(pool group, row bucket), from one resident arena per pool group."""
+
+import sys
+import threading
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.core import fidelity as fid
+from repro.core.engine import QueryEngine, _row_bucket
+from repro.core.sthc import STHCConfig
+from repro.launch.serve import VideoSearchConfig, VideoSearchServer
+
+FRAME_HW = (12, 16)
+SIGNAL = (12, 16, 8)  # window geometry the gratings are recorded at
+N_TENANTS = 12
+FRAMES = 24
+# the pooled program and query_stream are two differently fused float32
+# programs over the same arithmetic: scores agree to a few ulps of the
+# request's largest score, not bitwise
+RTOL = 1e-5
+
+
+def _engines(use_pallas: bool):
+    """(the pooled engine, per-tenant engines, per-tenant gratings): 12
+    seeded tenants alternating ideal and physical, as the served cell
+    alternates them by popularity rank."""
+    rng = np.random.RandomState(11)
+    ideal = QueryEngine(STHCConfig(fidelity=fid.ideal(), use_pallas=use_pallas,
+                                   osave_chunk_windows=2))
+    phys = QueryEngine(STHCConfig(fidelity=fid.physical(),
+                                  use_pallas=use_pallas,
+                                  osave_chunk_windows=2))
+    engines, gratings = [], []
+    for t in range(N_TENANTS):
+        eng = ideal if t % 2 == 0 else phys
+        k = jnp.asarray(rng.randn(3, 1, 4, 6, 3).astype(np.float32))
+        engines.append(eng)
+        gratings.append(eng.record(k, SIGNAL))
+    ideal.set_resident(gratings)
+    return ideal, engines, gratings
+
+
+def _streams(n: int, seed: int = 3) -> list[np.ndarray]:
+    rng = np.random.RandomState(seed)
+    return [rng.rand(1, 1, *FRAME_HW, FRAMES).astype(np.float32)
+            for _ in range(n)]
+
+
+def _zipf(rng, n: int) -> list[int]:
+    rank = np.arange(1, N_TENANTS + 1, dtype=np.float64)
+    p = rank ** -1.1
+    return [int(t) for t in rng.choice(N_TENANTS, size=n, p=p / p.sum())]
+
+
+def _compositions(rng, streams):
+    """30 Zipf-drawn batches of (tenant, clip) requests.  Streams are
+    drawn with replacement from a small pool, so some batches share a
+    stream between tenants (dedup union spans); the first batches pin a
+    hot tenant twice on two streams, a hot tenant stacked twice in one
+    request, and a single-fidelity batch."""
+    out = [
+        [(0, streams[0]), (0, streams[1]), (1, streams[2])],
+        [(0, np.concatenate([streams[3], streams[4]])), (3, streams[5])],
+        [(0, streams[6]), (2, streams[7]), (4, streams[6]), (8, streams[0])],
+    ]
+    while len(out) < 30:
+        tenants = _zipf(rng, int(rng.randint(1, 9)))
+        out.append([(t, streams[int(rng.randint(len(streams)))])
+                    for t in tenants])
+    return out
+
+
+def _check(engines, gratings, batch, dets):
+    for (t, x), det in zip(batch, dets):
+        vol = np.asarray(engines[t].query_stream(gratings[t], jnp.asarray(x)))
+        flat = vol.reshape(vol.shape[0], vol.shape[1], -1)
+        peak = flat.max(-1)
+        scale = np.abs(flat).max(-1)
+        s = np.asarray(det.scores)[..., 0]
+        i = np.asarray(det.index)[..., 0]
+        assert s.shape == peak.shape
+        assert np.all(np.abs(s - peak) <= RTOL * scale), (t, s, peak)
+        # the served position holds the peak, up to the same tolerance
+        # (two positions within it of each other are a tie)
+        at = np.take_along_axis(flat, i[..., None], -1)[..., 0]
+        assert np.all(at >= peak - RTOL * scale), (t, at, peak)
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_zipf_compositions_match_each_request_alone(use_pallas):
+    """30 compositions through the runtime path: every request's top-1
+    equals its own query_stream's peak and position."""
+    pooled, engines, gratings = _engines(use_pallas)
+    streams = _streams(8)
+    rng = np.random.RandomState(5)
+    for batch in _compositions(rng, streams):
+        dets = pooled.query_stream_many(
+            [(gratings[t], x) for t, x in batch], readout_k=1
+        )
+        _check(engines, gratings, batch, dets)
+    stats = pooled.pool_stats()
+    assert stats["arena_builds"] == 2  # one per pool group, never again
+    assert stats["rows_saved"] > 0  # some batches shared a stream
+
+
+def test_whole_state_slices_equal_device_slices():
+    """``whole_state`` hands back the group state and each request's
+    rows and kernels in it; slicing it equals the split answer."""
+    pooled, _, gratings = _engines(False)
+    streams = _streams(4)
+    reqs = [(gratings[0], streams[0]), (gratings[2], streams[1]),
+            (gratings[1], streams[2]), (gratings[0], streams[3])]
+    split = pooled.query_stream_many(reqs, readout_k=2)
+    whole = pooled.query_stream_many(reqs, readout_k=2, whole_state=True)
+    for a, b in zip(split, whole):
+        assert b.scores.shape[0] == _row_bucket(b.scores.shape[0])
+        host = np.asarray(b.scores)[b.rows, b.kernels]
+        assert np.array_equal(np.asarray(a.scores), host)
+        assert np.array_equal(np.asarray(a.index),
+                              np.asarray(b.index)[b.rows, b.kernels])
+
+
+def test_stream_traces_stop_growing_once_buckets_are_warm():
+    pooled, _, gratings = _engines(False)
+    streams = _streams(8)
+    by_group = [list(range(0, N_TENANTS, 2)), list(range(1, N_TENANTS, 2))]
+    for tenants in by_group:  # rows 1..8: every bucket of each group
+        for n in range(1, 9):
+            pooled.query_stream_many(
+                [(gratings[tenants[r % len(tenants)]], streams[r])
+                 for r in range(n)],
+                readout_k=1,
+            )
+    warm = pooled.stream_traces
+    assert warm == 2 * 6  # buckets 1, 2, 3, 4, 6, 8 per pool group
+    rng = np.random.RandomState(9)
+    for _ in range(20):
+        tenants = _zipf(rng, 8)
+        order = rng.permutation(8)
+        pooled.query_stream_many(
+            [(gratings[t], streams[int(s)]) for t, s in zip(tenants, order)],
+            readout_k=1,
+        )
+    assert pooled.stream_traces == warm
+    assert pooled.pool_stats()["stream_traces"] == warm
+
+
+def test_undeclared_grating_is_admitted_by_one_rebuild():
+    pooled, engines, gratings = _engines(False)
+    extra = engines[0].record(
+        jnp.asarray(np.random.RandomState(2).randn(3, 1, 4, 6, 3)
+                    .astype(np.float32)),
+        SIGNAL,
+    )
+    engines.append(engines[0])
+    gratings.append(extra)
+    streams = _streams(2)
+    batch = [(N_TENANTS, streams[0]), (0, streams[1])]
+    for _ in range(2):
+        dets = pooled.query_stream_many(
+            [(gratings[t], x) for t, x in batch], readout_k=1
+        )
+        _check(engines, gratings, batch, dets)
+    assert pooled.pool_stats()["arena_builds"] == 1
+
+
+def test_concurrent_batches_and_declarations_stay_right():
+    """Eight threads serve batches while another declares the residents
+    in two orders over and over, so arenas are packed again under their
+    feet: every answer stays right and every thread finishes in time."""
+    pooled, engines, gratings = _engines(False)
+    streams = _streams(8)
+    batches = []
+    for seed in range(8):
+        rng = np.random.RandomState(seed)
+        batches.append([
+            [(t, streams[int(rng.randint(8))]) for t in _zipf(rng, 4)]
+            for _ in range(4)
+        ])
+    # compile every program and reference before the threads start
+    for runs in batches:
+        for batch in runs:
+            _check(engines, gratings, batch, pooled.query_stream_many(
+                [(gratings[t], x) for t, x in batch], readout_k=1))
+    errors: list = []
+    stop = threading.Event()
+
+    def serve(runs):
+        try:
+            for batch in runs:
+                dets = pooled.query_stream_many(
+                    [(gratings[t], x) for t, x in batch], readout_k=1
+                )
+                _check(engines, gratings, batch, dets)
+        except Exception as exc:  # noqa: BLE001 — reported below
+            errors.append(exc)
+
+    def declare():
+        while not stop.is_set():
+            pooled.set_resident(gratings[::-1])
+            pooled.set_resident(gratings)
+
+    workers = [threading.Thread(target=serve, args=(r,)) for r in batches]
+    declarer = threading.Thread(target=declare)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        declarer.start()
+        for w in workers:
+            w.start()
+        for w in workers:
+            w.join(timeout=300)
+    finally:
+        stop.set()
+        declarer.join(timeout=30)
+        sys.setswitchinterval(interval)
+    assert not any(w.is_alive() for w in workers)
+    assert not declarer.is_alive()
+    assert not errors, errors
+
+
+def _server(n: int) -> VideoSearchServer:
+    cfg = VideoSearchConfig(window_frames=8, chunk_windows=2,
+                            cache_entries=16)
+    server = VideoSearchServer(frame_hw=FRAME_HW, cfg=cfg)
+    rng = np.random.RandomState(4)
+    for t in range(n):
+        server.add_tenant(
+            f"t{t}", rng.randn(3, 1, 4, 6, 3).astype(np.float32),
+            fidelity=fid.ideal() if t % 2 == 0 else fid.physical(),
+        )
+    return server
+
+
+def _answers_right(server, requests):
+    """Each pooled answer equals the tenant's own sequential search."""
+    pooled = server.search_batch(requests)
+    alone = server.search_batch(requests, pooled=False)
+    for a, b in zip(pooled, alone):
+        scale = np.abs(b["scores"]).max()
+        assert np.all(np.abs(a["scores"] - b["scores"]) <= RTOL * scale)
+
+
+def test_adding_or_removing_a_tenant_rebuilds_its_arena_once():
+    server = _server(6)
+    engine = server.sthc.engine
+    streams = _streams(4)
+    reqs = [("t0", streams[0]), ("t1", streams[1]), ("t2", streams[2])]
+    _answers_right(server, reqs)
+    builds = engine.pool_stats()["arena_builds"]
+    assert builds == 2  # one arena per pool group
+    _answers_right(server, reqs)
+    assert engine.pool_stats()["arena_builds"] == builds
+    # a new ideal tenant: only the ideal group's arena is packed again
+    server.add_tenant(
+        "t6", np.random.RandomState(8).randn(3, 1, 4, 6, 3).astype(np.float32),
+        fidelity=fid.ideal(),
+    )
+    reqs.append(("t6", streams[3]))
+    _answers_right(server, reqs)
+    assert engine.pool_stats()["arena_builds"] == builds + 1
+    _answers_right(server, reqs)
+    assert engine.pool_stats()["arena_builds"] == builds + 1
+    server.remove_tenant("t4")
+    _answers_right(server, reqs)
+    assert engine.pool_stats()["arena_builds"] == builds + 2
