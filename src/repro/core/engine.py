@@ -239,9 +239,10 @@ class GratingPool:
       re / im: split real/imag planes of the arena, in the members'
         storage dtype (bf16 gratings stay bf16 in HBM; the MAC up-casts
         tiles to f32 — f32 accumulation either way): (ΣO_pad, C, FH,
-        FW, FTr), or (ΣO_pad, C, F_pad) with the bins flattened and
-        lane-padded where the grouped Pallas kernel serves the arena
-        (the resident arenas of the pooled stream path).
+        FW, FTr), or lane planes (ΣO_pad, C, FTr·Hp, Wp)
+        (``spectral_conv.to_lane_planes``) where the grouped Pallas
+        kernel serves the arena (the resident arenas of the pooled
+        stream path).
       o_start: per-member first-row offset.  Member slots are padded to
         ``align`` rows (the Pallas grouped kernel indexes the arena in
         O-tile units; the dense gather path uses align=1), and the arena
@@ -426,30 +427,18 @@ def _bin_members(slots: list[int], shards: int) -> tuple[list[int], int]:
     return bin_of, max(load) if load else 0
 
 
-def _lane_planes(
-    re: Array, im: Array, lanes: int
-) -> tuple[Array, Array]:
-    """A grating's (O, C, FH, FW, FTr) planes as (O, C, F_pad): bins
-    flattened and zero-padded to a multiple of ``lanes``, the layout the
-    grouped Pallas kernel reads."""
-    o, c = int(re.shape[0]), int(re.shape[1])
-    f = int(np.prod(re.shape[2:]))
-    widths = [(0, 0), (0, 0), (0, -f % lanes)]
-    return (jnp.pad(re.reshape(o, c, f), widths),
-            jnp.pad(im.reshape(o, c, f), widths))
-
-
 def _build_pool(
     members: list[FusedGrating],
     align: int,
     shards: int = 1,
-    lanes: int | None = None,
+    lanes: bool = False,
 ) -> GratingPool:
     """Pack member gratings' planes into one arena (see GratingPool).
 
-    ``lanes`` packs the planes flat and lane-padded (:func:`_lane_planes`)
-    so that the grouped kernel reads the arena as it is stored; without
-    it the arena keeps the gratings' 5-D bins.
+    ``lanes`` packs the planes as lane planes
+    (:func:`spectral_conv.to_lane_planes`), the bin layout that the
+    grouped kernel and the inverse transform read as it is stored;
+    without it the arena keeps the gratings' 5-D bins.
 
     ``shards > 1`` makes the packing mesh-aware: members are binned
     into ``shards`` equal tiles of ``shard_rows`` rows (every tile
@@ -466,8 +455,11 @@ def _build_pool(
                 f"{[m.channels for m in members]}"
             )
     planes = [g.planes for g in members]
-    if lanes is not None:
-        planes = [_lane_planes(re, im, lanes) for re, im in planes]
+    if lanes:
+        planes = [
+            spectral_conv.to_lane_planes(re, im, g.fft_shape)
+            for (re, im), g in zip(planes, members)
+        ]
     slots = [
         -(-int(re.shape[0]) // align) * align for re, _ in planes
     ]
@@ -907,6 +899,9 @@ class QueryEngine:
         self._pooled_rows_offered = 0  # guarded-by: _pools_lock
         self._pooled_rows_dispatched = 0  # guarded-by: _pools_lock
         self._pooled_rows_padded = 0  # guarded-by: _pools_lock
+        # pooled dispatches whose MAC output the inverse transform read
+        # in the kernel's own layout (an arena of lane planes)
+        self._native_layout_dispatches = 0  # guarded-by: _pools_lock
 
     def pool_stats(self) -> dict:
         """Pooled-executor counters for serving metrics: how many clip
@@ -914,7 +909,9 @@ class QueryEngine:
         rows that carried a request (``rows_dispatched``) and rows that
         only padded a batch to its bucket (``rows_padded``); resident
         arenas packed (``arena_builds``) and pooled stream programs
-        traced (``stream_traces``)."""
+        traced (``stream_traces``); dispatches whose MAC output the
+        inverse transform read in the grouped kernel's lane-plane layout
+        (``native_layout_dispatches``)."""
         with self._trace_lock:
             traces = self._stream_traces
         with self._pools_lock:
@@ -928,13 +925,16 @@ class QueryEngine:
                 "rows_padded": self._pooled_rows_padded,
                 "arena_builds": self._arena_builds,
                 "stream_traces": traces,
+                "native_layout_dispatches": self._native_layout_dispatches,
             }
 
     def _count_pooled(
-        self, offered: int, dispatched: int, padded: int = 0
+        self, offered: int, dispatched: int, padded: int = 0,
+        native: bool = False,
     ) -> None:
         with self._pools_lock:
             self._pooled_dispatches += 1
+            self._native_layout_dispatches += int(native)
             self._pooled_rows_offered += int(offered)
             self._pooled_rows_dispatched += int(dispatched)
             self._pooled_rows_padded += int(padded)
@@ -1742,7 +1742,8 @@ class QueryEngine:
                     rows[:n_rows] = np.repeat(lay.row_of, nbs)
                     args = (rows,)
                 self._count_pooled(
-                    sum(int(xj.shape[0]) for xj in xs), n_rows, n_pad
+                    sum(int(xj.shape[0]) for xj in xs), n_rows, n_pad,
+                    native=pool_re.ndim == 4,
                 )
             with span("sthc.engine.dispatch"):
                 if mbw is None or plan.n_blocks <= mbw:
@@ -1881,7 +1882,8 @@ class QueryEngine:
         members = declared + extra
         arena = _Arena(
             pool=_build_pool(
-                members, self._pool_align(), lanes=self._arena_lanes(members[0])
+                members, self._pool_align(),
+                lanes=bool(getattr(self.config, "use_pallas", False)),
             ),
             slot={id(g): i for i, g in enumerate(members)},
             declared=tuple(id(g) for g in declared),
@@ -1932,22 +1934,6 @@ class QueryEngine:
         return int(
             getattr(cfg, "stmul_block_o", None) or stmul_kernel.BLOCK_O
         )
-
-    def _arena_lanes(self, g: FusedGrating) -> int | None:
-        """Lane block a resident arena's bins are padded to: the grouped
-        Pallas kernel's frequency tile (it pads the bins of whatever it
-        reads to that tile, so an arena stored so is read as it is,
-        with no copy per dispatch); None on the dense path, which
-        gathers 5-D slices."""
-        cfg = self.config
-        if not getattr(cfg, "use_pallas", False):
-            return None
-        from repro.kernels.stmul import kernel as stmul_kernel  # lazy
-
-        fh, fw, ft = g.fft_shape
-        bins = fh * fw * (ft // 2 + 1)
-        block = getattr(cfg, "stmul_block_f", None) or stmul_kernel.BLOCK_F
-        return min(int(block), bins)
 
     def _pool_for(
         self, members: list[FusedGrating], shards: int = 1

@@ -178,6 +178,130 @@ def rfft3_dft(x: Array, s: Sequence[int]) -> Planes:
     return _cmatmul(re, im, _H_SPEC, c, -s_)
 
 
+# ---------------------------------------------------------------------------
+# Lane planes: the bin layout of the pooled search path
+# ---------------------------------------------------------------------------
+# The grouped Pallas MAC reads the resident arena and the stream spectra,
+# and writes its output, with the bins of an (FH, FW, FTr) grid stored as
+# (FTr·Hp, Wp) planes: row k·Hp + h, lane w, zero where h ≥ FH or w ≥ FW,
+# with Hp and Wp the grid's H and W rounded up to the TPU tile (8, 128).
+# On a TPU the (…, FTr·Hp, Wp) array is the tiled (…, FTr, Hp, Wp) array
+# byte for byte, so the inverse transform reads the MAC's output as it
+# lies: H is contracted on the sublanes and W on the lanes, and nothing
+# is sliced or relayouted on the (…, n_out, bins) volume.  The padded
+# bins are zero in the spectra and meet zero rows of every DFT matrix.
+
+_SUBLANES, _LANES = 8, 128
+
+
+def lane_grid(s: Sequence[int]) -> tuple[int, int, int]:
+    """(FTr, Hp, Wp) of the lane planes of the rfft grid ``s``."""
+    h, w, n = (int(d) for d in s)
+    return n // 2 + 1, -(-h // _SUBLANES) * _SUBLANES, -(-w // _LANES) * _LANES
+
+
+def to_lane_planes(re: Array, im: Array, s: Sequence[int]) -> Planes:
+    """(…, FH, FW, FTr) spectrum planes as (…, FTr·Hp, Wp) lane planes."""
+    h, w, _ = (int(d) for d in s)
+    k, hp, wp = lane_grid(s)
+    widths = [(0, 0)] * (re.ndim - 3) + [(0, 0), (0, hp - h), (0, wp - w)]
+
+    def pack(p):
+        p = jnp.pad(jnp.moveaxis(p, -1, -3), widths)
+        return p.reshape(p.shape[:-3] + (k * hp, wp))
+
+    return pack(re), pack(im)
+
+
+def from_lane_planes(re: Array, im: Array, s: Sequence[int]) -> Planes:
+    """Inverse of :func:`to_lane_planes`: (…, FH, FW, FTr) planes."""
+    h, w, _ = (int(d) for d in s)
+    k, hp, wp = lane_grid(s)
+
+    def unpack(p):
+        p = p.reshape(p.shape[:-2] + (k, hp, wp))[..., :h, :w]
+        return jnp.moveaxis(p, -3, -1)
+
+    return unpack(re), unpack(im)
+
+
+@span("sthc.rfft")
+def rfft3_lanes(x: Array, s: Sequence[int]) -> Planes:
+    """:func:`rfft3_planes` in the lane-plane layout."""
+    if _use_dft():
+        return rfft3_lanes_dft(x, s)
+    return to_lane_planes(*rfft3_planes(x, s), s)
+
+
+@span("sthc.irfft")
+def irfft3_lanes(
+    re: Array, im: Array, s: Sequence[int], out: Sequence[int] | None = None
+) -> Array:
+    """:func:`irfft3_planes` of lane planes."""
+    if _use_dft():
+        return irfft3_lanes_dft(re, im, s, out)
+    return irfft3_planes(*from_lane_planes(re, im, s), s, out)
+
+
+def _padded(m: np.ndarray, axis: int, n: int) -> np.ndarray:
+    widths = [(0, 0)] * m.ndim
+    widths[axis] = (0, n - m.shape[axis])
+    return np.pad(m, widths)
+
+
+def rfft3_lanes_dft(x: Array, s: Sequence[int]) -> Planes:
+    """:func:`rfft3_dft` written straight into lane planes: the H and W
+    DFT matrices carry zero columns for the padded rows and lanes."""
+    h, w, n = (int(d) for d in s)
+    k, hp, wp = lane_grid(s)
+    x = x[..., :h, :w, :n].astype(jnp.float32)
+    hin, win, tin = x.shape[-3:]
+    cos, sin = _dft_cos_sin(tin, n // 2 + 1, n)
+    hi = lax.Precision.HIGHEST
+    re = jnp.einsum("...t,tk->...k", x, cos.astype(np.float32), precision=hi)
+    im = -jnp.einsum("...t,tk->...k", x, sin.astype(np.float32), precision=hi)
+    c, s_ = _dft_cos_sin(win, w, w)
+    re, im = _cmatmul(re, im, "...hwk,wb->...khb",
+                      _padded(c, 1, wp), _padded(-s_, 1, wp))
+    c, s_ = _dft_cos_sin(hin, h, h)
+    re, im = _cmatmul(re, im, "...khb,ha->...kab",
+                      _padded(c, 1, hp), _padded(-s_, 1, hp))
+    lead = re.shape[:-3]
+    return re.reshape(lead + (k * hp, wp)), im.reshape(lead + (k * hp, wp))
+
+
+def irfft3_lanes_dft(
+    re: Array, im: Array, s: Sequence[int], out: Sequence[int] | None = None
+) -> Array:
+    """:func:`irfft3_dft` of lane planes, read as they lie: the complex
+    inverse along H contracts the sublanes of each (Hp, Wp) plane, the
+    one along W its lanes, and the real inverse along T the planes; the
+    matrices' rows for padded bins are zero."""
+    h, w, n = (int(d) for d in s)
+    oh, ow, on = (h, w, n) if out is None else (int(d) for d in out)
+    k, hp, wp = lane_grid(s)
+    lead = re.shape[:-2]
+    re = re.reshape(lead + (k, hp, wp))
+    im = im.reshape(lead + (k, hp, wp))
+    c, s_ = _dft_cos_sin(h, oh, h)
+    re, im = _cmatmul(re, im, "...khw,ha->...kaw",
+                      _padded(c / h, 0, hp), _padded(s_ / h, 0, hp))
+    c, s_ = _dft_cos_sin(w, ow, w)
+    re, im = _cmatmul(re, im, "...kaw,wb->...kab",
+                      _padded(c / w, 0, wp), _padded(s_ / w, 0, wp))
+    kk = np.arange(k)
+    weight = np.where((kk == 0) | (2 * kk == n), 1.0, 2.0)[:, None] / n
+    cos, sin = _dft_cos_sin(k, on, n)
+    hi = lax.Precision.HIGHEST
+    return jnp.einsum(
+        "...kab,kt->...abt", re, (weight * cos).astype(np.float32),
+        precision=hi,
+    ) - jnp.einsum(
+        "...kab,kt->...abt", im, (weight * sin).astype(np.float32),
+        precision=hi,
+    )
+
+
 def irfft3_dft(
     re: Array, im: Array, s: Sequence[int], out: Sequence[int] | None = None
 ) -> Array:

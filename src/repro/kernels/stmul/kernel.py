@@ -39,7 +39,10 @@ BlockSpec's index map, so the right arena tile is DMA'd per program).
 A mixed-tenant batch of N same-geometry tenants is thus one kernel
 launch instead of N.  Arena planes may be stored bf16 (half-precision
 grating storage); tiles are up-cast to f32 in-kernel so the contraction
-accumulates in f32 either way.
+accumulates in f32 either way.  The bins may also come as lane planes,
+``(…, R, L)`` (``repro.core.spectral_conv.to_lane_planes``), the layout
+of the resident arenas: tiles are then whole rows of L lanes, and the
+output is written in the layout the inverse transform reads.
 
 Tiling
 ------
@@ -70,6 +73,9 @@ Array = jax.Array
 BLOCK_B = 4
 BLOCK_O = 8
 BLOCK_F = 512  # lanes; multiple of 128
+# Lane-plane tiles: rows of the (R, L) bins per tile at C = 1 — one
+# (96, 128) plane of the paper's window grid, 12,288 bins a step.
+BLOCK_R = 96
 
 # Contraction depth at which the MXU beats an unrolled VPU MAC.  This is
 # the *default* routing threshold; callers tune it per deployment via the
@@ -226,15 +232,15 @@ def spectral_mac_pallas(
 def _stmul_kernel_grouped(
     off_ref, xr_ref, xi_ref, gr_ref, gi_ref, yr_ref, yi_ref, *, use_mxu: bool
 ):
-    """One (1, bO, bF) tile of the pooled contraction.
+    """One (1, bO, *tile) tile of the pooled contraction.
 
     ``off_ref`` is the prefetched per-row block-offset vector — consumed
     by the grating BlockSpec's index map, not here.  Tiles up-cast to
     f32 (arena planes may be bf16) so accumulation is f32 either way.
     """
-    xr = xr_ref[...].astype(jnp.float32)  # (1, C, bF)
+    xr = xr_ref[...].astype(jnp.float32)  # (1, C, *tile)
     xi = xi_ref[...].astype(jnp.float32)
-    gr = gr_ref[...].astype(jnp.float32)  # (bO, C, bF)
+    gr = gr_ref[...].astype(jnp.float32)  # (bO, C, *tile)
     gi = gi_ref[...].astype(jnp.float32)
     t1 = _contract_c(xr, gr, use_mxu)
     t2 = _contract_c(xi, gi, use_mxu)
@@ -256,7 +262,7 @@ def spectral_mac_grouped_pallas(
     *,
     n_out: int,
     block_o: int = BLOCK_O,
-    block_f: int = BLOCK_F,
+    block_f: int | None = None,
     min_mxu_c: int | None = None,
     interpret: bool = False,
 ) -> tuple[Array, Array]:
@@ -267,21 +273,29 @@ def spectral_mac_grouped_pallas(
     — one launch contracts every query row against its own tenant's
     O-slice of the arena (per-row offsets via scalar prefetch).
 
+    The bins come flat, ``(…, F)``, and are padded to the lane tile
+    ``block_f`` (default ``BLOCK_F``) at every call; or as lane planes,
+    ``(…, R, L)`` with R a multiple of 8 and L of 128
+    (``repro.core.spectral_conv.to_lane_planes``), which are read and
+    written as they lie, in tiles of whole rows — ``block_f // L`` of
+    them where ``block_f`` is given, else ``BLOCK_R // C`` (at least 8),
+    cut to a divisor of R — with C contracted on the VPU.
+
     Args:
-      xr, xi: (B, C, F) float32 query-spectrum planes.
-      gr, gi: (ΣO_pad, C, F) float32 *or bfloat16* pooled arena planes
-        (half-precision grating storage stays narrow in HBM; tiles
-        up-cast in-kernel, f32 accumulation).
+      xr, xi: (B, C, *bins) float32 query-spectrum planes.
+      gr, gi: (ΣO_pad, C, *bins) float32 *or bfloat16* pooled arena
+        planes (half-precision grating storage stays narrow in HBM;
+        tiles up-cast in-kernel, f32 accumulation).
       o_start: (B,) int32 first-row offset per query row; every offset
         must sit on the ``block_o`` grid (the arena packs member slots
         aligned — see ``repro.core.engine.GratingPool``).
       n_out: rows read/written per query row (the widest member slot).
 
-    Returns (yr, yi): (B, n_out, F) float32.
+    Returns (yr, yi): (B, n_out, *bins) float32.
     """
-    B, C, F = xr.shape
+    B, C = xr.shape[:2]
+    bins = xr.shape[2:]
     bO = block_o
-    bF = min(block_f, F)
     n_pad = (-n_out) % bO
 
     def pad_to(a, axis, mult):
@@ -292,49 +306,71 @@ def spectral_mac_grouped_pallas(
         widths[axis] = (0, rem)
         return jnp.pad(a, widths)
 
-    xr_p = pad_to(xr, 2, bF)
-    xi_p = pad_to(xi, 2, bF)
+    if len(bins) == 2:  # lane planes
+        rows, lanes = bins
+        want = max(8, block_f // lanes if block_f else BLOCK_R // C)
+        b_r = max(r for r in range(8, want + 1, 8) if rows % r == 0)
+        tile = (b_r, lanes)
+        n_f = rows // b_r
+        xr_p, xi_p, gr_p, gi_p = xr, xi, gr, gi
+        use_mxu = False
+    else:
+        (F,) = bins
+        bF = min(block_f or BLOCK_F, F)
+        tile = (bF,)
+        xr_p = pad_to(xr, 2, bF)
+        xi_p = pad_to(xi, 2, bF)
+        gr_p = pad_to(gr, 2, bF)
+        gi_p = pad_to(gi, 2, bF)
+        n_f = xr_p.shape[2] // bF
+        threshold = MIN_MXU_C if min_mxu_c is None else int(min_mxu_c)
+        use_mxu = C >= threshold
     # row-pad the arena so the widest tile read (o_start + n_out_pad)
     # stays in bounds even for the last member slot
-    gr_p = pad_to(pad_to(gr, 0, bO), 2, bF)
-    gi_p = pad_to(pad_to(gi, 0, bO), 2, bF)
+    gr_p = pad_to(gr_p, 0, bO)
+    gi_p = pad_to(gi_p, 0, bO)
     if n_pad:
         widths = [(0, n_pad)] + [(0, 0)] * (gr_p.ndim - 1)
         gr_p = jnp.pad(gr_p, widths)
         gi_p = jnp.pad(gi_p, widths)
-    Fp = xr_p.shape[2]
     n_out_pad = n_out + n_pad
+    out_bins = xr_p.shape[2:]
+    rest = (0,) * (len(tile) - 1)
 
-    threshold = MIN_MXU_C if min_mxu_c is None else int(min_mxu_c)
-    kernel = functools.partial(
-        _stmul_kernel_grouped, use_mxu=C >= threshold
-    )
+    kernel = functools.partial(_stmul_kernel_grouped, use_mxu=use_mxu)
     off_blocks = (o_start // bO).astype(jnp.int32)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, n_out_pad // bO, Fp // bF),
+        grid=(B, n_out_pad // bO, n_f),
         in_specs=[
-            pl.BlockSpec((1, C, bF), lambda b, o, f, off: (b, 0, f)),
-            pl.BlockSpec((1, C, bF), lambda b, o, f, off: (b, 0, f)),
-            pl.BlockSpec((bO, C, bF), lambda b, o, f, off: (off[b] + o, 0, f)),
-            pl.BlockSpec((bO, C, bF), lambda b, o, f, off: (off[b] + o, 0, f)),
+            pl.BlockSpec((1, C) + tile, lambda b, o, f, off: (b, 0, f) + rest),
+            pl.BlockSpec((1, C) + tile, lambda b, o, f, off: (b, 0, f) + rest),
+            pl.BlockSpec(
+                (bO, C) + tile, lambda b, o, f, off: (off[b] + o, 0, f) + rest
+            ),
+            pl.BlockSpec(
+                (bO, C) + tile, lambda b, o, f, off: (off[b] + o, 0, f) + rest
+            ),
         ],
         out_specs=[
-            pl.BlockSpec((1, bO, bF), lambda b, o, f, off: (b, o, f)),
-            pl.BlockSpec((1, bO, bF), lambda b, o, f, off: (b, o, f)),
+            pl.BlockSpec((1, bO) + tile, lambda b, o, f, off: (b, o, f) + rest),
+            pl.BlockSpec((1, bO) + tile, lambda b, o, f, off: (b, o, f) + rest),
         ],
     )
     yr, yi = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, n_out_pad, Fp), jnp.float32),
-            jax.ShapeDtypeStruct((B, n_out_pad, Fp), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_out_pad) + out_bins, jnp.float32),
+            jax.ShapeDtypeStruct((B, n_out_pad) + out_bins, jnp.float32),
         ],
         interpret=interpret,
     )(off_blocks, xr_p, xi_p, gr_p, gi_p)
-    return yr[:, :n_out, :F], yi[:, :n_out, :F]
+    if out_bins == bins and not n_pad:
+        return yr, yi
+    crop = (slice(None), slice(0, n_out)) + tuple(slice(0, n) for n in bins)
+    return yr[crop], yi[crop]
 
 
 # ---------------------------------------------------------------------------

@@ -138,24 +138,28 @@ def _mac_grouped_planes(
     block_f: int | None,
 ) -> tuple[Array, Array]:
     """:func:`spectral_mac_grouped` on split (real, imaginary) planes.
-    The arena planes are (ΣO, C, *bins) or, stored flat and padded to
-    the kernel's lane block, (ΣO, C, F_pad)."""
+
+    The arena's stored layout decides the bins' layout throughout: a
+    5-D arena, (ΣO, C, FH, FW, FTr), is flattened here (and the kernel
+    pads its bins to the lane tile at every call), and the output comes
+    back 5-D; an arena of lane planes, (ΣO, C, R, L), takes the spectra
+    as lane planes too, and the output is the kernel's own (B, n_out,
+    R, L), which :func:`repro.core.spectral_conv.irfft3_lanes` reads as
+    it lies."""
     tiles = _tile_kwargs(None, block_o, block_f)
-    fshape = xr.shape[2:]
     B, C = xr.shape[:2]
-    f = 1
-    for n in fshape:
-        f *= n
-    so = pool_re.shape[0]
-    if pool_re.ndim != 3:
-        # a 5-D arena: flatten its bins here (and the kernel pads them to
-        # its lane block at every call); a resident arena comes stored
-        # flat and lane-padded, and is read as it is
+    fshape = xr.shape[2:]
+    if pool_re.ndim == 5:
+        f = 1
+        for n in fshape:
+            f *= n
+        so = pool_re.shape[0]
+        xr, xi = xr.reshape(B, C, f), xi.reshape(B, C, f)
         pool_re = pool_re.reshape(so, C, f)
         pool_im = pool_im.reshape(so, C, f)
     yr, yi = _kernel.spectral_mac_grouped_pallas(
-        xr.reshape(B, C, f).astype(jnp.float32),
-        xi.reshape(B, C, f).astype(jnp.float32),
+        xr.astype(jnp.float32),
+        xi.astype(jnp.float32),
         pool_re,
         pool_im,
         jnp.asarray(o_start, jnp.int32),
@@ -184,8 +188,13 @@ def query_grating_pooled(
     """Pooled counterpart of :func:`query_grating_pallas`: one forward
     FFT over the stacked mixed-tenant batch, one grouped-kernel launch
     against the pooled arena, one inverse FFT — all on split real /
-    imaginary planes."""
-    xr, xi = spectral_conv.rfft3_planes(x, fft_shape)
+    imaginary planes, in the arena's bin layout (lane planes where the
+    arena is stored so, see :func:`_mac_grouped_planes`)."""
+    if pool_re.ndim == 4:
+        rfft, irfft = spectral_conv.rfft3_lanes, spectral_conv.irfft3_lanes
+    else:
+        rfft, irfft = spectral_conv.rfft3_planes, spectral_conv.irfft3_planes
+    xr, xi = rfft(x, fft_shape)
     yr, yi = _mac_grouped_planes(
         xr,
         xi,
@@ -197,7 +206,7 @@ def query_grating_pooled(
         block_o=block_o,
         block_f=block_f,
     )
-    return spectral_conv.irfft3_planes(yr, yi, fft_shape, out_shape)
+    return irfft(yr, yi, fft_shape, out_shape)
 
 
 def pooled_query_shard(

@@ -24,16 +24,16 @@ FRAMES = 24
 RTOL = 1e-5
 
 
-def _engines(use_pallas: bool):
+def _engines(use_pallas: bool, **cfg):
     """(the pooled engine, per-tenant engines, per-tenant gratings): 12
     seeded tenants alternating ideal and physical, as the served cell
     alternates them by popularity rank."""
     rng = np.random.RandomState(11)
     ideal = QueryEngine(STHCConfig(fidelity=fid.ideal(), use_pallas=use_pallas,
-                                   osave_chunk_windows=2))
+                                   osave_chunk_windows=2, **cfg))
     phys = QueryEngine(STHCConfig(fidelity=fid.physical(),
                                   use_pallas=use_pallas,
-                                  osave_chunk_windows=2))
+                                  osave_chunk_windows=2, **cfg))
     engines, gratings = [], []
     for t in range(N_TENANTS):
         eng = ideal if t % 2 == 0 else phys
@@ -105,6 +105,86 @@ def test_zipf_compositions_match_each_request_alone(use_pallas):
     stats = pooled.pool_stats()
     assert stats["arena_builds"] == 2  # one per pool group, never again
     assert stats["rows_saved"] > 0  # some batches shared a stream
+
+
+def _dense(storage: str) -> tuple[QueryEngine, QueryEngine]:
+    return tuple(
+        QueryEngine(STHCConfig(fidelity=f, osave_chunk_windows=2,
+                               grating_dtype=storage))
+        for f in (fid.ideal(), fid.physical())
+    )
+
+
+@pytest.mark.parametrize("storage", ["float32", "bfloat16"])
+def test_lane_arena_answers_equal_dense_query_stream(storage):
+    """The Pallas path stores its resident arenas as lane planes and
+    reads the MAC's output in that layout: for every row count 1–8 of
+    a batch over both fidelities, each request's top-1 equals its own
+    dense ``query_stream`` — the score to a few ulps, the index
+    bitwise — and every dispatch is counted as read in the layout."""
+    pooled, _, gratings = _engines(True, grating_dtype=storage)
+    dense = _dense(storage)
+    streams = _streams(8)
+    for n in range(1, 9):
+        batch = [((5 * r + n) % N_TENANTS, streams[r]) for r in range(n)]
+        dets = pooled.query_stream_many(
+            [(gratings[t], x) for t, x in batch], readout_k=1
+        )
+        for (t, x), det in zip(batch, dets):
+            vol = np.asarray(dense[t % 2].query_stream(gratings[t],
+                                                       jnp.asarray(x)))
+            flat = vol.reshape(vol.shape[0], vol.shape[1], -1)
+            scale = np.abs(flat).max(-1)
+            s = np.asarray(det.scores)[..., 0]
+            assert np.all(np.abs(s - flat.max(-1)) <= RTOL * scale), (n, t)
+            assert np.array_equal(np.asarray(det.index)[..., 0],
+                                  flat.argmax(-1)), (n, t)
+    for arena in pooled._arenas.values():
+        assert arena.pool.re.ndim == 4  # (rows, C, FTr·Hp, Wp)
+        assert arena.pool.re.dtype == jnp.dtype(storage)
+    stats = pooled.pool_stats()
+    assert stats["native_layout_dispatches"] == stats["dispatches"] > 0
+
+
+def test_chunked_cursor_on_lane_arena_equals_one_shot():
+    """Streams fed through the cursor one window at a time ride the same
+    lane-plane programs, and their merged states equal the one-shot
+    pass bitwise."""
+    pooled, _, gratings = _engines(True)
+    streams = _streams(5)
+    reqs = [(gratings[t], x) for t, x in zip((0, 1, 2, 4, 7), streams)]
+    one = pooled.query_stream_many(reqs, readout_k=2)
+    before = pooled.pool_stats()["native_layout_dispatches"]
+    chunked = pooled.query_stream_many(reqs, readout_k=2,
+                                       max_buffer_windows=1)
+    for a, b in zip(one, chunked):
+        assert np.array_equal(np.asarray(a.scores), np.asarray(b.scores))
+        assert np.array_equal(np.asarray(a.index), np.asarray(b.index))
+    # one count per pool-group dispatch, however many segments it took
+    assert pooled.pool_stats()["native_layout_dispatches"] == before + 2
+
+
+def test_native_layout_counter_stays_zero_on_5d_arenas():
+    """The dense path, the one-shot pooled query and the mesh path keep
+    5-D arenas: none of their dispatches counts as read in the lane
+    layout."""
+    from repro.launch.mesh import make_local_mesh
+
+    streams = _streams(3)
+    dense, _, gratings = _engines(False)
+    dense.query_stream_many([(gratings[0], streams[0])], readout_k=1)
+    pallas, _, gratings = _engines(True)
+    reqs = [(gratings[t], jnp.asarray(x[..., :8]))
+            for t, x in zip((0, 2), streams)]
+    pallas.query_many(reqs)
+    pallas.query_stream_many(
+        [(gratings[0], streams[0]), (gratings[2], streams[1])],
+        readout_k=1, mesh=make_local_mesh(1, 1),
+    )
+    for engine in (dense, pallas):
+        stats = engine.pool_stats()
+        assert stats["dispatches"] > 0
+        assert stats["native_layout_dispatches"] == 0
 
 
 def test_whole_state_slices_equal_device_slices():

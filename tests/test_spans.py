@@ -161,6 +161,40 @@ def test_pooled_driver_names_its_device_stages():
                          re.M), scope
 
 
+def test_pooled_driver_scopes_hold_the_lane_layout(monkeypatch):
+    """With the TPU's DFT transforms, the pooled driver reads the MAC's
+    lane-plane output as it lies: the grouped kernel sits under
+    ``sthc.mac`` and the inverse transform's lane-plane contractions
+    under ``sthc.irfft``, with no slice of the kernel's output between
+    them."""
+    from repro.core import spectral_conv
+
+    monkeypatch.setattr(spectral_conv, "_use_dft", lambda: True)
+    server = _server()
+    engine = server.sthc.engine
+    calls = []
+    inner = engine._stream_many_topk_fn
+
+    def record(*args, **kw):
+        calls.append((args, kw))
+        return inner(*args, **kw)
+
+    engine._stream_many_topk_fn = record
+    server.search_batch([("ideal", _stream()), ("physical", _stream())])
+    assert len(calls) == 2
+    assert all(args[1].ndim == 4 for args, _ in calls)  # lane planes
+    assert engine.pool_stats()["native_layout_dispatches"] == 2
+    args, kw = calls[0]
+    hlo = inner.lower(*args, **kw).as_text(dialect="hlo", debug_info=True)
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    mac = [n for n in names if "spectral_mac_grouped_pallas" in n]
+    assert mac and all(re.search(r"(^|[/(])sthc\.mac[/)]", n) for n in mac)
+    assert not any("sthc.mac" in n and n.endswith("/slice") for n in names)
+    lanes = [n for n in names if "...khw,ha->...kaw" in n]
+    assert lanes and all(re.search(r"(^|[/(])sthc\.irfft[/)]", n)
+                         for n in lanes)
+
+
 def test_classifier_conv_program_names_its_device_stages():
     """The classifier's optical layer is one jitted program per call, and
     its lowered ops carry the one-shot query's device scopes."""

@@ -58,6 +58,62 @@ def test_dft_transforms_match_numpy(shape, s, out, rng):
     np.testing.assert_allclose(got, y, atol=1e-6 * np.abs(y).max())
 
 
+# the served window grid (60×80×64 frames against 30×40×8 kernels)
+# beside the small odd grids
+LANE_CASES = DFT_CASES + [((1, 1, 60, 80, 64), (90, 120, 72), (31, 41, 57))]
+
+
+def _lane_pads(s) -> np.ndarray:
+    """Mask of the padded bins of the lane planes of grid ``s``."""
+    k, hp, wp = sc.lane_grid(s)
+    pad = np.ones((k, hp, wp), bool)
+    pad[:, : s[0], : s[1]] = False
+    return pad.reshape(k * hp, wp)
+
+
+@pytest.mark.parametrize("shape,s,out", LANE_CASES)
+def test_lane_dft_transforms_match_numpy(shape, s, out, rng):
+    """The lane-plane DFT transforms against numpy's float64 rfftn /
+    irfftn: the forward writes zeros in every padded bin, and garbage in
+    the padded bins never reaches the inverse's output."""
+    x = rng.randn(*shape)
+    spec = np.fft.rfftn(x, s, axes=(-3, -2, -1))
+    re, im = sc.rfft3_lanes_dft(jnp.asarray(x, jnp.float32), s)
+    k, hp, wp = sc.lane_grid(s)
+    assert re.shape == shape[:-3] + (k * hp, wp)
+    pad = _lane_pads(s)
+    assert not np.any(np.asarray(re)[..., pad])
+    assert not np.any(np.asarray(im)[..., pad])
+    re5, im5 = sc.from_lane_planes(re, im, s)
+    scale = np.abs(spec).max()
+    np.testing.assert_allclose(re5, spec.real, atol=1e-6 * scale)
+    np.testing.assert_allclose(im5, spec.imag, atol=1e-6 * scale)
+    y = np.fft.irfftn(spec, s, axes=(-3, -2, -1))
+    if out is not None:
+        y = y[..., : out[0], : out[1], : out[2]]
+    lr, li = sc.to_lane_planes(jnp.asarray(spec.real, jnp.float32),
+                               jnp.asarray(spec.imag, jnp.float32), s)
+    garbage = np.broadcast_to(np.where(pad, 1e4, 0.0), lr.shape).astype(np.float32)
+    got = sc.irfft3_lanes_dft(lr + garbage, li - garbage, s, out)
+    assert got.shape == y.shape
+    np.testing.assert_allclose(got, y, atol=1e-6 * np.abs(y).max())
+
+
+@pytest.mark.parametrize("shape,s,out", LANE_CASES)
+def test_lane_wrappers_are_the_5d_transforms_off_tpu(shape, s, out, rng):
+    """Off the TPU the lane-plane transforms are jnp.fft's, repacked:
+    the same numbers in the same bins."""
+    x = jnp.asarray(rng.randn(*shape), jnp.float32)
+    re, im = sc.rfft3_planes(x, s)
+    lr, li = sc.rfft3_lanes(x, s)
+    assert all(np.array_equal(a, b) for a, b in
+               zip(sc.to_lane_planes(re, im, s), (lr, li)))
+    assert all(np.array_equal(a, b) for a, b in
+               zip(sc.from_lane_planes(lr, li, s), (re, im)))
+    assert np.array_equal(sc.irfft3_lanes(lr, li, s, out),
+                          sc.irfft3_planes(re, im, s, out))
+
+
 @pytest.mark.parametrize("shape,s,out", DFT_CASES)
 def test_transform_wrappers_are_jnp_fft_off_tpu(shape, s, out, rng):
     x = jnp.asarray(rng.randn(*shape), jnp.float32)

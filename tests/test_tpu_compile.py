@@ -17,6 +17,7 @@ file loads it in the worker that runs the file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -136,6 +137,39 @@ def test_pooled_window_query_compiles(one_chip, monkeypatch):
         ),
         x, g, g, o,
     )
+
+
+def test_pooled_chunk_reads_the_lane_layout_as_it_lies(one_chip, monkeypatch):
+    """A chunk of windows of the served pooled query against an arena of
+    lane planes, as the resident arenas are stored: the compiled program
+    holds the grouped kernel, and no slice, copy, transpose or reshape
+    of the kernel's (…, n_out, bins) output — the inverse transform
+    reads it as it lies.  ``n_out`` is a tenant slot (16 rows), as the
+    resident arena reads it."""
+    monkeypatch.setattr(spectral_conv, "_use_dft", lambda: True)
+    monkeypatch.setattr(stmul_ops, "_use_interpret", lambda: False)
+    fft = spectral_conv.fft_shape_for(FRAME_HW + (WINDOW_FRAMES,), KER)
+    out = spectral_conv.valid_shape(FRAME_HW + (WINDOW_FRAMES,), KER)
+    k, hp, wp = spectral_conv.lane_grid(fft)
+    n_out = 2 * stmul_kernel.BLOCK_O
+    x = _spec((CHUNK_WINDOWS, 1, 1) + FRAME_HW + (WINDOW_FRAMES,),
+              jnp.float32, one_chip)
+    g = _spec((ARENA_ROWS, 1, k * hp, wp), jnp.float32, one_chip)
+    o = _spec((1,), jnp.int32, one_chip)
+
+    def chunk(x, gr, gi, off):
+        return jax.vmap(lambda w: stmul_ops.query_grating_pooled(
+            w, gr, gi, off, n_out, fft, out))(x)
+
+    text = jax.jit(chunk).lower(x, g, g, o).compile().as_text()
+    assert "tpu_custom_call" in text
+    op = re.compile(r"= f32\[([\d,]+)\]\S* (slice|copy|transpose|reshape)\(")
+    volumes = (f"{n_out},{k * hp},{wp}", f"{n_out},{k},{hp},{wp}")
+    moved = [
+        line.strip()[:120] for line in text.splitlines()
+        if (m := op.search(line)) and m.group(1).endswith(volumes)
+    ]
+    assert not moved, moved[:3]
 
 
 def test_stmul_v2_compiles(one_chip):
