@@ -36,11 +36,10 @@ grating), recomputing the identical ``rfftn(x)`` both times, and
   one per window.  Together these make the streaming output equal to
   the one-shot physical correlation (tested property).
 
-* **Pooled serving** — ``query_many`` / ``query_stream_many`` extend the
+* **Pooled serving** — ``query_stream_many`` extends the
   weight-stationary dataflow *across tenants*: resident effective
   gratings that share FFT geometry and encode semantics are packed into
-  one ``(ΣO, C, FH, FW, FTr)`` arena (:class:`GratingPool`; on the
-  streaming path one resident arena per pool group, packed from the
+  one arena per pool group (:class:`GratingPool`, packed from the
   gratings declared by :meth:`QueryEngine.set_resident`, with a batch's
   composition — rows and their arena offsets — passed as runtime data,
   so any mix of resident tenants reuses one compiled program per row
@@ -48,7 +47,7 @@ grating), recomputing the identical ``rfftn(x)`` both times, and
   exactly one forward FFT, one pooled channel-contracted MAC in which
   every clip row reads only its own tenant's O-offset slice, and one
   inverse FFT — N same-geometry tenants pay 1 device dispatch instead of
-  N.  **Clip-dedup** takes the fan-out the rest of the way to the
+  N.  A one-shot clip is a stream of one window.  **Clip-dedup** takes the fan-out the rest of the way to the
   paper's headline dataflow (many kernels correlated against *one*
   stream in parallel): batch rows whose clips hash content-equal
   (:func:`clip_key`) collapse onto one physical row reading the union
@@ -238,22 +237,20 @@ class GratingPool:
     Attributes:
       re / im: split real/imag planes of the arena, in the members'
         storage dtype (bf16 gratings stay bf16 in HBM; the MAC up-casts
-        tiles to f32 — f32 accumulation either way): (ΣO_pad, C, FH,
-        FW, FTr), or lane planes (ΣO_pad, C, FTr·Hp, Wp)
-        (``spectral_conv.to_lane_planes``) where the grouped Pallas
-        kernel serves the arena (the resident arenas of the pooled
-        stream path).
+        tiles to f32 — f32 accumulation either way): lane planes
+        (ΣO_pad, C, FTr·Hp, Wp) (``spectral_conv.to_lane_planes``)
+        where the grouped Pallas kernel serves the arena, else the
+        dense path's (ΣO_pad, C, FH, FW, FTr).  On a mesh they are
+        placed with their rows sharded over the model axis.
       o_start: per-member first-row offset.  Member slots are padded to
         ``align`` rows (the Pallas grouped kernel indexes the arena in
         O-tile units; the dense gather path uses align=1), and the arena
         carries enough tail rows that every ``o_start[i] + n_out`` read
         stays in bounds.
-      n_out: rows each pooled query reads/writes per request (the widest
-        member slot); per-request outputs are cropped back to their own
-        O.
+      n_out: the widest member slot.
       members: strong references to the member gratings — the arena is a
         pure repack of their planes, and pinning them keeps the
-        identity-keyed pool cache sound.
+        identity-keyed slot map sound.
       shards: number of equal-row arena shards the packing respects
         (mesh serving).  ``shards > 1`` bins members into ``shards``
         equal tiles of ``shard_rows`` rows each (greedy least-loaded,
@@ -414,8 +411,8 @@ def _bin_members(slots: list[int], shards: int) -> tuple[list[int], int]:
     equal arena tiles.
 
     Returns (bin_of, shard_rows): each member's tile index (first-seen
-    order, ties broken by lowest tile index — deterministic, so the
-    identity-keyed pool cache stays sound) and the per-tile row count
+    order, ties broken by lowest tile index — deterministic) and the
+    per-tile row count
     (the max tile load, rounded up so every tile is the same height).
     """
     load = [0] * shards
@@ -436,9 +433,10 @@ def _build_pool(
     """Pack member gratings' planes into one arena (see GratingPool).
 
     ``lanes`` packs the planes as lane planes
-    (:func:`spectral_conv.to_lane_planes`), the bin layout that the
-    grouped kernel and the inverse transform read as it is stored;
-    without it the arena keeps the gratings' 5-D bins.
+    (:func:`spectral_conv.to_lane_planes`), the one bin layout that the
+    grouped Pallas kernel takes and the inverse transform reads as it
+    is stored; without it the arena keeps the gratings' 5-D bins, which
+    the dense path gathers from.
 
     ``shards > 1`` makes the packing mesh-aware: members are binned
     into ``shards`` equal tiles of ``shard_rows`` rows (every tile
@@ -534,7 +532,7 @@ def clip_key(x) -> tuple | None:
     Two requests whose clips hash equal (bytes + shape + dtype) are the
     *same stream*: the pooled executor answers them with one forward FFT
     over one physical copy, each tenant reading its own O-slice of the
-    union span (see :meth:`QueryEngine.query_many`).  Hashing is the
+    union span (see :meth:`QueryEngine.query_stream_many`).  Hashing is the
     point, not an optimization hazard: a false "same clip" would answer
     one tenant with another's stream, so the full buffer is digested
     (SHA-1), never a sample.  Tracers (inside ``jit``) have no bytes to
@@ -617,6 +615,8 @@ class _Arena:
       pool: the packed arena; its members are the declared residents of
         the group (:meth:`QueryEngine.set_resident`), in declaration
         order, then any other gratings the last rebuild had to admit.
+        A mesh's arena is shard-tiled over its model axis and its planes
+        are placed there.
       slot: ``id(grating) -> member index`` (the pool pins every member,
         so ids stay unique while the arena lives).
       declared: ids of the declared residents it was built from.
@@ -677,20 +677,6 @@ def _presel_query_dense(
             "bcxyz,bocxyz->boxyz", xhat, sel, precision="highest"
         )
     return spectral_conv.irfft3(yhat, fft_shape, out_shape)
-
-
-def _pooled_query_dense(
-    x: Array,
-    pool_re: Array,
-    pool_im: Array,
-    rows: Array,
-    n_out: int,
-    fft_shape: tuple[int, int, int],
-    out_shape: tuple[int, int, int],
-) -> Array:
-    """Dense pooled query: offset-gather + einsum."""
-    sel = _pool_select(pool_re, pool_im, rows, n_out)
-    return _presel_query_dense(x, sel, fft_shape, out_shape)
 
 
 # ---------------------------------------------------------------------------
@@ -805,8 +791,8 @@ def _segments_rebase_merge(
 class QueryEngine:
     """Record-once / query-many executor for one :class:`STHCConfig`."""
 
-    # LRU bound on query_many's and the mesh's arenas, and on zero rows
-    _max_pools = 8
+    # row shapes whose zero padding rows stay on the device
+    _max_zero_rows = 8
 
     def __init__(self, config: "STHCConfig"):
         self.config = config
@@ -870,13 +856,10 @@ class QueryEngine:
             _segments_rebase_merge,
             static_argnames=("k", "nv_locals", "t0s", "nv_total"),
         )
-        # per-member-set arenas of the one-shot query_many and the mesh
-        # path (their compositions stay static)
-        self._pools: OrderedDict[tuple, GratingPool] = OrderedDict()  # guarded-by: _pools_lock
         # the pooled stream path's resident arenas, one per pool group
-        # (_arena_key), packed from the declared residents
-        # (set_resident) and rebuilt only when those change or a batch
-        # brings an undeclared grating
+        # (_arena_key) and mesh (None on one device), packed from the
+        # declared residents (set_resident) and rebuilt only when those
+        # change or a batch brings an undeclared grating
         self._resident: dict[tuple, list[FusedGrating]] = {}  # guarded-by: _pools_lock
         self._arenas: dict[tuple, _Arena] = {}  # guarded-by: _pools_lock
         self._arena_builds = 0  # guarded-by: _pools_lock
@@ -884,13 +867,9 @@ class QueryEngine:
         # shape, the last few shapes kept (device-resident: padding
         # uploads nothing)
         self._zero_rows: OrderedDict[tuple, Array] = OrderedDict()  # guarded-by: _pools_lock
-        # mesh serving state: per-Mesh jitted sharded drivers and
-        # per-(pool, mesh) arena placements (planes device_put once with
-        # rows NamedSharding'd over the model axis, reused across
-        # dispatches).  A server owns one mesh per replica, so both
-        # caches stay tiny.
+        # per-Mesh jitted sharded drivers (a server owns one mesh per
+        # replica, so this stays tiny)
         self._mesh_jits: dict = {}  # guarded-by: _pools_lock
-        self._mesh_arenas: OrderedDict[tuple, tuple] = OrderedDict()  # guarded-by: _pools_lock
         self._pools_lock = threading.Lock()
         # shared-stream fan-out accounting (clip-dedup in the pooled
         # paths): offered = clip rows requested, dispatched = physical
@@ -899,8 +878,8 @@ class QueryEngine:
         self._pooled_rows_offered = 0  # guarded-by: _pools_lock
         self._pooled_rows_dispatched = 0  # guarded-by: _pools_lock
         self._pooled_rows_padded = 0  # guarded-by: _pools_lock
-        # pooled dispatches whose MAC output the inverse transform read
-        # in the kernel's own layout (an arena of lane planes)
+        # pooled dispatches of the grouped Pallas kernel, whose MAC
+        # output the inverse transform reads in the kernel's own layout
         self._native_layout_dispatches = 0  # guarded-by: _pools_lock
 
     def pool_stats(self) -> dict:
@@ -909,9 +888,9 @@ class QueryEngine:
         rows that carried a request (``rows_dispatched``) and rows that
         only padded a batch to its bucket (``rows_padded``); resident
         arenas packed (``arena_builds``) and pooled stream programs
-        traced (``stream_traces``); dispatches whose MAC output the
-        inverse transform read in the grouped kernel's lane-plane layout
-        (``native_layout_dispatches``)."""
+        traced (``stream_traces``); dispatches of the grouped Pallas
+        kernel, whose lane-plane output the inverse transform reads as
+        it lies (``native_layout_dispatches``, 0 on the dense path)."""
         with self._trace_lock:
             traces = self._stream_traces
         with self._pools_lock:
@@ -1093,9 +1072,10 @@ class QueryEngine:
 
     @property
     def stream_traces(self) -> int:
-        """How many times the single-device pooled stream programs have
-        been traced — at most one per pool group, row bucket and
-        ``n_out``, whatever the batches' compositions."""
+        """How many times the pooled stream programs have been traced —
+        on one device at most one per pool group, row bucket and
+        ``n_out``, on a mesh one per pool group and row count, whatever
+        the batches' compositions."""
         with self._trace_lock:
             return self._stream_traces
 
@@ -1474,107 +1454,6 @@ class QueryEngine:
 
     # -- query (pooled cross-tenant batch) ----------------------------------
 
-    def query_many(
-        self,
-        requests: "Sequence[tuple[FusedGrating, Array]]",
-        *,
-        clip_keys: "Sequence[tuple | None] | None" = None,
-        dedup: bool = True,
-        mesh=None,
-    ) -> list[Array]:
-        """Answer a mixed-tenant clip batch with one dispatch per pool group.
-
-        ``requests`` is a sequence of ``(grating, x)`` pairs, each ``x``
-        a (B_i, C, H, W, T) clip batch.  Requests are grouped by (FFT
-        geometry, encode semantics, storage dtype, clip geometry); each
-        group's resident gratings are packed into one pooled
-        ``(ΣO, C, FH, FW, FTr)`` arena with per-tenant O-offsets
-        (:class:`GratingPool`, reused across calls while the member
-        gratings stay alive) and the whole group is answered with
-        exactly one forward ``rfftn`` over the stacked clips, one
-        channel-contracted MAC against the pool (each clip row reading
-        only its own tenant's O-slice, via offset-gather — or the
-        grouped Pallas ``stmul`` launch when ``use_pallas``), and one
-        inverse FFT.  A mixed-tenant load of N same-geometry tenants
-        thus pays 1 FFT+MAC+IFFT dispatch instead of N.
-
-        **Clip-dedup (shared-stream fan-out).**  Within a group, rows
-        whose clips hash content-equal (``clip_keys``, default computed
-        via :func:`clip_key`) collapse onto *one* physical row reading
-        the union of their tenants' O-slices — the paper's headline
-        dataflow, many kernels correlated against one stream in
-        parallel: N tenants searching the same clip pay one forward FFT
-        and one MAC row instead of N.  Per-request outputs are sliced
-        from the shared row's span, equal to the undeduped answer
-        exactly (the MAC rows each tenant reads are identical).
-        ``dedup=False`` keeps the one-row-per-request baseline.
-
-        The gratings may come from *different* engines (mixed-fidelity
-        serving): everything record-time is already folded into each
-        effective grating, and the query-time semantics ride on the
-        grating itself (``encode`` / ``slm_bits``), so pipelines that
-        share encode semantics and geometry share one pool group.
-
-        ``mesh`` switches the group dispatch to the sharded executor: a
-        ``(data, model)`` :class:`jax.sharding.Mesh` (see
-        :func:`repro.launch.mesh.make_local_mesh`) shards the arena's
-        ΣO rows over the model axis and the physical clip rows over the
-        data axis — each device contracts its own arena tile against
-        its own clip rows, psum-free — and every request's answer is
-        bitwise-equal to the single-device dispatch (see docs/mesh.md).
-
-        Returns outputs in request order, each (B_i, O_i, *out_shape) —
-        equal to ``query(grating_i, x_i)`` to float tolerance.
-        """
-        groups = self._group_requests(requests)
-        keys = self._clip_ids(requests, clip_keys, dedup)
-        results: list[Array | None] = [None] * len(requests)
-        shards = int(mesh.shape["model"]) if mesh is not None else 1
-        for idxs in groups.values():
-            gratings = [requests[i][0] for i in idxs]
-            members, slot_of = _dedup_members(gratings)
-            pool = self._pool_for(members, shards)
-            xs = [requests[i][1] for i in idxs]
-            gkeys = [keys[i] for i in idxs]
-            starts = [pool.o_start[s] for s in slot_of]
-            if mesh is not None:
-                lay = _fanout_layout(starts, gkeys, int(pool.re.shape[0]))
-            else:
-                lay = _span_layout(
-                    starts, [g.n_out for g in gratings], gkeys, pool.align,
-                    int(pool.re.shape[0]),
-                )
-            ux = [xs[j] for j in lay.uniq]
-            x = ux[0] if len(ux) == 1 else jnp.concatenate(ux, axis=0)
-            nbs = [int(xj.shape[0]) for xj in ux]
-            rows = np.repeat(lay.row_of, nbs).astype(np.int32)
-            self._count_pooled(sum(int(xj.shape[0]) for xj in xs), sum(nbs))
-            if mesh is not None:
-                proto = gratings[0]
-                pool_re, pool_im = self._mesh_arena(pool, mesh)
-                x_scale = None
-                if proto.encode:
-                    # eager, like _pooled_dispatch: jit-fusing the
-                    # encode chain with the MAC rounds differently
-                    x, x_scale = self._encode(x, int(proto.slm_bits))
-                y = self._mesh_fns(mesh)["oneshot"](
-                    x, pool_re, pool_im, x_scale,
-                    fft_shape=proto.fft_shape,
-                    out_shape=proto.out_shape,
-                    n_out=lay.n_out,
-                )
-            else:
-                y = self._pooled_dispatch(
-                    x, pool, rows, gratings[0], n_out=lay.n_out
-                )
-            ub0 = np.concatenate([[0], np.cumsum(nbs)])
-            for j, i in enumerate(idxs):
-                b0 = int(ub0[lay.uniq_of[j]])
-                nb = int(xs[j].shape[0])
-                oo = lay.o_off[j]
-                results[i] = y[b0 : b0 + nb, oo : oo + gratings[j].n_out]
-        return results  # type: ignore[return-value]
-
     def _clip_ids(self, requests, clip_keys, dedup) -> list:
         """Per-request clip identities for the dedup grouping.  Callers
         that already fingerprinted their clips (the microbatch scheduler
@@ -1606,21 +1485,32 @@ class QueryEngine:
     ) -> "list[Array] | list[TopKDetections] | list[PooledTopK]":
         """Pooled :meth:`query_stream`: one overlap-save pass per group.
 
-        The streaming analogue of :meth:`query_many` — mixed-tenant long
-        clips sharing the coherence-window geometry (same recorded
-        kernel/window shapes, encode semantics and stream length) stack
-        on the batch axis and every window chunk runs one pooled
-        FFT+MAC+IFFT against the group arena, instead of one overlap-
-        save pass per tenant.  Clip-dedup applies as in
-        :meth:`query_many`: requests whose streams hash content-equal
-        share one physical batch row reading the union of their O-slices
-        — N tenants fanning out over one shared stream pay one forward
-        FFT per window chunk, total.  Streams whose window count exceeds
-        ``max_buffer_windows`` (default
-        ``config.osave_max_buffer_windows``) are fed through the stream
-        cursor in fixed-size T-chunks at constant peak memory.  Encoding
-        stays per-example stream-global, so each request's output equals
-        ``query_stream(grating_i, x_i)`` to float tolerance.
+        Mixed-tenant clips sharing the coherence-window geometry (same
+        recorded kernel/window shapes, encode semantics and stream
+        length) stack on the batch axis and every window chunk runs one
+        pooled FFT+MAC+IFFT against the group arena, instead of one
+        overlap-save pass per tenant.  The gratings may come from
+        *different* engines (mixed-fidelity serving): everything
+        record-time is folded into each effective grating and the
+        query-time semantics ride on the grating (``encode`` /
+        ``slm_bits``), so pipelines that share them share a pool group.
+        A caller that wants one pooled answer for a clip passes the clip
+        as its stream: a clip of the recorded length is one window, and
+        its answer equals :meth:`query` to float tolerance.
+
+        **Clip-dedup (shared-stream fan-out).**  Within a group, requests
+        whose streams hash content-equal (``clip_keys``, default computed
+        via :func:`clip_key`) share one physical batch row reading the
+        union of their O-slices — N tenants fanning out over one shared
+        stream pay one forward FFT per window chunk, total.
+        ``dedup=False`` keeps one row per request.
+
+        Streams whose window count exceeds ``max_buffer_windows``
+        (default ``config.osave_max_buffer_windows``) are fed through the
+        stream cursor in fixed-size T-chunks at constant peak memory.
+        Encoding stays per-example stream-global, so each request's
+        output equals ``query_stream(grating_i, x_i)`` to float
+        tolerance.
 
         **Composition is runtime data.**  Each group reads the resident
         arena of its pool group (:meth:`set_resident`; a grating not
@@ -1644,19 +1534,23 @@ class QueryEngine:
         instead: the group's whole state and its own rows and kernels in
         it, for a caller that copies each group's state to the host once.
 
-        ``mesh`` switches every group dispatch to the sharded executor
-        (see :meth:`query_many`): arena ΣO rows over the model axis,
-        physical stream rows over the data axis, the forward ``rfftn``
-        of each stream row running once on its data shard, and the MAC
-        + fused readout shard-local (psum-free).  Outputs — volumes and
-        top-K states, chunked-cursor and bf16 storage included — are
+        ``mesh`` — a ``(data, model)`` :class:`jax.sharding.Mesh` (see
+        :func:`repro.launch.mesh.make_local_mesh`) — switches every group
+        dispatch to the sharded executor.  The group's resident arena is
+        shard-tiled and placed on the mesh (:meth:`_resident_arena`):
+        ΣO rows over the model axis, physical stream rows over the data
+        axis, the forward ``rfftn`` of each stream row running once on
+        its data shard, and the MAC + fused readout shard-local
+        (psum-free).  Every row computes against the whole arena
+        (:func:`_fanout_layout`), so a new composition of residents
+        reuses the arena and its program.  Outputs — volumes and top-K
+        states, chunked-cursor and bf16 storage included — are
         bitwise-equal to the single-device path.
         """
         with span("sthc.engine.layout"):
-            groups = self._group_requests(requests, stream=True)
+            groups = self._group_requests(requests)
             keys = self._clip_ids(requests, clip_keys, dedup)
         results: list = [None] * len(requests)
-        shards = int(mesh.shape["model"]) if mesh is not None else 1
         fused = readout_k is not None
         for idxs in groups.values():
             with span("sthc.engine.layout"):
@@ -1677,23 +1571,19 @@ class QueryEngine:
                         f"not match the recorded frame size {frame_hw}"
                     )
                 gkeys = [keys[i] for i in idxs]
+                arena = self._resident_arena(gratings, mesh)
+                pool = arena.pool
+                starts = [pool.o_start[arena.slot[id(g)]] for g in gratings]
                 if mesh is not None:
-                    members, slot_of = _dedup_members(gratings)
-                    pool = self._pool_for(members, shards)
-                    starts = [pool.o_start[s] for s in slot_of]
+                    # full-arena fan-out: planes live on the mesh, rows
+                    # on 'model'
                     lay = _fanout_layout(starts, gkeys, int(pool.re.shape[0]))
-                    # full-arena fan-out: planes live on the mesh, rows on
-                    # 'model'
-                    pool_re, pool_im = self._mesh_arena(pool, mesh)
                 else:
-                    arena = self._resident_arena(gratings)
-                    pool = arena.pool
-                    starts = [pool.o_start[arena.slot[id(g)]] for g in gratings]
                     lay = _span_layout(
                         starts, [g.n_out for g in gratings], gkeys,
                         pool.align, int(pool.re.shape[0]),
                     )
-                    pool_re, pool_im = pool.re, pool.im
+                pool_re, pool_im = pool.re, pool.im
                 ux = [xs[j] for j in lay.uniq]
                 nbs = [int(xj.shape[0]) for xj in ux]
                 ub0 = [0]
@@ -1832,7 +1722,7 @@ class QueryEngine:
             zero = jax.device_put(np.zeros(x.shape, jnp.dtype(x.dtype)))
             with self._pools_lock:
                 zero = self._zero_rows.setdefault(key, zero)
-                while len(self._zero_rows) > self._max_pools:
+                while len(self._zero_rows) > self._max_zero_rows:
                     self._zero_rows.popitem(last=False)
         return rows + (zero,) * n_pad
 
@@ -1849,7 +1739,8 @@ class QueryEngine:
         whenever a tenant is added or removed or a grating is recorded
         again.  A batch that brings an undeclared grating still runs:
         its group's arena is rebuilt with it (``arena_builds`` in
-        :meth:`pool_stats` counts every packing)."""
+        :meth:`pool_stats` counts every packing).  A mesh keeps arenas of
+        its own, under the same declaration."""
         by_key: dict[tuple, list[FusedGrating]] = {}
         seen: set[int] = set()
         for g in gratings:
@@ -1859,32 +1750,57 @@ class QueryEngine:
         with self._pools_lock:
             self._resident = by_key
             for key, arena in list(self._arenas.items()):
-                ids = tuple(id(g) for g in by_key.get(key, ()))
+                ids = tuple(id(g) for g in by_key.get(key[0], ()))
                 if arena.declared != ids:
                     del self._arenas[key]
 
-    def _resident_arena(self, gratings: list[FusedGrating]) -> _Arena:
-        """The resident arena of the gratings' pool group, rebuilt when
-        one of them is not in it: declared residents first, then the
-        batch's undeclared gratings (so undeclared members never
-        accumulate)."""
-        key = _arena_key(gratings[0])
+    def _resident_arena(
+        self, gratings: list[FusedGrating], mesh=None
+    ) -> _Arena:
+        """The resident arena of the gratings' pool group on one device
+        (``mesh`` None) or on ``mesh``, rebuilt when one of them is not
+        in it: declared residents first, then the batch's undeclared
+        gratings (so undeclared members never accumulate).
+
+        The one place that decides an arena's layout: lane planes where
+        the grouped Pallas kernel reads it, 5-D bins for the dense path.
+        A mesh's arena is shard-tiled over its model axis
+        (:func:`_build_pool`) and placed there once, at build time, by
+        the serving rules' ``grating`` axis."""
+        key = (_arena_key(gratings[0]), mesh)
         with self._pools_lock:
             arena = self._arenas.get(key)
             if arena is not None and all(id(g) in arena.slot for g in gratings):
                 return arena
-            declared = list(self._resident.get(key, ()))
+            declared = list(self._resident.get(key[0], ()))
             # drop the old arena before packing the new one: two arenas
             # of a large group need not be live at once
             self._arenas.pop(key, None)
         ids = {id(g) for g in declared}
         extra, _ = _dedup_members([g for g in gratings if id(g) not in ids])
         members = declared + extra
+        pool = _build_pool(
+            members, self._pool_align(),
+            shards=1 if mesh is None else int(mesh.shape["model"]),
+            lanes=bool(getattr(self.config, "use_pallas", False)),
+        )
+        if mesh is not None:
+            from repro.distributed import sharding as shardlib  # lazy
+
+            spec = shardlib.spec_for(
+                pool.re.shape,
+                ("grating",) + (None,) * (pool.re.ndim - 1),
+                shardlib.make_serving_rules(),
+                mesh,
+            )
+            placed = jax.sharding.NamedSharding(mesh, spec)
+            pool = dataclasses.replace(
+                pool,
+                re=jax.device_put(pool.re, placed),
+                im=jax.device_put(pool.im, placed),
+            )
         arena = _Arena(
-            pool=_build_pool(
-                members, self._pool_align(),
-                lanes=bool(getattr(self.config, "use_pallas", False)),
-            ),
+            pool=pool,
             slot={id(g): i for i, g in enumerate(members)},
             declared=tuple(id(g) for g in declared),
         )
@@ -1893,7 +1809,7 @@ class QueryEngine:
             self._arena_builds += 1
         return arena
 
-    def _group_requests(self, requests, stream: bool = False) -> dict:
+    def _group_requests(self, requests) -> dict:
         """Pool-group the requests: same FFT geometry + encode semantics
         + storage dtype + clip geometry can share one arena/dispatch."""
         groups: dict[tuple, list[int]] = {}
@@ -1911,7 +1827,7 @@ class QueryEngine:
             key = (
                 g.fft_shape,
                 g.out_shape,
-                g.ker_shape if stream else None,
+                g.ker_shape,
                 bool(g.encode),
                 int(g.slm_bits) if g.encode else -1,
                 g.storage_dtype,
@@ -1935,66 +1851,7 @@ class QueryEngine:
             getattr(cfg, "stmul_block_o", None) or stmul_kernel.BLOCK_O
         )
 
-    def _pool_for(
-        self, members: list[FusedGrating], shards: int = 1
-    ) -> "GratingPool":
-        """Fetch or build the packed arena for this member list.
-
-        Pools are memoized per (member identity, alignment, shard
-        count): gratings are immutable once recorded, so object identity
-        is content identity, and the entry holds strong references to
-        its members — the arena is a *stable* device buffer reused
-        across dispatches instead of being re-packed per batch.  A small
-        LRU bound keeps retired membership sets (tenant churn) from
-        pinning dead gratings.  ``shards`` selects the mesh-aware
-        shard-tiled packing (see :func:`_build_pool`); the same member
-        set sharded differently is a different arena.
-        """
-        align = self._pool_align()
-        key = (tuple(id(g) for g in members), align, int(shards))
-        with self._pools_lock:
-            pool = self._pools.get(key)
-            if pool is not None:
-                self._pools.move_to_end(key)
-                return pool
-        pool = _build_pool(members, align, shards)
-        with self._pools_lock:
-            self._pools[key] = pool
-            while len(self._pools) > self._max_pools:
-                self._pools.popitem(last=False)
-        return pool
-
-    # -- mesh-sharded execution (query_many/query_stream_many mesh=) -------
-
-    def _mesh_arena(self, pool: "GratingPool", mesh) -> tuple[Array, Array]:
-        """The pool planes placed on the mesh — arena rows sharded over
-        the model axis via the serving rules' ``grating`` logical axis —
-        memoized per (pool, mesh) so the arena ships to the devices once
-        per membership, not once per dispatch.  Entries pin the pool
-        (strong ref: id-keyed lookups stay sound)."""
-        key = (id(pool), mesh)
-        with self._pools_lock:
-            hit = self._mesh_arenas.get(key)
-            if hit is not None:
-                self._mesh_arenas.move_to_end(key)
-                return hit[1], hit[2]
-        from repro.distributed import sharding as shardlib  # lazy
-
-        rules = shardlib.make_serving_rules()
-        spec = shardlib.spec_for(
-            pool.re.shape,
-            ("grating",) + (None,) * (pool.re.ndim - 1),
-            rules,
-            mesh,
-        )
-        sharding = jax.sharding.NamedSharding(mesh, spec)
-        re = jax.device_put(pool.re, sharding)
-        im = jax.device_put(pool.im, sharding)
-        with self._pools_lock:
-            self._mesh_arenas[key] = (pool, re, im)
-            while len(self._mesh_arenas) > self._max_pools:
-                self._mesh_arenas.popitem(last=False)
-        return re, im
+    # -- mesh-sharded execution (query_stream_many mesh=) ------------------
 
     def _mesh_fns(self, mesh) -> dict:
         """Per-mesh jitted sharded drivers, memoized (the Mesh is
@@ -2092,6 +1949,7 @@ class QueryEngine:
         ):
             # full-arena fan-out: every row reads the whole local tile
             # (zero offsets); `n_out` is the whole arena's row count
+            self._count_stream_trace()
             if len(xs) != 1:
                 raise ValueError(
                     "sharded stream drivers take one pre-packed batch "
@@ -2125,6 +1983,7 @@ class QueryEngine:
             xs, pool_re, pool_im, x_scale=None, *,
             ker_shape, fft_shape, plan, encode, slm_bits, n_out, k,
         ):
+            self._count_stream_trace()
             if len(xs) != 1:
                 raise ValueError(
                     "sharded stream drivers take one pre-packed batch "
@@ -2158,26 +2017,6 @@ class QueryEngine:
             spec = P("data", "model")
             return run(body, x, pool_re, pool_im, x_scale, (spec, spec))
 
-        def oneshot(
-            x, pool_re, pool_im, x_scale=None, *, fft_shape,
-            out_shape, n_out,
-        ):
-            # runs UN-jitted: the single-device one-shot dispatch is
-            # eager op-by-op, and wrapping the sharded body in jit lets
-            # XLA contract the bf16-upcast MAC differently (FMA in the
-            # fused complex multiply) — eager shard_map keeps the same
-            # op boundaries and is bitwise-equal; encode likewise
-            # happens eagerly in the caller
-            x, x_scale = pad_b(x, x_scale)
-            del n_out  # per-shard width = the local tile's own row count
-            qfn = self._pooled_query_shard_fn()
-
-            def body(xl, prl, pil, xsl):
-                y = qfn(xl, prl, pil, fft_shape, out_shape)
-                return y if xsl is None else y * xsl
-
-            return run(body, x, pool_re, pool_im, x_scale, P("data", "model"))
-
         return {
             "stream": jax.jit(
                 stream_many,
@@ -2193,39 +2032,7 @@ class QueryEngine:
                     "n_out", "k",
                 ),
             ),
-            "oneshot": oneshot,
         }
-
-    def _pooled_dispatch(
-        self,
-        x: Array,
-        pool: "GratingPool",
-        rows: np.ndarray,
-        proto: FusedGrating,
-        n_out: int | None = None,
-    ) -> Array:
-        """One pooled FFT+MAC+IFFT (+ the group's encode epilogue).
-
-        ``proto`` is any member grating — the group key guarantees they
-        share geometry and encode semantics.  ``n_out`` widens the
-        per-row read past the widest member slot when clip-dedup rows
-        cover union spans (default: the pool's slot width)."""
-        if n_out is None:
-            n_out = pool.n_out
-        pool_re, pool_im = pool.re, pool.im
-        rows = jnp.asarray(rows, jnp.int32)
-        query = self._pooled_query_fn()
-        if not proto.encode:
-            return query(
-                x, pool_re, pool_im, rows, n_out,
-                proto.fft_shape, proto.out_shape,
-            )
-        enc, x_scale = self._encode(x, proto.slm_bits)
-        y = query(
-            enc, pool_re, pool_im, rows, n_out,
-            proto.fft_shape, proto.out_shape,
-        )
-        return y * x_scale
 
     def _stream_many_impl(
         self, xs, pool_re, pool_im, rows, x_scale=None,
@@ -2261,8 +2068,9 @@ class QueryEngine:
         """Shared front half of the pooled overlap-save bodies: stack
         the per-row clips, encode (stream-global scale), pad the time
         axis and build the per-window pooled query closure (grouped
-        Pallas launch under ``use_pallas``, hoisted-gather einsum
-        otherwise) reading arena rows ``[rows[b], rows[b] + n_out)``.
+        Pallas launch on lane planes under ``use_pallas``, hoisted-gather
+        einsum otherwise) reading arena rows ``[rows[b], rows[b] +
+        n_out)``.
         Returns (one_window, win_out, x_scale)."""
         x = xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=0)
         rows = jnp.asarray(rows, jnp.int32)
@@ -2331,14 +2139,11 @@ class QueryEngine:
         return self._fold_chunk_states(chunk_s, chunk_i, k)
 
     def _pooled_query_fn(self):
-        """The per-group pooled FFT+MAC+IFFT: dense offset-gather einsum
-        by default, the grouped Pallas stmul launch under ``use_pallas``."""
-        cfg = self.config
-        if not getattr(cfg, "use_pallas", False):
-            return _pooled_query_dense
+        """The per-window pooled FFT+MAC+IFFT of the Pallas path: the
+        grouped stmul launch on a lane-plane arena."""
         from repro.kernels.stmul import ops as stmul_ops  # lazy import
 
-        min_mxu_c = getattr(cfg, "stmul_min_mxu_c", None)
+        cfg = self.config
         tiles = dict(
             block_o=getattr(cfg, "stmul_block_o", None),
             block_f=getattr(cfg, "stmul_block_f", None),
@@ -2347,39 +2152,7 @@ class QueryEngine:
         def query(x, pool_re, pool_im, rows, n_out, fft_shape, out_shape):
             return stmul_ops.query_grating_pooled(
                 x, pool_re, pool_im, rows, n_out, fft_shape, out_shape,
-                min_mxu_c=min_mxu_c, **tiles,
-            )
-
-        return query
-
-    def _pooled_query_shard_fn(self):
-        """Shard-local pooled FFT+MAC+IFFT for the mesh bodies: every
-        clip row reads the local arena tile whole (zero offsets) —
-        ``stmul_ops.pooled_query_shard`` under ``use_pallas``, the dense
-        offset-gather einsum at offset 0 otherwise."""
-        cfg = self.config
-        if not getattr(cfg, "use_pallas", False):
-
-            def dense(x, pool_re, pool_im, fft_shape, out_shape):
-                rows = jnp.zeros((x.shape[0],), jnp.int32)
-                return _pooled_query_dense(
-                    x, pool_re, pool_im, rows, int(pool_re.shape[0]),
-                    fft_shape, out_shape,
-                )
-
-            return dense
-        from repro.kernels.stmul import ops as stmul_ops  # lazy import
-
-        min_mxu_c = getattr(cfg, "stmul_min_mxu_c", None)
-        tiles = dict(
-            block_o=getattr(cfg, "stmul_block_o", None),
-            block_f=getattr(cfg, "stmul_block_f", None),
-        )
-
-        def query(x, pool_re, pool_im, fft_shape, out_shape):
-            return stmul_ops.pooled_query_shard(
-                x, pool_re, pool_im, fft_shape, out_shape,
-                min_mxu_c=min_mxu_c, **tiles,
+                **tiles,
             )
 
         return query

@@ -32,17 +32,17 @@ Grouped (pooled cross-tenant) variant
 -------------------------------------
 ``spectral_mac_grouped_pallas`` contracts a whole *pooled* grating arena
 in one launch: the gratings of every resident tenant are stacked on the
-O axis (``(ΣO_pad, C, F)``) and each query row ``b`` reads only its own
+O axis (``(ΣO_pad, C, R, L)``) and each query row ``b`` reads only its own
 tenant's O-slice, selected by a per-row block offset prefetched into
 SMEM (``pltpu.PrefetchScalarGridSpec`` — the offset feeds the grating
 BlockSpec's index map, so the right arena tile is DMA'd per program).
 A mixed-tenant batch of N same-geometry tenants is thus one kernel
 launch instead of N.  Arena planes may be stored bf16 (half-precision
 grating storage); tiles are up-cast to f32 in-kernel so the contraction
-accumulates in f32 either way.  The bins may also come as lane planes,
+accumulates in f32 either way.  The bins come as lane planes,
 ``(…, R, L)`` (``repro.core.spectral_conv.to_lane_planes``), the layout
-of the resident arenas: tiles are then whole rows of L lanes, and the
-output is written in the layout the inverse transform reads.
+of the resident arenas: tiles are whole rows of L lanes, and the output
+is written in the layout the inverse transform reads.
 
 Tiling
 ------
@@ -230,28 +230,28 @@ def spectral_mac_pallas(
 
 
 def _stmul_kernel_grouped(
-    off_ref, xr_ref, xi_ref, gr_ref, gi_ref, yr_ref, yi_ref, *, use_mxu: bool
+    off_ref, xr_ref, xi_ref, gr_ref, gi_ref, yr_ref, yi_ref
 ):
-    """One (1, bO, *tile) tile of the pooled contraction.
+    """One (1, bO, b_r, L) tile of the pooled contraction, C on the VPU.
 
     ``off_ref`` is the prefetched per-row block-offset vector — consumed
     by the grating BlockSpec's index map, not here.  Tiles up-cast to
     f32 (arena planes may be bf16) so accumulation is f32 either way.
     """
-    xr = xr_ref[...].astype(jnp.float32)  # (1, C, *tile)
+    xr = xr_ref[...].astype(jnp.float32)  # (1, C, b_r, L)
     xi = xi_ref[...].astype(jnp.float32)
-    gr = gr_ref[...].astype(jnp.float32)  # (bO, C, *tile)
+    gr = gr_ref[...].astype(jnp.float32)  # (bO, C, b_r, L)
     gi = gi_ref[...].astype(jnp.float32)
-    t1 = _contract_c(xr, gr, use_mxu)
-    t2 = _contract_c(xi, gi, use_mxu)
-    t3 = _contract_c(xr + xi, gr + gi, use_mxu)
+    t1 = _contract_c(xr, gr, False)
+    t2 = _contract_c(xi, gi, False)
+    t3 = _contract_c(xr + xi, gr + gi, False)
     yr_ref[...] = t1 - t2
     yi_ref[...] = t3 - t1 - t2
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("n_out", "block_o", "block_f", "min_mxu_c", "interpret"),
+    static_argnames=("n_out", "block_o", "block_f", "interpret"),
 )
 def spectral_mac_grouped_pallas(
     xr: Array,
@@ -263,27 +263,25 @@ def spectral_mac_grouped_pallas(
     n_out: int,
     block_o: int = BLOCK_O,
     block_f: int | None = None,
-    min_mxu_c: int | None = None,
     interpret: bool = False,
 ) -> tuple[Array, Array]:
     """Grouped/ragged spectral MAC against a pooled grating arena.
 
-        y[b, o, f] = Σ_c  x[b, c, f] · g[o_start[b] + o, c, f]
+        y[b, o, r, l] = Σ_c  x[b, c, r, l] · g[o_start[b] + o, c, r, l]
 
     — one launch contracts every query row against its own tenant's
     O-slice of the arena (per-row offsets via scalar prefetch).
 
-    The bins come flat, ``(…, F)``, and are padded to the lane tile
-    ``block_f`` (default ``BLOCK_F``) at every call; or as lane planes,
-    ``(…, R, L)`` with R a multiple of 8 and L of 128
-    (``repro.core.spectral_conv.to_lane_planes``), which are read and
-    written as they lie, in tiles of whole rows — ``block_f // L`` of
-    them where ``block_f`` is given, else ``BLOCK_R // C`` (at least 8),
-    cut to a divisor of R — with C contracted on the VPU.
+    The bins come as lane planes, ``(…, R, L)`` with R a multiple of 8
+    and L of 128 (``repro.core.spectral_conv.to_lane_planes``), which
+    are read and written as they lie, in tiles of whole rows —
+    ``block_f // L`` of them where ``block_f`` is given, else
+    ``BLOCK_R // C`` (at least 8), cut to a divisor of R — with C
+    contracted on the VPU.
 
     Args:
-      xr, xi: (B, C, *bins) float32 query-spectrum planes.
-      gr, gi: (ΣO_pad, C, *bins) float32 *or bfloat16* pooled arena
+      xr, xi: (B, C, R, L) float32 query-spectrum planes.
+      gr, gi: (ΣO_pad, C, R, L) float32 *or bfloat16* pooled arena
         planes (half-precision grating storage stays narrow in HBM;
         tiles up-cast in-kernel, f32 accumulation).
       o_start: (B,) int32 first-row offset per query row; every offset
@@ -291,10 +289,16 @@ def spectral_mac_grouped_pallas(
         aligned — see ``repro.core.engine.GratingPool``).
       n_out: rows read/written per query row (the widest member slot).
 
-    Returns (yr, yi): (B, n_out, *bins) float32.
+    Returns (yr, yi): (B, n_out, R, L) float32.
     """
-    B, C = xr.shape[:2]
-    bins = xr.shape[2:]
+    if gr.ndim != 4 or xr.ndim != 4:
+        raise ValueError(
+            "the grouped MAC takes lane planes: arena (ΣO, C, R, L) and "
+            f"spectra (B, C, R, L); got arena {tuple(gr.shape)} and "
+            f"spectra {tuple(xr.shape)} (see "
+            "repro.core.spectral_conv.to_lane_planes)"
+        )
+    B, C, rows, lanes = xr.shape
     bO = block_o
     n_pad = (-n_out) % bO
 
@@ -306,71 +310,49 @@ def spectral_mac_grouped_pallas(
         widths[axis] = (0, rem)
         return jnp.pad(a, widths)
 
-    if len(bins) == 2:  # lane planes
-        rows, lanes = bins
-        want = max(8, block_f // lanes if block_f else BLOCK_R // C)
-        b_r = max(r for r in range(8, want + 1, 8) if rows % r == 0)
-        tile = (b_r, lanes)
-        n_f = rows // b_r
-        xr_p, xi_p, gr_p, gi_p = xr, xi, gr, gi
-        use_mxu = False
-    else:
-        (F,) = bins
-        bF = min(block_f or BLOCK_F, F)
-        tile = (bF,)
-        xr_p = pad_to(xr, 2, bF)
-        xi_p = pad_to(xi, 2, bF)
-        gr_p = pad_to(gr, 2, bF)
-        gi_p = pad_to(gi, 2, bF)
-        n_f = xr_p.shape[2] // bF
-        threshold = MIN_MXU_C if min_mxu_c is None else int(min_mxu_c)
-        use_mxu = C >= threshold
+    want = max(8, block_f // lanes if block_f else BLOCK_R // C)
+    b_r = max(r for r in range(8, want + 1, 8) if rows % r == 0)
+    assert rows % b_r == 0  # tiles of whole rows
+    tile = (b_r, lanes)
     # row-pad the arena so the widest tile read (o_start + n_out_pad)
     # stays in bounds even for the last member slot
-    gr_p = pad_to(gr_p, 0, bO)
-    gi_p = pad_to(gi_p, 0, bO)
+    gr = pad_to(gr, 0, bO)
+    gi = pad_to(gi, 0, bO)
     if n_pad:
-        widths = [(0, n_pad)] + [(0, 0)] * (gr_p.ndim - 1)
-        gr_p = jnp.pad(gr_p, widths)
-        gi_p = jnp.pad(gi_p, widths)
+        widths = [(0, n_pad)] + [(0, 0)] * (gr.ndim - 1)
+        gr = jnp.pad(gr, widths)
+        gi = jnp.pad(gi, widths)
     n_out_pad = n_out + n_pad
-    out_bins = xr_p.shape[2:]
-    rest = (0,) * (len(tile) - 1)
-
-    kernel = functools.partial(_stmul_kernel_grouped, use_mxu=use_mxu)
     off_blocks = (o_start // bO).astype(jnp.int32)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, n_out_pad // bO, n_f),
+        grid=(B, n_out_pad // bO, rows // b_r),
         in_specs=[
-            pl.BlockSpec((1, C) + tile, lambda b, o, f, off: (b, 0, f) + rest),
-            pl.BlockSpec((1, C) + tile, lambda b, o, f, off: (b, 0, f) + rest),
+            pl.BlockSpec((1, C) + tile, lambda b, o, f, off: (b, 0, f, 0)),
+            pl.BlockSpec((1, C) + tile, lambda b, o, f, off: (b, 0, f, 0)),
             pl.BlockSpec(
-                (bO, C) + tile, lambda b, o, f, off: (off[b] + o, 0, f) + rest
+                (bO, C) + tile, lambda b, o, f, off: (off[b] + o, 0, f, 0)
             ),
             pl.BlockSpec(
-                (bO, C) + tile, lambda b, o, f, off: (off[b] + o, 0, f) + rest
+                (bO, C) + tile, lambda b, o, f, off: (off[b] + o, 0, f, 0)
             ),
         ],
         out_specs=[
-            pl.BlockSpec((1, bO) + tile, lambda b, o, f, off: (b, o, f) + rest),
-            pl.BlockSpec((1, bO) + tile, lambda b, o, f, off: (b, o, f) + rest),
+            pl.BlockSpec((1, bO) + tile, lambda b, o, f, off: (b, o, f, 0)),
+            pl.BlockSpec((1, bO) + tile, lambda b, o, f, off: (b, o, f, 0)),
         ],
     )
+    out = jax.ShapeDtypeStruct((B, n_out_pad, rows, lanes), jnp.float32)
     yr, yi = pl.pallas_call(
-        kernel,
+        _stmul_kernel_grouped,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, n_out_pad) + out_bins, jnp.float32),
-            jax.ShapeDtypeStruct((B, n_out_pad) + out_bins, jnp.float32),
-        ],
+        out_shape=[out, out],
         interpret=interpret,
-    )(off_blocks, xr_p, xi_p, gr_p, gi_p)
-    if out_bins == bins and not n_pad:
+    )(off_blocks, xr, xi, gr, gi)
+    if not n_pad:
         return yr, yi
-    crop = (slice(None), slice(0, n_out)) + tuple(slice(0, n) for n in bins)
-    return yr[crop], yi[crop]
+    return yr[:, :n_out], yi[:, :n_out]
 
 
 # ---------------------------------------------------------------------------

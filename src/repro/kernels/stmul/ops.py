@@ -90,40 +90,6 @@ def spectral_mac(
     return (yr + 1j * yi).reshape(B, O, *fshape)
 
 
-def spectral_mac_grouped(
-    xhat: Array,
-    pool_re: Array,
-    pool_im: Array,
-    o_start: Array,
-    n_out: int,
-    *,
-    min_mxu_c: int | None = None,
-    block_o: int | None = None,
-    block_f: int | None = None,
-) -> Array:
-    """Pooled cross-tenant spectral MAC via the grouped Pallas kernel.
-
-        Ŷ[b, o, f] = Σ_c  X̂[b, c, f] · Gpool[o_start[b] + o, c, f]
-
-    Args:
-      xhat: (B, C, *F) complex query spectra (the stacked mixed-tenant
-        batch).
-      pool_re / pool_im: (ΣO_pad, C, *F) split real/imag planes of the
-        pooled grating arena — float32 or bfloat16 (half-precision
-        grating storage; the kernel up-casts tiles, f32 accumulation).
-      o_start: (B,) int32 per-row first-row offsets into the arena, on
-        the ``block_o`` grid.
-      n_out: O rows produced per query row.
-
-    Returns (B, n_out, *F) complex64.
-    """
-    yr, yi = _mac_grouped_planes(
-        jnp.real(xhat), jnp.imag(xhat), pool_re, pool_im, o_start, n_out,
-        min_mxu_c=min_mxu_c, block_o=block_o, block_f=block_f,
-    )
-    return yr + 1j * yi
-
-
 @span("sthc.mac")
 def _mac_grouped_planes(
     xr: Array,
@@ -133,43 +99,31 @@ def _mac_grouped_planes(
     o_start: Array,
     n_out: int,
     *,
-    min_mxu_c: int | None,
     block_o: int | None,
     block_f: int | None,
 ) -> tuple[Array, Array]:
-    """:func:`spectral_mac_grouped` on split (real, imaginary) planes.
+    """Pooled cross-tenant spectral MAC on lane planes, via the grouped
+    Pallas kernel:
 
-    The arena's stored layout decides the bins' layout throughout: a
-    5-D arena, (ΣO, C, FH, FW, FTr), is flattened here (and the kernel
-    pads its bins to the lane tile at every call), and the output comes
-    back 5-D; an arena of lane planes, (ΣO, C, R, L), takes the spectra
-    as lane planes too, and the output is the kernel's own (B, n_out,
-    R, L), which :func:`repro.core.spectral_conv.irfft3_lanes` reads as
-    it lies."""
-    tiles = _tile_kwargs(None, block_o, block_f)
-    B, C = xr.shape[:2]
-    fshape = xr.shape[2:]
-    if pool_re.ndim == 5:
-        f = 1
-        for n in fshape:
-            f *= n
-        so = pool_re.shape[0]
-        xr, xi = xr.reshape(B, C, f), xi.reshape(B, C, f)
-        pool_re = pool_re.reshape(so, C, f)
-        pool_im = pool_im.reshape(so, C, f)
-    yr, yi = _kernel.spectral_mac_grouped_pallas(
+        Ŷ[b, o, bins] = Σ_c  X̂[b, c, bins] · Gpool[o_start[b] + o, c, bins]
+
+    ``xr``/``xi`` are the (B, C, R, L) query spectra, ``pool_re`` /
+    ``pool_im`` the (ΣO_pad, C, R, L) arena (float32 or bfloat16; f32
+    accumulation), both as :func:`repro.core.spectral_conv.to_lane_planes`
+    lays them out.  ``o_start`` holds each row's first arena row, on the
+    ``block_o`` grid.  Returns the kernel's own (B, n_out, R, L) planes,
+    which :func:`repro.core.spectral_conv.irfft3_lanes` reads as they
+    lie."""
+    return _kernel.spectral_mac_grouped_pallas(
         xr.astype(jnp.float32),
         xi.astype(jnp.float32),
         pool_re,
         pool_im,
         jnp.asarray(o_start, jnp.int32),
         n_out=int(n_out),
-        min_mxu_c=min_mxu_c,
         interpret=_use_interpret(),
-        **tiles,
+        **_tile_kwargs(None, block_o, block_f),
     )
-    out = (B, int(n_out), *fshape)
-    return yr.reshape(out), yi.reshape(out)
 
 
 def query_grating_pooled(
@@ -181,20 +135,14 @@ def query_grating_pooled(
     fft_shape: tuple[int, int, int],
     out_shape: tuple[int, int, int],
     *,
-    min_mxu_c: int | None = None,
     block_o: int | None = None,
     block_f: int | None = None,
 ) -> Array:
     """Pooled counterpart of :func:`query_grating_pallas`: one forward
     FFT over the stacked mixed-tenant batch, one grouped-kernel launch
-    against the pooled arena, one inverse FFT — all on split real /
-    imaginary planes, in the arena's bin layout (lane planes where the
-    arena is stored so, see :func:`_mac_grouped_planes`)."""
-    if pool_re.ndim == 4:
-        rfft, irfft = spectral_conv.rfft3_lanes, spectral_conv.irfft3_lanes
-    else:
-        rfft, irfft = spectral_conv.rfft3_planes, spectral_conv.irfft3_planes
-    xr, xi = rfft(x, fft_shape)
+    against the pooled lane-plane arena, one inverse FFT — all on split
+    real / imaginary lane planes (see :func:`_mac_grouped_planes`)."""
+    xr, xi = spectral_conv.rfft3_lanes(x, fft_shape)
     yr, yi = _mac_grouped_planes(
         xr,
         xi,
@@ -202,52 +150,10 @@ def query_grating_pooled(
         pool_im,
         o_start,
         n_out,
-        min_mxu_c=min_mxu_c,
         block_o=block_o,
         block_f=block_f,
     )
-    return irfft(yr, yi, fft_shape, out_shape)
-
-
-def pooled_query_shard(
-    x: Array,
-    pool_re: Array,
-    pool_im: Array,
-    fft_shape: tuple[int, int, int],
-    out_shape: tuple[int, int, int],
-    *,
-    min_mxu_c: int | None = None,
-    block_o: int | None = None,
-    block_f: int | None = None,
-) -> Array:
-    """Shard-local full-arena fan-out: :func:`query_grating_pooled` with
-    every clip row reading the local arena tile *whole* (zero offsets,
-    ``n_out`` = the tile's row count).
-
-    The grouped-MAC body of the engine's mesh executor: under
-    ``shard_map`` each model-axis device holds one ``shard_rows`` tile
-    of the pooled arena and contracts it against its data-shard's clip
-    rows — no offsets cross a shard, no psum follows (each tenant's
-    O-slice lives on exactly one tile by packing).  Callers must pass
-    ``check_vma=False`` to ``jax.shard_map``: ``pallas_call`` has no
-    varying-manual-axes rule, and this body is collective-free anyway.  Bitwise
-    equal to the offset-gather dispatch at the corresponding rows — the
-    per-(row, kernel, frequency) C-contraction is the same op sequence
-    regardless of the tile's row offset.
-    """
-    rows = jnp.zeros((x.shape[0],), jnp.int32)
-    return query_grating_pooled(
-        x,
-        pool_re,
-        pool_im,
-        rows,
-        int(pool_re.shape[0]),
-        fft_shape,
-        out_shape,
-        min_mxu_c=min_mxu_c,
-        block_o=block_o,
-        block_f=block_f,
-    )
+    return spectral_conv.irfft3_lanes(yr, yi, fft_shape, out_shape)
 
 
 @span("sthc.readout")

@@ -656,8 +656,6 @@ class VideoSearchServer:
         is recorded again (after an eviction or an integrity failure).
         Tenants the cache does not hold are left out, so the arenas stay
         within the cache's budget."""
-        if self.mesh is not None:  # the mesh path packs its own arenas
-            return
         gratings = []
         with self._lock:
             for ten in self._tenants.values():
@@ -823,7 +821,7 @@ class VideoSearchServer:
                     self._fetch_grating(key[0], ten)
                     for (key, _), ten in zip(order, tens)
                 ]
-                if self.mesh is None and any(
+                if any(
                     g is not ten.resident for g, ten in zip(gratings, tens)
                 ):
                     # a grating recorded again since the last declaration

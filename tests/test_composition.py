@@ -165,26 +165,30 @@ def test_chunked_cursor_on_lane_arena_equals_one_shot():
 
 
 def test_native_layout_counter_stays_zero_on_5d_arenas():
-    """The dense path, the one-shot pooled query and the mesh path keep
-    5-D arenas: none of their dispatches counts as read in the lane
-    layout."""
+    """Only the dense path keeps 5-D arenas, and none of its dispatches
+    counts as read in the lane layout; the Pallas mesh path reads a
+    lane-plane arena, and every one of its dispatches counts."""
     from repro.launch.mesh import make_local_mesh
 
     streams = _streams(3)
     dense, _, gratings = _engines(False)
     dense.query_stream_many([(gratings[0], streams[0])], readout_k=1)
+    dense.query_stream_many(
+        [(gratings[0], streams[0]), (gratings[2], streams[1])],
+        readout_k=1, mesh=make_local_mesh(1, 1),
+    )
+    stats = dense.pool_stats()
+    assert stats["dispatches"] == 2
+    assert stats["native_layout_dispatches"] == 0
+    assert all(a.pool.re.ndim == 5 for a in dense._arenas.values())
     pallas, _, gratings = _engines(True)
-    reqs = [(gratings[t], jnp.asarray(x[..., :8]))
-            for t, x in zip((0, 2), streams)]
-    pallas.query_many(reqs)
     pallas.query_stream_many(
         [(gratings[0], streams[0]), (gratings[2], streams[1])],
         readout_k=1, mesh=make_local_mesh(1, 1),
     )
-    for engine in (dense, pallas):
-        stats = engine.pool_stats()
-        assert stats["dispatches"] > 0
-        assert stats["native_layout_dispatches"] == 0
+    stats = pallas.pool_stats()
+    assert stats["native_layout_dispatches"] == stats["dispatches"] == 1
+    assert all(a.pool.re.ndim == 4 for a in pallas._arenas.values())
 
 
 def test_whole_state_slices_equal_device_slices():

@@ -431,14 +431,15 @@ def test_query_is_one_program_equal_to_eager_composition(pipe, store, rng):
 
 
 def test_query_many_matches_query_loop(rng):
-    """Pooled one-shot answers equal the per-tenant query loop: mixed O,
-    a duplicate grating (two requests, one tenant) and mixed batch
-    sizes in one call."""
+    """A pooled batch of one-window clips (each clip its own stream)
+    equals the per-tenant one-shot query loop: mixed O, a duplicate
+    grating (two requests, one tenant) and mixed batch sizes in one
+    call."""
     x1, x2 = _clips(rng, B=2), _clips(rng, B=1)
     eng = QueryEngine(STHCConfig(fidelity=fid.physical()))
     g1 = eng.record(_kernels(rng, O=3), (20, 24, 10))
     g2 = eng.record(_kernels(rng, O=5), (20, 24, 10))
-    outs = eng.query_many([(g1, x1), (g2, x2), (g1, x2)])
+    outs = eng.query_stream_many([(g1, x1), (g2, x2), (g1, x2)])
     refs = [eng.query(g1, x1), eng.query(g2, x2), eng.query(g1, x2)]
     for out, ref in zip(outs, refs):
         assert out.shape == ref.shape
@@ -465,7 +466,9 @@ def test_query_many_paper_geometry_mixed_fidelity_one_pool_group(rng):
     requests = [(g1, x), (g2, x)]
     # same encode semantics (SLM at 8 bits) + same FFT grid -> one group
     assert len(eng_phys._group_requests(requests)) == 1
-    outs = eng_phys.query_many(requests)
+    before = eng_phys.pool_stats()["dispatches"]
+    outs = eng_phys.query_stream_many(requests)
+    assert eng_phys.pool_stats()["dispatches"] == before + 1
     refs = [eng_phys.query(g1, x), eng_sub.query(g2, x)]
     for out, ref in zip(outs, refs):
         rel = float(jnp.linalg.norm(out - ref) / jnp.linalg.norm(ref))
@@ -474,21 +477,23 @@ def test_query_many_paper_geometry_mixed_fidelity_one_pool_group(rng):
 
 def test_pooled_dispatch_single_forward_fft(rng):
     """The pooled dataflow claim: one group dispatch = exactly one
-    forward FFT + one inverse FFT, however many tenants it serves."""
-    from repro.core.engine import _dedup_members
-
-    x = _clips(rng, B=2)
+    forward FFT + one inverse FFT per window, however many tenants and
+    stream rows it serves."""
+    x = _clips(rng, B=1)
     eng = QueryEngine(STHCConfig(fidelity=fid.physical()))
     g1 = eng.record(_kernels(rng, O=3), (20, 24, 10))
     g2 = eng.record(_kernels(rng, O=3), (20, 24, 10))
-    members, slot_of = _dedup_members([g1, g2])
-    pool = eng._pool_for(members)
-    rows = np.asarray(
-        [pool.o_start[slot_of[0]], pool.o_start[slot_of[1]]], np.int32
-    )
+    pool = eng._resident_arena([g1, g2]).pool
+    rows = np.asarray(pool.o_start, np.int32)
+    plan = eng.stream_plan_for(g1, x.shape[-1])
+    assert plan.n_blocks == 1  # one window
     jaxpr = jax.make_jaxpr(
-        lambda x: eng._pooled_dispatch(x, pool, rows, g1)
-    )(x)
+        lambda a, b: eng._stream_many_impl(
+            (a, b), pool.re, pool.im, rows, ker_shape=g1.ker_shape,
+            fft_shape=g1.fft_shape, plan=plan, encode=g1.encode,
+            slm_bits=g1.slm_bits, n_out=pool.n_out,
+        )
+    )(x, x + 1.0)
     assert _count_ffts(jaxpr.jaxpr, "RFFT") == 1
     assert _count_ffts(jaxpr.jaxpr, "IRFFT") == 1
 
@@ -520,42 +525,48 @@ def test_query_many_pallas_grouped_matches_dense(rng):
     k1, k2 = _kernels(rng, O=3), _kernels(rng, O=5)
     gd1, gd2 = dense.record(k1, (20, 24, 10)), dense.record(k2, (20, 24, 10))
     gp1, gp2 = pallas.record(k1, (20, 24, 10)), pallas.record(k2, (20, 24, 10))
-    outs_d = dense.query_many([(gd1, x), (gd2, x)])
-    outs_p = pallas.query_many([(gp1, x), (gp2, x)])
+    outs_d = dense.query_stream_many([(gd1, x), (gd2, x)])
+    outs_p = pallas.query_stream_many([(gp1, x), (gp2, x)])
     for d, p in zip(outs_d, outs_p):
         rel = float(jnp.linalg.norm(p - d) / jnp.linalg.norm(d))
         assert rel <= 1e-4, rel
+    assert pallas.pool_stats()["native_layout_dispatches"] == 1
+    assert dense.pool_stats()["native_layout_dispatches"] == 0
 
 
 def test_pool_arena_reused_across_calls(rng):
     """The packed arena is a stable buffer: repeated dispatches with the
-    same resident gratings hit one memoized GratingPool."""
+    same resident gratings pack it once."""
     x = _clips(rng)
     eng = QueryEngine(STHCConfig(fidelity=fid.ideal()))
     g1 = eng.record(_kernels(rng, O=2), (20, 24, 10))
     g2 = eng.record(_kernels(rng, O=2), (20, 24, 10))
-    eng.query_many([(g1, x), (g2, x)])
-    pools_after_first = len(eng._pools)
-    eng.query_many([(g1, x), (g2, x)])
-    eng.query_many([(g1, x), (g2, x)])
-    assert len(eng._pools) == pools_after_first == 1
+    eng.set_resident([g1, g2])
+    eng.query_stream_many([(g1, x), (g2, x)])
+    arena = eng._resident_arena([g1])
+    eng.query_stream_many([(g1, x), (g2, x)])
+    eng.query_stream_many([(g2, x)])
+    assert eng.pool_stats()["arena_builds"] == 1
+    assert eng._resident_arena([g1, g2]) is arena
 
 
 def test_query_many_rejects_channel_mismatch(rng):
     eng = QueryEngine(STHCConfig(fidelity=fid.ideal()))
     g = eng.record(_kernels(rng, C=1), (20, 24, 10))
     with pytest.raises(ValueError, match="channels"):
-        eng.query_many([(g, _clips(rng, C=3))])
+        eng.query_stream_many([(g, _clips(rng, C=3))])
 
 
 # -- grouped stmul kernel vs the v1 loop oracle --------------------------------
 
 
-@pytest.mark.parametrize("C", [1, 8])  # spans the VPU/MXU routing split
+@pytest.mark.parametrize("C", [1, 8])  # one and many contracted channels
 def test_stmul_grouped_matches_loop_oracle(C):
-    """One grouped launch over a pooled arena equals the per-request v1
-    loop oracle — shared offsets included (two rows, one tenant)."""
+    """One grouped launch over a pooled lane-plane arena equals the
+    per-request loop oracle — shared offsets included (two rows, one
+    tenant) — and an arena that is not lane planes is refused."""
     rng = np.random.RandomState(C)
+    s = (6, 7, 8)  # rfft grid: bins (6, 7, 5)
     sh = (6, 7, 5)
     B, n_out, block_o = 4, 4, 4
     pool = (rng.randn(12, C, *sh) + 1j * rng.randn(12, C, *sh)).astype(
@@ -570,27 +581,34 @@ def test_stmul_grouped_matches_loop_oracle(C):
     ref = stmul_ref.spectral_mac_grouped_ref(
         xh, jnp.asarray(pool), o_start, n_out
     )
-    got = stmul_ops.spectral_mac_grouped(
-        xh,
-        jnp.asarray(pool.real),
-        jnp.asarray(pool.imag),
-        o_start,
-        n_out,
-        block_o=block_o,
+    ref_r, ref_i = sc.to_lane_planes(jnp.real(ref), jnp.imag(ref), s)
+    xr, xi = sc.to_lane_planes(jnp.real(xh), jnp.imag(xh), s)
+    pr, pi = sc.to_lane_planes(
+        jnp.asarray(pool.real), jnp.asarray(pool.imag), s
+    )
+    assert pr.shape == (12, C, 5 * 8, 128)
+    got_r, got_i = stmul_ops._mac_grouped_planes(
+        xr, xi, pr, pi, o_start, n_out, block_o=block_o, block_f=None
     )
     tol = 1e-4 * float(jnp.max(jnp.abs(ref))) + 1e-6
-    np.testing.assert_allclose(got, ref, atol=tol)
+    np.testing.assert_allclose(got_r, ref_r, atol=tol)
+    np.testing.assert_allclose(got_i, ref_i, atol=tol)
     # bf16 arena planes (half-precision grating storage): f32-accumulated
-    got_bf = stmul_ops.spectral_mac_grouped(
-        xh,
-        jnp.asarray(pool.real, jnp.bfloat16),
-        jnp.asarray(pool.imag, jnp.bfloat16),
-        o_start,
-        n_out,
-        block_o=block_o,
+    got_r, got_i = stmul_ops._mac_grouped_planes(
+        xr, xi, pr.astype(jnp.bfloat16), pi.astype(jnp.bfloat16),
+        o_start, n_out, block_o=block_o, block_f=None,
     )
-    rel = float(jnp.linalg.norm(got_bf - ref) / jnp.linalg.norm(ref))
+    err = jnp.sqrt(
+        jnp.sum((got_r - ref_r) ** 2) + jnp.sum((got_i - ref_i) ** 2)
+    )
+    rel = float(err / jnp.linalg.norm(ref))
     assert rel <= 2e-2, rel
+    with pytest.raises(ValueError, match="lane planes"):
+        stmul_ops._mac_grouped_planes(
+            jnp.real(xh), jnp.imag(xh), jnp.asarray(pool.real),
+            jnp.asarray(pool.imag), o_start, n_out, block_o=block_o,
+            block_f=None,
+        )
 
 
 # -- half-precision (bf16 split-real) grating storage --------------------------
@@ -628,8 +646,8 @@ def test_bf16_storage_halves_nbytes_and_cache_bytes(rng):
 
 def test_bf16_pooled_query_close_to_f32(rng):
     """bf16-at-rest, f32-accumulation: one-shot and pooled queries stay
-    within tolerance of the f32 grating, and the pooled bf16 answer
-    equals the per-tenant bf16 query."""
+    within tolerance of the f32 grating, and the pooled bf16 answer (a
+    one-window stream) equals the per-tenant bf16 query."""
     x = _clips(rng)
     k = _kernels(rng)
     f32 = QueryEngine(STHCConfig(fidelity=fid.physical()))
@@ -640,7 +658,7 @@ def test_bf16_pooled_query_close_to_f32(rng):
     yf, yb = f32.query(gf, x), bf16.query(gb, x)
     rel = float(jnp.linalg.norm(yb - yf) / jnp.linalg.norm(yf))
     assert rel <= 2e-2, rel
-    (pooled,) = bf16.query_many([(gb, x)])
+    (pooled,) = bf16.query_stream_many([(gb, x)])
     rel = float(jnp.linalg.norm(pooled - yb) / jnp.linalg.norm(yb))
     assert rel <= 1e-5, rel
 
@@ -689,7 +707,7 @@ def test_query_many_clip_dedup_paper_geometry_matches_loop(rng):
         for _ in range(4)
     ]
     before = eng.pool_stats()
-    outs = eng.query_many([(g, x) for g in gs])
+    outs = eng.query_stream_many([(g, x) for g in gs])
     after = eng.pool_stats()
     for g, out in zip(gs, outs):
         ref = eng.query(g, x)
@@ -712,7 +730,9 @@ def test_query_many_dedup_is_content_addressed_not_identity(rng):
     g1 = eng.record(_kernels(rng, O=2), (20, 24, 10))
     g2 = eng.record(_kernels(rng, O=3), (20, 24, 10))
     before = eng.pool_stats()
-    outs = eng.query_many([(g1, same), (g2, also_same), (g1, different)])
+    outs = eng.query_stream_many(
+        [(g1, same), (g2, also_same), (g1, different)]
+    )
     delta = {
         k: eng.pool_stats()[k] - before[k] for k in ("rows_offered", "rows_dispatched")
     }
@@ -731,7 +751,7 @@ def test_query_many_dedup_off_is_row_per_request(rng):
     g1 = eng.record(_kernels(rng, O=2), (20, 24, 10))
     g2 = eng.record(_kernels(rng, O=4), (20, 24, 10))
     before = eng.pool_stats()
-    outs = eng.query_many([(g1, x), (g2, x)], dedup=False)
+    outs = eng.query_stream_many([(g1, x), (g2, x)], dedup=False)
     after = eng.pool_stats()
     assert after["rows_dispatched"] - before["rows_dispatched"] == 2
     assert after["rows_saved"] == before["rows_saved"]

@@ -442,4 +442,4 @@ def test_guarded_annotations_present_in_runtime_classes():
     assert {"_tenants", "_sthcs", "_quarantined"} <= guarded["VideoSearchServer"]
     assert {"_state", "failures", "trips"} <= guarded["CircuitBreaker"]
     assert {"_tracked", "expired"} <= guarded["Watchdog"]
-    assert {"_pools", "_arenas", "_resident"} <= guarded["QueryEngine"]
+    assert {"_arenas", "_resident", "_zero_rows"} <= guarded["QueryEngine"]

@@ -149,14 +149,45 @@ def test_pallas_grouped_kernel_bitwise():
 
 @needs_mesh
 def test_query_many_oneshot_bitwise():
+    """One-shot clips — each a stream of one window — are answered
+    bitwise alike on the mesh and on one device."""
     eng = _engine()
     ks = [_kernels(i, O=o) for i, o in enumerate((3, 5, 2, 4))]
     xs = [_clips(i, B=b, T=10) for i, b in enumerate((2, 1, 3, 2))]
     gs = [eng.record(k, x.shape[-3:]) for k, x in zip(ks, xs)]
     reqs = list(zip(gs, xs))
-    ref = eng.query_many(reqs, dedup=True)
-    got = eng.query_many(reqs, dedup=True, mesh=make_local_mesh(2, 4))
+    assert eng.stream_plan_for(gs[0], 10).n_blocks == 1
+    ref = eng.query_stream_many(reqs, dedup=True)
+    got = eng.query_stream_many(reqs, dedup=True, mesh=make_local_mesh(2, 4))
     assert _bitwise(ref, got)
+
+
+@needs_mesh
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_new_composition_reuses_mesh_arena_and_program(use_pallas):
+    """The mesh path reads the resident arena: a batch of a tenant
+    composition it has not seen packs no arena and traces no program,
+    and its answers stay bitwise equal to one device."""
+    eng = _engine(use_pallas=use_pallas)
+    gs = [g for g, _ in _requests(eng)]
+    eng.set_resident(gs)
+    streams = [_clips(10 + i, B=1) for i in range(3)]
+    first = [(gs[0], streams[0]), (gs[1], streams[1]), (gs[1], streams[2])]
+    second = [(gs[3], streams[2]), (gs[2], streams[0]), (gs[0], streams[1])]
+    mesh = make_local_mesh(2, 4)
+    refs = [eng.query_stream_many(b, readout_k=2) for b in (first, second)]
+    got = [eng.query_stream_many(first, readout_k=2, mesh=mesh)]
+    warm = eng.pool_stats()
+    got.append(eng.query_stream_many(second, readout_k=2, mesh=mesh))
+    after = eng.pool_stats()
+    assert after["arena_builds"] == warm["arena_builds"] == 2
+    assert after["stream_traces"] == warm["stream_traces"]
+    assert after["dispatches"] == warm["dispatches"] + 1
+    for ref, out in zip(refs, got):
+        assert _bitwise(
+            [(d.scores, d.index) for d in ref],
+            [(d.scores, d.index) for d in out],
+        )
 
 
 @needs_mesh

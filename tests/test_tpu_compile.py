@@ -52,6 +52,11 @@ def _geometry():
 
 
 F_BINS, N_SCORES, ARENA_ROWS = _geometry()
+# the spectra and arenas of the grouped MAC as lane planes (R, L)
+_FTR, _HP, _WP = spectral_conv.lane_grid(
+    spectral_conv.fft_shape_for(FRAME_HW + (WINDOW_FRAMES,), KER)
+)
+LANES = (_FTR * _HP, _WP)
 
 
 @pytest.fixture(scope="module")
@@ -91,8 +96,8 @@ def test_paper_geometry_matches_served_shapes():
 
 @pytest.mark.parametrize("arena_dtype", [jnp.float32, jnp.bfloat16])
 def test_grouped_stmul_compiles(one_chip, arena_dtype):
-    x = _spec((ROWS, 1, F_BINS), jnp.float32, one_chip)
-    g = _spec((ARENA_ROWS, 1, F_BINS), arena_dtype, one_chip)
+    x = _spec((ROWS, 1) + LANES, jnp.float32, one_chip)
+    g = _spec((ARENA_ROWS, 1) + LANES, arena_dtype, one_chip)
     o = _spec((ROWS,), jnp.int32, one_chip)
     _assert_mosaic(
         lambda xr, xi, gr, gi, off: stmul_kernel.spectral_mac_grouped_pallas(
@@ -104,8 +109,8 @@ def test_grouped_stmul_compiles(one_chip, arena_dtype):
 
 def test_grouped_stmul_compiles_under_vmap(one_chip):
     """The engine maps the grouped launch over a chunk's windows."""
-    x = _spec((CHUNK_WINDOWS, ROWS, 1, F_BINS), jnp.float32, one_chip)
-    g = _spec((ARENA_ROWS, 1, F_BINS), jnp.float32, one_chip)
+    x = _spec((CHUNK_WINDOWS, ROWS, 1) + LANES, jnp.float32, one_chip)
+    g = _spec((ARENA_ROWS, 1) + LANES, jnp.float32, one_chip)
     o = _spec((ROWS,), jnp.int32, one_chip)
 
     def chunk(xr, xi, gr, gi, off):
@@ -120,16 +125,15 @@ def test_grouped_stmul_compiles_under_vmap(one_chip):
 
 def test_pooled_window_query_compiles(one_chip, monkeypatch):
     """One window of the served pooled query as the chip runs it: the
-    DFT-matmul transforms around the grouped MAC.  One stream row: the
-    compile time of the transforms grows with the batch, their widths
-    are what the chip could refuse."""
+    DFT-matmul transforms around the grouped MAC, on a lane-plane arena.
+    One stream row: the compile time of the transforms grows with the
+    batch, their widths are what the chip could refuse."""
     monkeypatch.setattr(spectral_conv, "_use_dft", lambda: True)
     monkeypatch.setattr(stmul_ops, "_use_interpret", lambda: False)
     fft = spectral_conv.fft_shape_for(FRAME_HW + (WINDOW_FRAMES,), KER)
     out = spectral_conv.valid_shape(FRAME_HW + (WINDOW_FRAMES,), KER)
     x = _spec((1, 1) + FRAME_HW + (WINDOW_FRAMES,), jnp.float32, one_chip)
-    g = _spec((ARENA_ROWS, 1, fft[0], fft[1], fft[2] // 2 + 1), jnp.float32,
-              one_chip)
+    g = _spec((ARENA_ROWS, 1) + LANES, jnp.float32, one_chip)
     o = _spec((1,), jnp.int32, one_chip)
     _assert_mosaic(
         lambda x, gr, gi, off: stmul_ops.query_grating_pooled(
