@@ -201,7 +201,7 @@ def _scaling_rows(mesh, reps: int, log) -> list[str]:
 
     # analytic per-device scaling, from the shard-tiled packing itself
     d, m = MESH_SHAPE
-    align = eng._pool_align()
+    align, _ = eng._slot_layout(gs)
     pool1 = engine_mod._build_pool(gs, align, 1)
     poolm = engine_mod._build_pool(gs, align, m)
     rows_single = int(pool1.re.shape[0])

@@ -243,11 +243,16 @@ class GratingPool:
         dense path's (ΣO_pad, C, FH, FW, FTr).  On a mesh they are
         placed with their rows sharded over the model axis.
       o_start: per-member first-row offset.  Member slots are padded to
-        ``align`` rows (the Pallas grouped kernel indexes the arena in
-        O-tile units; the dense gather path uses align=1), and the arena
-        carries enough tail rows that every ``o_start[i] + n_out`` read
-        stays in bounds.
+        multiples of ``align`` rows, the slot stride: the members' own
+        kernel count where they all have the same one (no zero rows),
+        else the grouped Pallas kernel's ``BLOCK_O`` grid; the dense
+        gather path uses align=1 (:meth:`QueryEngine._slot_layout`).
+        The arena carries enough tail rows that every ``o_start[i] +
+        n_out`` read stays in bounds.
       n_out: the widest member slot.
+      align: the slot stride.
+      block_o: the grouped Pallas kernel's O block, a divisor of
+        ``align`` (None on the dense path).
       members: strong references to the member gratings — the arena is a
         pure repack of their planes, and pinning them keeps the
         identity-keyed slot map sound.
@@ -266,6 +271,7 @@ class GratingPool:
     align: int
     members: tuple[FusedGrating, ...]
     shards: int = 1
+    block_o: int | None = None
 
     @property
     def shard_rows(self) -> int:
@@ -290,7 +296,7 @@ class _DedupLayout:
       o_off: per group-local request — offset of its tenant's O-slice
         inside its physical row's read.
       n_out: rows every physical row reads/writes (the widest span,
-        aligned to the pool's O-tile grid).
+        a whole number of the pool's slot strides).
     """
 
     uniq: list[int]
@@ -335,12 +341,12 @@ def _span_layout(
     n_out)`` read; tenants between two requested slots are computed and
     discarded — in the canonical all-tenants-one-stream batch the span
     is exactly the arena).  ``n_out`` is the widest span, rounded up to
-    a bucket (:func:`_row_bucket`) of the arena's O-tiles (the grouped
-    Pallas kernel's grid), so the pooled stream programs see few
-    distinct widths.  A span that would read past the arena's last row
-    starts earlier instead and its requests' ``o_off`` grow by the
-    shift, so every read stays inside the arena (``n_out`` never
-    exceeds it).
+    a bucket (:func:`_row_bucket`) of the arena's slot strides
+    (``align``), so the pooled stream programs see few distinct widths
+    and a span of whole slots is read with no zero rows.  A span that
+    would read past the arena's last row starts earlier instead and its
+    requests' ``o_off`` grow by the shift, so every read stays inside
+    the arena (``n_out`` never exceeds it).
 
     One ``n_out`` for the whole dispatch is a deliberate trade-off: the
     MAC needs a uniform per-row width, so in a *mixed* batch (one wide
@@ -429,6 +435,7 @@ def _build_pool(
     align: int,
     shards: int = 1,
     lanes: bool = False,
+    block_o: int | None = None,
 ) -> GratingPool:
     """Pack member gratings' planes into one arena (see GratingPool).
 
@@ -437,6 +444,10 @@ def _build_pool(
     grouped Pallas kernel takes and the inverse transform reads as it
     is stored; without it the arena keeps the gratings' 5-D bins, which
     the dense path gathers from.
+
+    Each member's slot is its rows rounded up to a multiple of
+    ``align``, the slot stride; ``block_o`` is kept for the grouped
+    kernel (:meth:`QueryEngine._slot_layout`).
 
     ``shards > 1`` makes the packing mesh-aware: members are binned
     into ``shards`` equal tiles of ``shard_rows`` rows (every tile
@@ -523,6 +534,7 @@ def _build_pool(
         align=align,
         members=tuple(members),
         shards=max(1, int(shards)),
+        block_o=block_o,
     )
 
 
@@ -686,6 +698,9 @@ def _presel_query_dense(
 # Sentinel for an unfilled/poisoned top-K slot (int32 max, matching
 # kernels.stmul.kernel.TOPK_EMPTY_IDX without importing Pallas eagerly).
 TOPK_EMPTY_IDX = 2**31 - 1
+# Rows of a float32 (8, 128) TPU tile: the readout's folded row axis is
+# padded to a whole number of them before its relayout.
+_SUBLANES = 8
 
 
 @dataclasses.dataclass(frozen=True)
@@ -829,7 +844,7 @@ class QueryEngine:
             self._stream_many_impl,
             static_argnames=(
                 "ker_shape", "fft_shape", "plan", "encode", "slm_bits",
-                "n_out",
+                "n_out", "block_o",
             ),
         )
         # fused-readout overlap-save drivers: same window loop, but each
@@ -846,7 +861,7 @@ class QueryEngine:
             self._stream_many_topk_impl,
             static_argnames=(
                 "ker_shape", "fft_shape", "plan", "encode", "slm_bits",
-                "n_out", "k",
+                "n_out", "block_o", "k",
             ),
         )
         self._stream_traces = 0  # guarded-by: _trace_lock
@@ -878,6 +893,10 @@ class QueryEngine:
         self._pooled_rows_offered = 0  # guarded-by: _pools_lock
         self._pooled_rows_dispatched = 0  # guarded-by: _pools_lock
         self._pooled_rows_padded = 0  # guarded-by: _pools_lock
+        # kernel rows: those the requests of each physical row asked for
+        # (a tenant counted once per row) and those it computed (n_out)
+        self._kernel_rows_requested = 0  # guarded-by: _pools_lock
+        self._kernel_rows_dispatched = 0  # guarded-by: _pools_lock
         # pooled dispatches of the grouped Pallas kernel, whose MAC
         # output the inverse transform reads in the kernel's own layout
         self._native_layout_dispatches = 0  # guarded-by: _pools_lock
@@ -890,7 +909,11 @@ class QueryEngine:
         arenas packed (``arena_builds``) and pooled stream programs
         traced (``stream_traces``); dispatches of the grouped Pallas
         kernel, whose lane-plane output the inverse transform reads as
-        it lies (``native_layout_dispatches``, 0 on the dense path)."""
+        it lies (``native_layout_dispatches``, 0 on the dense path); and
+        over the rows that carried a request, the kernels their requests
+        asked for, a tenant counted once per row
+        (``kernel_rows_requested``), against the kernel rows computed,
+        ``n_out`` per row (``kernel_rows_dispatched``)."""
         with self._trace_lock:
             traces = self._stream_traces
         with self._pools_lock:
@@ -905,11 +928,13 @@ class QueryEngine:
                 "arena_builds": self._arena_builds,
                 "stream_traces": traces,
                 "native_layout_dispatches": self._native_layout_dispatches,
+                "kernel_rows_requested": self._kernel_rows_requested,
+                "kernel_rows_dispatched": self._kernel_rows_dispatched,
             }
 
     def _count_pooled(
-        self, offered: int, dispatched: int, padded: int = 0,
-        native: bool = False,
+        self, offered: int, dispatched: int, padded: int,
+        kernels_requested: int, kernels_dispatched: int, native: bool,
     ) -> None:
         with self._pools_lock:
             self._pooled_dispatches += 1
@@ -917,6 +942,8 @@ class QueryEngine:
             self._pooled_rows_offered += int(offered)
             self._pooled_rows_dispatched += int(dispatched)
             self._pooled_rows_padded += int(padded)
+            self._kernel_rows_requested += int(kernels_requested)
+            self._kernel_rows_dispatched += int(kernels_dispatched)
 
     # -- record -----------------------------------------------------------
 
@@ -1395,10 +1422,22 @@ class QueryEngine:
             valid[:, None, None, None, None, :], win, -jnp.inf
         )
         B, O = win.shape[1], win.shape[2]
-        # rows-major flatten, chunk folded into the score axis: one
-        # readout launch per chunk
-        flat = jnp.moveaxis(win, 0, 2).reshape(B, O, -1)
-        return readout(flat, gidx.reshape(-1), k)
+        # rows and kernels folded into one (B·O) axis, padded with −inf
+        # rows to whole sublane tiles *before* the chunk moves into the
+        # score axis: one readout launch per chunk, no row's O padded to
+        # the readout's row tile, and a relayout the TPU compiler takes
+        # seconds over (minutes for a folded axis off the 8-row tile)
+        n = B * O
+        n_pad = -n % _SUBLANES
+        win = win.reshape(win.shape[0], n, *win.shape[3:])
+        if n_pad:
+            win = jnp.pad(
+                win, [(0, 0), (0, n_pad)] + [(0, 0)] * 3,
+                constant_values=-jnp.inf,
+            )
+        flat = jnp.moveaxis(win, 0, 1).reshape(1, n + n_pad, -1)
+        s, i = readout(flat, gidx.reshape(-1), k)
+        return s[0, :n].reshape(B, O, -1), i[0, :n].reshape(B, O, -1)
 
     def _stream_topk_impl(
         self,
@@ -1597,6 +1636,7 @@ class QueryEngine:
                     encode=g0.encode,
                     slm_bits=g0.slm_bits,
                     n_out=lay.n_out,
+                    block_o=pool.block_o,
                 )
                 if fused:
                     static["k"] = int(readout_k)
@@ -1631,8 +1671,13 @@ class QueryEngine:
                     rows = np.zeros((n_rows + n_pad,), np.int32)
                     rows[:n_rows] = np.repeat(lay.row_of, nbs)
                     args = (rows,)
+                asked: list[dict] = [{} for _ in ux]
+                for j, g in enumerate(gratings):
+                    asked[lay.uniq_of[j]][id(g)] = g.n_out
                 self._count_pooled(
                     sum(int(xj.shape[0]) for xj in xs), n_rows, n_pad,
+                    sum(nb * sum(a.values()) for nb, a in zip(nbs, asked)),
+                    n_rows * lay.n_out,
                     native=pool_re.ndim == 4,
                 )
             with span("sthc.engine.dispatch"):
@@ -1779,10 +1824,12 @@ class QueryEngine:
         ids = {id(g) for g in declared}
         extra, _ = _dedup_members([g for g in gratings if id(g) not in ids])
         members = declared + extra
+        align, block_o = self._slot_layout(members)
         pool = _build_pool(
-            members, self._pool_align(),
+            members, align,
             shards=1 if mesh is None else int(mesh.shape["model"]),
             lanes=bool(getattr(self.config, "use_pallas", False)),
+            block_o=block_o,
         )
         if mesh is not None:
             from repro.distributed import sharding as shardlib  # lazy
@@ -1837,19 +1884,33 @@ class QueryEngine:
             groups.setdefault(key, []).append(i)
         return groups
 
-    def _pool_align(self) -> int:
-        """O-offset alignment of the pool arena: the Pallas grouped
-        kernel indexes the arena in O-tile units, so member slots must
-        start on its ``block_o`` grid; the dense gather path needs no
-        alignment."""
+    def _slot_layout(
+        self, members: "Sequence[FusedGrating]"
+    ) -> tuple[int, int | None]:
+        """``(align, block_o)`` of a pool group's arena: the rows from
+        one member slot's start to the next, and the grouped Pallas
+        kernel's O block.  Where every member has the same kernel count
+        O, the stride is O, so the arena holds no zero rows, and the
+        block is O or, above ``MAX_BLOCK_O`` rows, its largest divisor
+        that fits (:func:`~repro.kernels.stmul.kernel.grouped_block_o`).
+        Members of different counts sit on the kernel's ``BLOCK_O``
+        grid, so one declaration of another count moves the whole
+        group to that grid (docs/serving.md).  An explicit
+        ``stmul_block_o`` sets the stride and the block alike.  The
+        dense gather path needs no alignment and no block."""
         cfg = self.config
         if not getattr(cfg, "use_pallas", False):
-            return 1
+            return 1, None
+        explicit = getattr(cfg, "stmul_block_o", None)
+        if explicit:
+            return int(explicit), int(explicit)
         from repro.kernels.stmul import kernel as stmul_kernel  # lazy
 
-        return int(
-            getattr(cfg, "stmul_block_o", None) or stmul_kernel.BLOCK_O
-        )
+        counts = {int(g.n_out) for g in members}
+        if len(counts) == 1:
+            (o,) = counts
+            return o, stmul_kernel.grouped_block_o(o)
+        return stmul_kernel.BLOCK_O, stmul_kernel.BLOCK_O
 
     # -- mesh-sharded execution (query_stream_many mesh=) ------------------
 
@@ -1945,7 +2006,7 @@ class QueryEngine:
 
         def stream_many(
             xs, pool_re, pool_im, x_scale=None, *,
-            ker_shape, fft_shape, plan, encode, slm_bits, n_out,
+            ker_shape, fft_shape, plan, encode, slm_bits, n_out, block_o,
         ):
             # full-arena fan-out: every row reads the whole local tile
             # (zero offsets); `n_out` is the whole arena's row count
@@ -1966,7 +2027,7 @@ class QueryEngine:
                     (xl,), prl, pil, jnp.zeros((b_local,), jnp.int32), xsl,
                     ker_shape=ker_shape,
                     fft_shape=fft_shape, plan=plan, encode=encode,
-                    slm_bits=slm_bits, n_out=s_local,
+                    slm_bits=slm_bits, n_out=s_local, block_o=block_o,
                 )
                 starts = spectral_conv.window_starts(plan)
                 blocks = lax.map(
@@ -1981,7 +2042,7 @@ class QueryEngine:
 
         def stream_many_topk(
             xs, pool_re, pool_im, x_scale=None, *,
-            ker_shape, fft_shape, plan, encode, slm_bits, n_out, k,
+            ker_shape, fft_shape, plan, encode, slm_bits, n_out, block_o, k,
         ):
             self._count_stream_trace()
             if len(xs) != 1:
@@ -2001,7 +2062,7 @@ class QueryEngine:
                     (xl,), prl, pil, jnp.zeros((b_local,), jnp.int32), xsl,
                     ker_shape=ker_shape,
                     fft_shape=fft_shape, plan=plan, encode=encode,
-                    slm_bits=slm_bits, n_out=s_local,
+                    slm_bits=slm_bits, n_out=s_local, block_o=block_o,
                 )
 
                 def one_chunk(cs):
@@ -2022,21 +2083,21 @@ class QueryEngine:
                 stream_many,
                 static_argnames=(
                     "ker_shape", "fft_shape", "plan", "encode", "slm_bits",
-                    "n_out",
+                    "n_out", "block_o",
                 ),
             ),
             "stream_topk": jax.jit(
                 stream_many_topk,
                 static_argnames=(
                     "ker_shape", "fft_shape", "plan", "encode", "slm_bits",
-                    "n_out", "k",
+                    "n_out", "block_o", "k",
                 ),
             ),
         }
 
     def _stream_many_impl(
         self, xs, pool_re, pool_im, rows, x_scale=None,
-        *, ker_shape, fft_shape, plan, encode, slm_bits, n_out,
+        *, ker_shape, fft_shape, plan, encode, slm_bits, n_out, block_o,
     ):
         """Pooled overlap-save body (jitted; mirrors ``_stream_impl``).
 
@@ -2047,12 +2108,13 @@ class QueryEngine:
         ``x_scale`` carries precomputed stream-global SLM scales when
         the clips are cursor segments of longer streams.  Returns the
         whole ``(rows, n_out, H', W', T')`` volume; callers slice each
-        request's rows and O-window from it."""
+        request's rows and O-window from it.  ``block_o`` is the grouped
+        kernel's O block (``GratingPool.block_o``)."""
         self._count_stream_trace()
         one_window, win_out, x_scale = self._pooled_osave_setup(
             xs, pool_re, pool_im, rows, x_scale,
             ker_shape=ker_shape, fft_shape=fft_shape, plan=plan,
-            encode=encode, slm_bits=slm_bits, n_out=n_out,
+            encode=encode, slm_bits=slm_bits, n_out=n_out, block_o=block_o,
         )
         starts = spectral_conv.window_starts(plan)
         blocks = lax.map(lambda cs: jax.vmap(one_window)(cs), starts)
@@ -2063,7 +2125,7 @@ class QueryEngine:
 
     def _pooled_osave_setup(
         self, xs, pool_re, pool_im, rows, x_scale,
-        *, ker_shape, fft_shape, plan, encode, slm_bits, n_out,
+        *, ker_shape, fft_shape, plan, encode, slm_bits, n_out, block_o,
     ):
         """Shared front half of the pooled overlap-save bodies: stack
         the per-row clips, encode (stream-global scale), pad the time
@@ -2083,7 +2145,7 @@ class QueryEngine:
         xp = jnp.pad(x, [(0, 0)] * 4 + [(0, plan.pad_t)])
         win_out = (H - kh + 1, W - kw + 1, plan.step)
         if getattr(self.config, "use_pallas", False):
-            query = self._pooled_query_fn()
+            query = self._pooled_query_fn(block_o)
 
             def one_window(start):
                 win = lax.dynamic_slice_in_dim(
@@ -2109,7 +2171,7 @@ class QueryEngine:
 
     def _stream_many_topk_impl(
         self, xs, pool_re, pool_im, rows, x_scale=None,
-        *, ker_shape, fft_shape, plan, encode, slm_bits, n_out, k,
+        *, ker_shape, fft_shape, plan, encode, slm_bits, n_out, block_o, k,
     ):
         """Fused-readout pooled overlap-save body (jitted): the window
         loop of ``_stream_many_impl`` with the stitch replaced by the
@@ -2124,7 +2186,7 @@ class QueryEngine:
         one_window, win_out, x_scale = self._pooled_osave_setup(
             xs, pool_re, pool_im, rows, x_scale,
             ker_shape=ker_shape, fft_shape=fft_shape, plan=plan,
-            encode=encode, slm_bits=slm_bits, n_out=n_out,
+            encode=encode, slm_bits=slm_bits, n_out=n_out, block_o=block_o,
         )
         readout = self._readout_fn()
 
@@ -2138,15 +2200,15 @@ class QueryEngine:
         chunk_s, chunk_i = lax.map(one_chunk, starts)
         return self._fold_chunk_states(chunk_s, chunk_i, k)
 
-    def _pooled_query_fn(self):
+    def _pooled_query_fn(self, block_o: int):
         """The per-window pooled FFT+MAC+IFFT of the Pallas path: the
-        grouped stmul launch on a lane-plane arena."""
+        grouped stmul launch on a lane-plane arena, in O blocks of
+        ``block_o`` rows (the arena's ``GratingPool.block_o``)."""
         from repro.kernels.stmul import ops as stmul_ops  # lazy import
 
-        cfg = self.config
         tiles = dict(
-            block_o=getattr(cfg, "stmul_block_o", None),
-            block_f=getattr(cfg, "stmul_block_f", None),
+            block_o=block_o,
+            block_f=getattr(self.config, "stmul_block_f", None),
         )
 
         def query(x, pool_re, pool_im, rows, n_out, fft_shape, out_shape):

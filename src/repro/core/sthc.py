@@ -72,7 +72,9 @@ class STHCConfig:
     # None = kernel default (MIN_MXU_C); tune from the kernels_bench sweep
     # on real TPU without touching kernel code.
     stmul_min_mxu_c: int | None = None
-    # stmul tile sizes (None = kernel defaults BLOCK_B/BLOCK_O/BLOCK_F).
+    # stmul tile sizes (None = kernel defaults BLOCK_B/BLOCK_O/BLOCK_F;
+    # the pooled arena's slot stride and O block default to its members'
+    # kernel count, see QueryEngine._slot_layout).
     # block_f must stay a multiple of 128 (lane width); tune from the
     # kernels_bench tile sweep on real TPU without touching kernel code.
     stmul_block_b: int | None = None

@@ -72,6 +72,9 @@ Array = jax.Array
 # Default tile sizes (see VMEM budget above).
 BLOCK_B = 4
 BLOCK_O = 8
+# Largest O block of the grouped kernel: 16 rows of (96, 128) planes,
+# double-buffered in and out, stay inside the default scoped VMEM.
+MAX_BLOCK_O = 16
 BLOCK_F = 512  # lanes; multiple of 128
 # Lane-plane tiles: rows of the (R, L) bins per tile at C = 1 — one
 # (96, 128) plane of the paper's window grid, 12,288 bins a step.
@@ -229,6 +232,18 @@ def spectral_mac_pallas(
     return yr[:B, :O, :F], yi[:B, :O, :F]
 
 
+def grouped_block_o(stride: int) -> int:
+    """O block of the grouped kernel over an arena whose member slots
+    start every ``stride`` rows: the whole stride up to ``MAX_BLOCK_O``
+    rows, else its largest divisor not above that — so every slot
+    offset, and every ``n_out`` that is a multiple of the stride, sits
+    on the block grid."""
+    stride = int(stride)
+    return max(
+        d for d in range(1, min(stride, MAX_BLOCK_O) + 1) if stride % d == 0
+    )
+
+
 def _stmul_kernel_grouped(
     off_ref, xr_ref, xi_ref, gr_ref, gi_ref, yr_ref, yi_ref
 ):
@@ -286,8 +301,12 @@ def spectral_mac_grouped_pallas(
         tiles up-cast in-kernel, f32 accumulation).
       o_start: (B,) int32 first-row offset per query row; every offset
         must sit on the ``block_o`` grid (the arena packs member slots
-        aligned — see ``repro.core.engine.GratingPool``).
-      n_out: rows read/written per query row (the widest member slot).
+        every ``align`` rows and the engine passes its
+        ``GratingPool.block_o``, ``grouped_block_o(align)``).
+      n_out: rows read/written per query row (a multiple of the slot
+        stride: one slot, or a union span of several).
+      block_o: rows of the O block, a leading block dimension, so any
+        size Mosaic can hold in VMEM (see ``grouped_block_o``).
 
     Returns (yr, yi): (B, n_out, R, L) float32.
     """

@@ -2,15 +2,19 @@
 mix of resident tenants is answered right by programs compiled once per
 (pool group, row bucket), from one resident arena per pool group."""
 
+import importlib.util
+import pathlib
 import sys
 import threading
+import types
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import fidelity as fid
-from repro.core.engine import QueryEngine, _row_bucket
+from repro.core.engine import QueryEngine, _row_bucket, _span_layout
+from repro.kernels.stmul import kernel as stmul_kernel
 from repro.core.sthc import STHCConfig
 from repro.launch.serve import VideoSearchConfig, VideoSearchServer
 
@@ -352,3 +356,215 @@ def test_adding_or_removing_a_tenant_rebuilds_its_arena_once():
     server.remove_tenant("t4")
     _answers_right(server, reqs)
     assert engine.pool_stats()["arena_builds"] == builds + 2
+
+
+# -- slots at the pool group's kernel count ------------------------------------
+
+
+def _nine_kernel_engines(n: int, **cfg):
+    """(ideal engine, physical engine, gratings): ``n`` seeded tenants of
+    9 kernels each — the paper's bank — alternating ideal and physical,
+    all declared resident on the ideal engine."""
+    rng = np.random.RandomState(21)
+    engines = tuple(
+        QueryEngine(STHCConfig(fidelity=f, use_pallas=True,
+                               osave_chunk_windows=2, **cfg))
+        for f in (fid.ideal(), fid.physical())
+    )
+    gratings = [
+        engines[t % 2].record(
+            jnp.asarray(rng.randn(9, 1, 4, 6, 3).astype(np.float32)), SIGNAL
+        )
+        for t in range(n)
+    ]
+    engines[0].set_resident(gratings)
+    return engines[0], engines[1], gratings
+
+
+@pytest.mark.parametrize("tenants", [4, 32])
+def test_uniform_kernel_count_packs_at_its_own_stride(tenants):
+    """A pool group whose members all have 9 kernels packs them every 9
+    rows: 4 tenants take 36 arena rows and 32 take 288, none of them
+    zero."""
+    pooled, _, gratings = _nine_kernel_engines(tenants)
+    arena = pooled._resident_arena(gratings[0::2])
+    pool = arena.pool
+    assert pool.align == 9 and pool.n_out == 9
+    assert pool.re.shape[0] == 9 * (tenants // 2)
+    assert list(pool.o_start) == [9 * i for i in range(tenants // 2)]
+    assert pool.block_o == 9
+    planes = np.asarray(pool.re).reshape(pool.re.shape[0], -1)
+    assert np.all(np.abs(planes).max(axis=1) > 0)  # no zero row
+
+
+def test_mixed_kernel_counts_keep_the_block_grid():
+    """Members of different kernel counts keep the grouped kernel's
+    ``BLOCK_O`` grid: 3 and 5 kernels each take an 8-row slot."""
+    rng = np.random.RandomState(6)
+    eng = QueryEngine(STHCConfig(fidelity=fid.ideal(), use_pallas=True))
+    gs = [eng.record(jnp.asarray(rng.randn(o, 1, 4, 6, 3)
+                                 .astype(np.float32)), SIGNAL)
+          for o in (3, 5, 3)]
+    pool = eng._resident_arena(gs).pool
+    assert pool.align == pool.block_o == stmul_kernel.BLOCK_O == 8
+    assert list(pool.o_start) == [0, 8, 16]
+    assert pool.re.shape[0] == 24
+
+
+@pytest.mark.parametrize(
+    "stride, explicit, want",
+    [(9, None, (9, 9)), (4, None, (4, 4)), (18, None, (18, 9)),
+     (17, None, (17, 1)), (9, 4, (4, 4))],
+)
+def test_pool_stride_follows_the_members_kernel_count(stride, explicit, want):
+    """The stride is the members' kernel count and the grouped kernel's
+    O block the count itself, or above ``MAX_BLOCK_O`` rows its largest
+    divisor that fits (18 is two blocks of 9, 17 seventeen of 1); an
+    explicit ``stmul_block_o`` sets both, and the dense path packs with
+    no alignment and no block."""
+    rng = np.random.RandomState(stride)
+    cfg = {} if explicit is None else {"stmul_block_o": explicit}
+    eng = QueryEngine(STHCConfig(fidelity=fid.ideal(), use_pallas=True,
+                                 **cfg))
+    dense = QueryEngine(STHCConfig(fidelity=fid.ideal()))
+    k = jnp.asarray(rng.randn(stride, 1, 4, 6, 3).astype(np.float32))
+    gs = [eng.record(k, SIGNAL), eng.record(k + 1.0, SIGNAL)]
+    assert eng._slot_layout(gs) == want
+    assert dense._slot_layout(gs) == (1, None)
+    align, block = want
+    assert align % block == 0 and block <= stmul_kernel.MAX_BLOCK_O
+
+
+def test_declaring_another_kernel_count_moves_the_group_to_the_grid():
+    """The slot stride follows the pool group's declared members at
+    serving time: declaring a 5-kernel tenant beside 9-kernel ones
+    repacks the group's arena on the 8-row grid (16-row slots) and
+    compiles the group's programs again for the new ``n_out`` and O
+    block; declaring the 9-kernel tenants alone again packs them every 9
+    rows.  Every answer equals the tenant's own ``query_stream``."""
+    pooled, _, gratings = _nine_kernel_engines(4)
+    nine = gratings[0::2]
+    rng = np.random.RandomState(21)
+    five = pooled.record(
+        jnp.asarray(rng.randn(5, 1, 4, 6, 3).astype(np.float32)), SIGNAL
+    )
+    streams = _streams(2, seed=17)
+    engines = [pooled] * 3
+    members = nine + [five]
+
+    def serve(batch, expect_align, expect_n_out):
+        before = pooled.pool_stats()
+        dets = pooled.query_stream_many(
+            [(members[t], x) for t, x in batch], readout_k=1
+        )
+        after = pooled.pool_stats()
+        (arena,) = pooled._arenas.values()
+        assert arena.pool.align == arena.pool.block_o == expect_align
+        assert arena.pool.n_out == expect_n_out
+        _check(engines, members, batch, dets)
+        return after["stream_traces"] - before["stream_traces"]
+
+    # two rows each time, so only the stride can ask for a new program
+    pooled.set_resident(nine)
+    assert serve([(0, streams[0]), (1, streams[1])], 9, 9) == 1
+    pooled.set_resident(members)
+    assert serve([(0, streams[0]), (2, streams[1])],
+                 stmul_kernel.BLOCK_O, 16) == 1
+    assert pooled.pool_stats()["arena_builds"] == 2
+    pooled.set_resident(nine)
+    assert serve([(1, streams[0]), (0, streams[1])], 9, 9) == 0
+    assert pooled.pool_stats()["arena_builds"] == 3
+
+
+def test_span_layout_reads_whole_slots():
+    """Four 9-kernel tenants sharing one stream read their 36-row union
+    span; a lone request reads its own 9 rows."""
+    starts, widths = [0, 9, 18, 27], [9] * 4
+    shared = _span_layout(starts, widths, ["s"] * 4, 9, 36)
+    assert shared.n_out == 36 and shared.row_of == [0]
+    assert shared.o_off == starts
+    lone = _span_layout([18], [9], ["s"], 9, 36)
+    assert lone.n_out == 9 and lone.row_of == [18] and lone.o_off == [0]
+
+
+def test_kernel_row_counters_read_each_rows_kernels():
+    """Per physical row, the kernels its requests asked for against the
+    rows it computed: 36/36 for four tenants on one stream, 9/9 for a
+    lone request, and a tenant asked twice of one stream counts once."""
+    pooled, _, gratings = _nine_kernel_engines(8)
+    ideal = gratings[0::2]
+    streams = _streams(2)
+
+    def delta(reqs):
+        before = pooled.pool_stats()
+        pooled.query_stream_many(reqs, readout_k=1)
+        after = pooled.pool_stats()
+        return tuple(after[k] - before[k] for k in (
+            "kernel_rows_requested", "kernel_rows_dispatched",
+            "rows_dispatched"))
+
+    assert delta([(g, streams[0]) for g in ideal]) == (36, 36, 1)
+    assert delta([(ideal[2], streams[1])]) == (9, 9, 1)
+    assert delta([(ideal[1], streams[0]), (ideal[1], streams[0])]) == (
+        9, 9, 1)
+    # two streams: each row computes the widest span, 18 rows
+    assert delta([(ideal[0], streams[0]), (ideal[1], streams[0]),
+                  (ideal[3], streams[1])]) == (27, 36, 2)
+
+
+@pytest.mark.parametrize("shared", [True, False])
+def test_nine_kernel_tenants_match_each_request_alone(shared):
+    """Pooled top-K at 9-kernel tenants of both fidelities, on one shared
+    stream or distinct streams, one-shot and through the chunked cursor:
+    every request's top-1 equals its own ``query_stream``."""
+    pooled, phys, gratings = _nine_kernel_engines(8)
+    engines = [pooled if t % 2 == 0 else phys for t in range(8)]
+    streams = _streams(8, seed=13)
+    batch = [(t, streams[0] if shared else streams[t]) for t in range(8)]
+    reqs = [(gratings[t], x) for t, x in batch]
+    one = pooled.query_stream_many(reqs, readout_k=2)
+    _check(engines, gratings, batch, one)
+    chunked = pooled.query_stream_many(reqs, readout_k=2,
+                                       max_buffer_windows=1)
+    for a, b in zip(one, chunked):
+        assert np.array_equal(np.asarray(a.scores), np.asarray(b.scores))
+        assert np.array_equal(np.asarray(a.index), np.asarray(b.index))
+    assert all(a.pool.align == 9 for a in pooled._arenas.values())
+    stats = pooled.pool_stats()
+    assert stats["kernel_rows_requested"] == stats["kernel_rows_dispatched"]
+
+
+def _kernel_fill_reader():
+    path = (pathlib.Path(__file__).resolve().parents[1]
+            / "bench" / "metrics" / "search.kernel_fill.py")
+    spec = importlib.util.spec_from_file_location("kernel_fill", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def test_kernel_fill_reader_reads_the_window_counters():
+    """The benchmark's ``search.kernel_fill`` reads 100 % over a window of
+    9-kernel tenants, each on its own stream, where slots hold no zero
+    rows; 9 of 16 rows on the 8-row grid; and nothing from a program
+    without the counters."""
+    read = _kernel_fill_reader()
+    pooled, _, gratings = _nine_kernel_engines(4)
+    streams = _streams(2)
+    before = pooled.pool_stats()
+    pooled.query_stream_many(
+        [(gratings[0], streams[0]), (gratings[2], streams[1])], readout_k=1
+    )
+    after = pooled.pool_stats()
+    ctx = types.SimpleNamespace(
+        cell=types.SimpleNamespace(pool_window=(before, after)))
+    assert read(ctx) == 100.0
+    grid = dict(after, kernel_rows_dispatched=before[
+        "kernel_rows_dispatched"] + 2 * 16)
+    ctx.cell.pool_window = (before, grid)
+    assert read(ctx) == 100.0 * 18 / 32
+    old = {k: v for k, v in after.items() if not k.startswith("kernel_")}
+    ctx.cell.pool_window = (before, old)
+    assert read(ctx) is None
+    ctx.cell.pool_window = (None, None)
+    assert read(ctx) is None

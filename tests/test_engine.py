@@ -491,7 +491,7 @@ def test_pooled_dispatch_single_forward_fft(rng):
         lambda a, b: eng._stream_many_impl(
             (a, b), pool.re, pool.im, rows, ker_shape=g1.ker_shape,
             fft_shape=g1.fft_shape, plan=plan, encode=g1.encode,
-            slm_bits=g1.slm_bits, n_out=pool.n_out,
+            slm_bits=g1.slm_bits, n_out=pool.n_out, block_o=pool.block_o,
         )
     )(x, x + 1.0)
     assert _count_ffts(jaxpr.jaxpr, "RFFT") == 1

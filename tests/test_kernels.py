@@ -4,10 +4,13 @@ pure-jnp oracles, with hypothesis shape/dtype sweeps."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _hypo import given, settings, st  # hypothesis, or deterministic fallback
 
+from repro.core import spectral_conv as sc
 from repro.kernels.conv3d import ops as conv_ops, ref as conv_ref
 from repro.kernels.ssd import ops as ssd_ops, ref as ssd_ref
+from repro.kernels.stmul import kernel as stmul_kernel
 from repro.kernels.stmul import ops as stmul_ops, ref as stmul_ref
 
 
@@ -50,6 +53,53 @@ def test_stmul_tile_boundary():
         got = stmul_ops.spectral_mac(xh, g)
         ref = stmul_ref.spectral_mac_ref(xh, g)
         np.testing.assert_allclose(got, ref, atol=1e-4)
+
+
+@pytest.mark.parametrize("arena_dtype", [jnp.float32, jnp.bfloat16])
+def test_stmul_grouped_nine_row_block_matches_oracle(arena_dtype):
+    """The grouped MAC over an arena of 9-kernel slots packed every 9
+    rows, in 9-row O blocks: single-slot reads and a 27-row union span,
+    offsets on the 9 grid (one shared by two rows), equal the loop
+    oracle — to float32 rounding, and for a bfloat16 arena to the
+    rounding of its stored planes."""
+    rng = np.random.RandomState(9)
+    s = (6, 7, 8)  # rfft grid: bins (6, 7, 5)
+    sh = (6, 7, 5)
+    pool = (rng.randn(36, 1, *sh) + 1j * rng.randn(36, 1, *sh)).astype(
+        np.complex64
+    )
+    pr, pi = sc.to_lane_planes(
+        jnp.asarray(pool.real), jnp.asarray(pool.imag), s
+    )
+    pr, pi = pr.astype(arena_dtype), pi.astype(arena_dtype)
+    # the oracle reads the arena as stored
+    fr, fi = sc.from_lane_planes(
+        pr.astype(jnp.float32), pi.astype(jnp.float32), s
+    )
+    pool = (np.asarray(fr) + 1j * np.asarray(fi)).astype(np.complex64)
+    block = stmul_kernel.grouped_block_o(9)
+    assert block == 9
+    for o_start, n_out in (([27, 9, 0, 9], 9), ([0, 9], 27)):
+        o_start = np.asarray(o_start, np.int32)
+        B = len(o_start)
+        xh = (rng.randn(B, 1, *sh) + 1j * rng.randn(B, 1, *sh)).astype(
+            np.complex64
+        )
+        ref = stmul_ref.spectral_mac_grouped_ref(
+            jnp.asarray(xh), jnp.asarray(pool), o_start, n_out
+        )
+        ref_r, ref_i = sc.to_lane_planes(jnp.real(ref), jnp.imag(ref), s)
+        xr, xi = sc.to_lane_planes(
+            jnp.asarray(xh.real), jnp.asarray(xh.imag), s
+        )
+        got_r, got_i = stmul_kernel.spectral_mac_grouped_pallas(
+            xr, xi, pr, pi, jnp.asarray(o_start), n_out=n_out,
+            block_o=block, interpret=True,
+        )
+        assert got_r.shape == (B, n_out) + pr.shape[2:]
+        tol = 1e-4 * float(jnp.max(jnp.abs(ref))) + 1e-6
+        np.testing.assert_allclose(got_r, ref_r, atol=tol)
+        np.testing.assert_allclose(got_i, ref_i, atol=tol)
 
 
 # -- conv3d --------------------------------------------------------------

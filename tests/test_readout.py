@@ -85,6 +85,23 @@ def test_topk_readout_tile_knob_is_bitwise_neutral(rng):
         assert np.array_equal(np.asarray(ix), np.asarray(base[1])), (bo, bl)
 
 
+@pytest.mark.parametrize("rows", [9, 36, 27])
+def test_topk_readout_on_folded_rows_matches_topk_select(rows, rng):
+    """The engine folds stream rows and kernels into one (B·n_out) axis:
+    over such an axis of a length that is not a multiple of the 8-row
+    tile, the Pallas readout equals ``topk_select`` bitwise."""
+    vals = _scores(rng, B=1, O=rows, L=900)
+    gidx = jnp.arange(vals.shape[-1], dtype=jnp.int32)
+    want = stmul_kernel.topk_select(
+        vals, jnp.broadcast_to(gidx, vals.shape), 3
+    )
+    got = stmul_ops.topk_readout(vals, gidx, 3, use_pallas=True,
+                                 block_l=256)
+    assert got[0].shape == (1, rows, 3)
+    assert np.array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    assert np.array_equal(np.asarray(got[1]), np.asarray(want[1]))
+
+
 # -- associative merge properties --------------------------------------------
 
 

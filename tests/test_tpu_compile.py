@@ -47,11 +47,13 @@ def _geometry():
     )
     oh, ow = (n - k + 1 for n, k in zip(FRAME_HW, KER[:2]))
     n_scores = plan.chunk * oh * ow * plan.step  # one chunk's readout axis
-    slot = -(-N_KERNELS // stmul_kernel.BLOCK_O) * stmul_kernel.BLOCK_O
-    return f_bins, n_scores, TENANTS * slot
+    # tenants of one kernel count pack at that count: no zero rows
+    return f_bins, n_scores, TENANTS * N_KERNELS
 
 
 F_BINS, N_SCORES, ARENA_ROWS = _geometry()
+# the grouped MAC's O block over slots of N_KERNELS rows
+BLOCK_O = stmul_kernel.grouped_block_o(N_KERNELS)
 # the spectra and arenas of the grouped MAC as lane planes (R, L)
 _FTR, _HP, _WP = spectral_conv.lane_grid(
     spectral_conv.fft_shape_for(FRAME_HW + (WINDOW_FRAMES,), KER)
@@ -91,7 +93,8 @@ def _assert_mosaic(fn, *args):
 
 
 def test_paper_geometry_matches_served_shapes():
-    assert (F_BINS, N_SCORES, ARENA_ROWS) == (399_600, 289_788, 64)
+    assert (F_BINS, N_SCORES, ARENA_ROWS) == (399_600, 289_788, 36)
+    assert BLOCK_O == N_KERNELS
 
 
 @pytest.mark.parametrize("arena_dtype", [jnp.float32, jnp.bfloat16])
@@ -101,7 +104,7 @@ def test_grouped_stmul_compiles(one_chip, arena_dtype):
     o = _spec((ROWS,), jnp.int32, one_chip)
     _assert_mosaic(
         lambda xr, xi, gr, gi, off: stmul_kernel.spectral_mac_grouped_pallas(
-            xr, xi, gr, gi, off, n_out=N_KERNELS
+            xr, xi, gr, gi, off, n_out=N_KERNELS, block_o=BLOCK_O
         ),
         x, x, g, g, o,
     )
@@ -116,7 +119,7 @@ def test_grouped_stmul_compiles_under_vmap(one_chip):
     def chunk(xr, xi, gr, gi, off):
         return jax.vmap(
             lambda a, b: stmul_kernel.spectral_mac_grouped_pallas(
-                a, b, gr, gi, off, n_out=N_KERNELS
+                a, b, gr, gi, off, n_out=N_KERNELS, block_o=BLOCK_O
             )
         )(xr, xi)
 
@@ -137,7 +140,7 @@ def test_pooled_window_query_compiles(one_chip, monkeypatch):
     o = _spec((1,), jnp.int32, one_chip)
     _assert_mosaic(
         lambda x, gr, gi, off: stmul_ops.query_grating_pooled(
-            x, gr, gi, off, N_KERNELS, fft, out
+            x, gr, gi, off, N_KERNELS, fft, out, block_o=BLOCK_O
         ),
         x, g, g, o,
     )
@@ -148,14 +151,14 @@ def test_pooled_chunk_reads_the_lane_layout_as_it_lies(one_chip, monkeypatch):
     lane planes, as the resident arenas are stored: the compiled program
     holds the grouped kernel, and no slice, copy, transpose or reshape
     of the kernel's (…, n_out, bins) output — the inverse transform
-    reads it as it lies.  ``n_out`` is a tenant slot (16 rows), as the
-    resident arena reads it."""
+    reads it as it lies.  ``n_out`` is the union span of the arena's
+    four 9-row slots (36 rows), as a shared stream reads it."""
     monkeypatch.setattr(spectral_conv, "_use_dft", lambda: True)
     monkeypatch.setattr(stmul_ops, "_use_interpret", lambda: False)
     fft = spectral_conv.fft_shape_for(FRAME_HW + (WINDOW_FRAMES,), KER)
     out = spectral_conv.valid_shape(FRAME_HW + (WINDOW_FRAMES,), KER)
     k, hp, wp = spectral_conv.lane_grid(fft)
-    n_out = 2 * stmul_kernel.BLOCK_O
+    n_out = TENANTS * N_KERNELS
     x = _spec((CHUNK_WINDOWS, 1, 1) + FRAME_HW + (WINDOW_FRAMES,),
               jnp.float32, one_chip)
     g = _spec((ARENA_ROWS, 1, k * hp, wp), jnp.float32, one_chip)
@@ -163,7 +166,7 @@ def test_pooled_chunk_reads_the_lane_layout_as_it_lies(one_chip, monkeypatch):
 
     def chunk(x, gr, gi, off):
         return jax.vmap(lambda w: stmul_ops.query_grating_pooled(
-            w, gr, gi, off, n_out, fft, out))(x)
+            w, gr, gi, off, n_out, fft, out, block_o=BLOCK_O))(x)
 
     text = jax.jit(chunk).lower(x, g, g, o).compile().as_text()
     assert "tpu_custom_call" in text
@@ -189,8 +192,54 @@ def test_stmul_v2_compiles(one_chip):
 
 @pytest.mark.parametrize("k", [1, 3])
 def test_topk_readout_compiles(one_chip, k):
-    vals = _spec((ROWS, N_KERNELS, N_SCORES), jnp.float32, one_chip)
+    """The readout as the engine feeds it: rows and kernels folded into
+    one axis (8 rows × 9 kernels), the chunk's windows into the scores."""
+    vals = _spec((1, ROWS * N_KERNELS, N_SCORES), jnp.float32, one_chip)
     gidx = _spec((N_SCORES,), jnp.int32, one_chip)
     _assert_mosaic(
         lambda v, i: stmul_kernel.topk_readout_pallas(v, i, k=k), vals, gidx
     )
+
+
+@pytest.mark.parametrize(
+    "rows, kernels", [(1, TENANTS * N_KERNELS), (3, N_KERNELS)]
+)
+def test_readout_fold_compiles_in_seconds(
+    one_chip, monkeypatch, rows, kernels
+):
+    """The fused readout's fold of one chunk, as the pooled program runs
+    it: a 36-row union span (fan-out) and three 9-kernel rows (27 folded
+    rows).  The folded axis reaches its relayout padded to whole 8-row
+    tiles (40 and 32 rows); a fold off that tile took the TPU compiler
+    minutes (about 227 s for 36 rows, 90 s for 27, on one CPU) where
+    the padded one takes seconds, so the bound here is generous."""
+    import time
+
+    from repro.core import fidelity as fid
+    from repro.core.engine import QueryEngine
+    from repro.core.sthc import STHCConfig
+
+    monkeypatch.setattr(stmul_ops, "_use_interpret", lambda: False)
+    eng = QueryEngine(STHCConfig(fidelity=fid.physical(), use_pallas=True))
+    plan = spectral_conv.stream_plan(
+        STREAM_FRAMES, KER[2], WINDOW_FRAMES, CHUNK_WINDOWS
+    )
+    win_out = tuple(n - k + 1 for n, k in zip(FRAME_HW, KER[:2])) + (
+        plan.step,
+    )
+    readout = eng._readout_fn()
+    win = _spec((plan.chunk, rows, kernels) + win_out, jnp.float32, one_chip)
+    starts = _spec((plan.chunk,), jnp.int32, one_chip)
+
+    def fold(w, s):
+        return eng._chunk_topk(w, s, plan, win_out, None, readout, 1)
+
+    t0 = time.perf_counter()
+    text = jax.jit(fold).lower(win, starts).compile().as_text()
+    seconds = time.perf_counter() - t0
+    assert "tpu_custom_call" in text
+    padded = -(-rows * kernels // 8) * 8
+    assert re.search(rf"f32\[1,{padded},\d+\]\S* \S*pad", text) or (
+        padded == rows * kernels
+    )
+    assert seconds < 120, seconds
